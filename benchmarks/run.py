@@ -16,6 +16,7 @@ import sys
 import traceback
 
 from benchmarks.common import write_results
+from repro.utils import enable_compile_cache
 
 MODULES = [
     "benchmarks.bench_kernels",       # per-kernel us/call + allclose
@@ -32,6 +33,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     argv = sys.argv[1:]
     fresh = "--fresh" in argv
     filters = [a for a in argv if not a.startswith("-")]

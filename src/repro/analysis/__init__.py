@@ -20,19 +20,27 @@ Two independent passes over the things the farm trusts implicitly:
 dead-letters uncertifiable boards with a journaled ``certify_fail``
 record.
 """
-from repro.analysis.annotations import (any_thread, control_thread_only,
-                                        exclusive, locked, slot_thread_only,
-                                        thread_confined)
-from repro.analysis.boardcheck import (CertReport, Finding, RULES,
-                                       certify_engine, certify_job,
-                                       certify_spec, no_dispatch_guard)
-from repro.analysis.racecheck import (RaceFinding, check_paths,
-                                      check_source, farm_sources)
+import importlib
 
-__all__ = [
-    "CertReport", "Finding", "RULES", "certify_engine", "certify_job",
-    "certify_spec", "no_dispatch_guard",
-    "RaceFinding", "check_paths", "check_source", "farm_sources",
-    "any_thread", "control_thread_only", "exclusive", "locked",
-    "slot_thread_only", "thread_confined",
-]
+# name -> defining submodule. Resolved on first attribute access so that
+# runtime modules importing ``repro.analysis.annotations`` never load the
+# analysers (or the JAX internals boardcheck walks).
+_EXPORTS = {
+    **dict.fromkeys(("any_thread", "control_thread_only", "exclusive",
+                     "locked", "slot_thread_only", "thread_confined"),
+                    "annotations"),
+    **dict.fromkeys(("CertReport", "Finding", "RULES", "certify_engine",
+                     "certify_job", "certify_spec", "no_dispatch_guard"),
+                    "boardcheck"),
+    **dict.fromkeys(("RaceFinding", "check_paths", "check_source",
+                     "farm_sources"), "racecheck"),
+}
+
+__all__ = sorted(_EXPORTS)
+
+
+def __getattr__(name):
+    if name not in _EXPORTS:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    return getattr(importlib.import_module(f"{__name__}.{_EXPORTS[name]}"),
+                   name)

@@ -55,7 +55,8 @@ from typing import Any, Callable, List, Optional
 
 import jax
 import jax.tree_util as tu
-from jax.core import ClosedJaxpr, Jaxpr, Literal, ShapedArray, Var
+from jax.core import ShapedArray
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
 
 #: rule id -> (severity, one-line catalog entry)
 RULES = {
@@ -483,15 +484,15 @@ def no_dispatch_guard():
     StableHLO lowering never compile, so every boardcheck pass must run
     clean under this guard (tests hold certification to it)."""
     from jax._src import compiler as _compiler
-    real = _compiler.backend_compile
+    real = _compiler.backend_compile_and_load
 
     def _blocked(*args, **kwargs):
         raise AssertionError(
             "device compile during certification — boardcheck must be "
             "trace-only (abstract eval, no dispatch)")
 
-    _compiler.backend_compile = _blocked
+    _compiler.backend_compile_and_load = _blocked
     try:
         yield
     finally:
-        _compiler.backend_compile = real
+        _compiler.backend_compile_and_load = real

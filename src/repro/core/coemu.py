@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core.commit import layer_checksums
 from repro.core.schedule import WindowScheduler, iter_windows
+from repro.utils import checksum
 
 
 @dataclasses.dataclass
@@ -268,7 +269,7 @@ class CommitStreamVerifier:
     Called as the train loop's drain verifier with ``(last_step,
     records)``: replays its OWN copy of the deterministic batch stream
     through ``oracle_step`` (eager, step-locked) and compares the drained
-    commit FIFO rows — per-step ``[layer, mean, abs_mean]`` checksums
+    commit FIFO rows — per-step ``[layer, abs_mean, rms]`` checksums
     pushed by the P-Shell ingest — against the oracle's
     ``layer_checksums``. A divergence raises :class:`CommitDivergence`,
     which the ``WindowScheduler`` barrier semantics turn into a checkpoint
@@ -286,7 +287,8 @@ class CommitStreamVerifier:
     scaling total verify cost with the scope's read rate (the paper's
     arbitrary-granularity knob). A mismatch falls through to the full
     compare, which localizes the divergence (step/layer) and raises.
-    ``digest_hits`` counts fast-path windows.
+    ``digest_hits`` counts fast-path windows; ``max_rel_err`` is the
+    largest relative error of any compared row so far.
 
     Mid-stream resume (the farm's checkpointed-requeue protocol):
     :meth:`snapshot` captures the oracle's position — host-copied state,
@@ -314,6 +316,7 @@ class CommitStreamVerifier:
         # the lane, so a fused farm run localizes the veto to ONE board
         self.expected_digests = expected_digests or {}
         self.digest_hits = 0        # windows verified by digest alone
+        self.max_rel_err = 0.0
 
     def _iter_batches(self):
         b = self._batches_src
@@ -342,6 +345,7 @@ class CommitStreamVerifier:
             exp = np.asarray(layer_checksums(aux), np.float64)   # (L, 2)
             got = rows[s * self.L:(s + 1) * self.L, 1:]
             err = _rel_err(got, exp).max(axis=1)                 # (L,)
+            self.max_rel_err = max(self.max_rel_err, float(err.max()))
             bad = np.nonzero(err > self.rtol)[0]
             if bad.size:
                 l = int(bad[0])
@@ -378,15 +382,6 @@ class CommitStreamVerifier:
 
 
 # ------------------------------------------------------------- multi-DUT ---
-def _activation_checksum(x):
-    """(abs-mean, rms) — both O(activation-scale) positive statistics, so
-    the relative comparison is stable (a raw mean sits near zero for
-    normalized activations and would amplify low-bit compile jitter)."""
-    x = x.astype(jnp.float32)
-    return jnp.stack([jnp.mean(jnp.abs(x)),
-                      jnp.sqrt(jnp.mean(jnp.square(x)))])
-
-
 def subsystem_boards(params, cfg, rt, xs: Sequence, positions,
                      layer_idxs: Sequence[int], dut_params=None):
     """Build the multi-DUT farm boards: for each activation batch in ``xs``
@@ -421,7 +416,7 @@ def subsystem_boards(params, cfg, rt, xs: Sequence, positions,
                 def step(x):
                     y, _ = tfm.block_apply(tree, cfg, spec, x,
                                            positions, rt)
-                    return _activation_checksum(y)
+                    return checksum(y)
                 return jax.lax.map(step, stack)
             jitted = jax.jit(window_fn)
 
@@ -436,7 +431,7 @@ def subsystem_boards(params, cfg, rt, xs: Sequence, positions,
         sub = subs[li]
         x_ins = [captures[s][li]["x_in"] for s in range(len(xs))]
         oracle_cks = np.stack([
-            np.asarray(_activation_checksum(captures[s][li]["x_out"]),
+            np.asarray(checksum(captures[s][li]["x_out"]),
                        np.float64)
             for s in range(len(xs))])              # (steps, 2)
         boards.append((shared_engine(sub.spec), sub.params, x_ins,

@@ -83,7 +83,7 @@ def default_shell_config(cfg, sample_interval: int = 1,
         "nan_bits": jax.ShapeDtypeStruct((max(L, 1),), jnp.int32),
     }
     fifos = {
-        # payload: [layer_id, mean, abs_mean]
+        # payload: [layer_id, abs_mean, rms] (repro.utils.checksum)
         "commits": FifoSpec(depth=depth, shape=(3,), dtype=jnp.float32),
     }
     if cfg.num_experts:

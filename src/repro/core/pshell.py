@@ -250,21 +250,19 @@ class PShell:
         return sched.run(engine, sched.windows(batches), state, shell,
                          on_drain=emit)
 
-    def compile_group(self, group_step, donate: Optional[bool] = None):
+    def compile_group(self, group_step, donate: bool = True):
         """Jit a group_step for fused dispatch, caching per (fn, donation).
         Returns the jitted group fn (the scheduler owns the device-side
-        ``group_reset`` double-buffering). ``donate=None`` donates
-        model/opt state (argnum 0) wherever donation is real — it is a
-        no-op warning on CPU backends. Callers that redispatch from the
-        SAME state object (benchmark timing loops) must pass donate=False
-        so the input buffers survive.
+        ``group_reset`` double-buffering). By default it donates the
+        model/opt state (argnum 0) — on every backend, the CPU included,
+        so CPU runs exercise the same buffer lifetimes as the chip.
+        Callers that redispatch from the SAME state object (benchmark
+        timing loops) must pass donate=False so the input buffers survive.
 
         The cache is keyed on the function OBJECT (kept alive by the key),
         never on ``id()``: id keys are only sound while every cached fn
         happens to stay alive, and a recycled id would silently hand a
         different step fn a stale compiled group."""
-        if donate is None:
-            donate = jax.default_backend() != "cpu"
         key = (group_step, donate)
         if key not in self._jit_cache:
             self._jit_cache[key] = jax.jit(
@@ -273,7 +271,7 @@ class PShell:
 
     def run_grouped(self, group_step, state, batches, shell=None,
                     on_drain: Optional[Callable[[int, dict], None]] = None,
-                    donate: Optional[bool] = None):
+                    donate: bool = True):
         """Fused host loop: ONE jit dispatch per clock-gated window,
         scheduled by the core WindowScheduler in overlap mode.
 

@@ -13,7 +13,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Iterable, List, Optional
 
-from repro.roofline.hw import Hardware, HW_V5E
+from repro.roofline.hw import Hardware, peaks
+
+#: the chip these analytic models predict for
+TARGET_KIND = "TPU v5 lite"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -25,10 +28,11 @@ class Event:
 
 
 class InterfaceTimer:
-    """Latency model per interface — the HBM-request timing analogue."""
+    """Latency model per interface — the HBM-request timing analogue.
+    ``hw`` defaults to the peaks of the modelled target chip."""
 
-    def __init__(self, hw: Hardware = HW_V5E):
-        self.hw = hw
+    def __init__(self, hw: Optional[Hardware] = None):
+        self.hw = hw or peaks(TARGET_KIND)
 
     def compute(self, flops: float) -> float:
         return flops / self.hw.peak_flops_bf16
@@ -55,8 +59,7 @@ class Timeline:
     the next dependency); ``overlap=False`` is the fully-serialized bound.
     """
 
-    def __init__(self, hw: Hardware = HW_V5E, overlap: bool = True):
-        self.hw = hw
+    def __init__(self, overlap: bool = True):
         self.overlap = overlap
 
     def simulate(self, groups: Iterable[Dict[str, float]]) -> Dict:

@@ -535,7 +535,10 @@ class _SlotWorker(threading.Thread):
                     driver.cancel()
                     mgr._results.put(("fault", run))
                     break
-                if run.evict_flag.is_set():
+                # a requested shutdown cuts here too, without waiting for
+                # the control plane's next sweep to mark the run: a short
+                # job could otherwise finish every window in between
+                if run.evict_flag.is_set() or mgr._shutdown.is_set():
                     driver.cancel()
                     mgr._results.put(("evicted", run))
                     break
@@ -1246,7 +1249,7 @@ class FarmManager(ClientPolicy):
                                   why=f"veto: {run.fault}")
             self._requeue_or_fail(run, f"drain veto: {run.fault}")
         elif kind == "evicted":
-            if run.evict_why == "shutdown":
+            if run.evict_why == "shutdown" or self._shutdown.is_set():
                 self._retire_interrupted(run)
             else:
                 self._requeue_or_fail(run, run.evict_why or "evicted")
@@ -1486,8 +1489,11 @@ class FarmManager(ClientPolicy):
         if snap is not None:
             tree, snap = self._restore_snapshot(job, slot, snap)
         if snap is None:
-            state = place(job._initial("state"), slot)
-            shell = place(job._initial("shell"), slot)
+            # build (factory) or copy the initial trees on the slot's own
+            # device, not the default one
+            with jax.default_device(slot.device):
+                state = place(job._initial("state"), slot)
+                shell = place(job._initial("shell"), slot)
             if hasattr(job.verify, "restore") \
                     and hasattr(job.verify, "snapshot"):
                 if job._verify_init is None:    # first admission: remember

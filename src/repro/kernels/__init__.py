@@ -1,12 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU CompilerParams across jax versions (renamed from
-    TPUCompilerParams after 0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

@@ -4,7 +4,9 @@ One new query token per sequence attends over a ring cache of length W.
 Grid: (batch, kv_head, k_blocks); the k-block dimension is sequential and
 accumulates the online softmax in VMEM scratch. All G = H/K query heads of
 one kv head are processed together so the score matmul is (G x hd)·(hd x bk)
-— MXU work instead of a matvec.
+— MXU work instead of a matvec. The cache is read head-major (B, K, W, hd)
+so each k/v block's last two dims are (block, hd), with the batch and kv-head
+axes squeezed: the tiling the TPU compiler accepts for any kv-head count.
 
 The current position ``pos`` arrives via SMEM (scalar memory), mirroring how
 a CSR would parameterize a ZynqParrot hardware timer: the kernel masks ring
@@ -18,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -35,9 +35,9 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         l_s[...] = jnp.zeros_like(l_s)
 
     pos = pos_ref[0]
-    q = q_ref[0, 0]                                  # (G, hd)
-    k = k_ref[0, :, 0, :]                            # (bk, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]                                   # (G, hd)
+    k = k_ref[...]                                   # (bk, hd)
+    v = v_ref[...]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if softcap > 0:
@@ -61,14 +61,14 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_kernel(q, k, v, pos_arr, *, softcap: float,
                             block_k: int, W: int, interpret: bool = False):
-    """q: (B, K, G, hd); k/v: (B, Wp, K, hd); pos_arr: (1,) i32."""
+    """q: (B, K, G, hd); k/v: (B, K, Wp, hd); pos_arr: (1,) i32."""
     B, K, G, hd = q.shape
-    Wp = k.shape[1]
+    Wp = k.shape[2]
     nk = Wp // block_k
     kernel = functools.partial(_kernel, bk=block_k, nk=nk, softcap=softcap,
                                scale=hd ** -0.5, W=W)
@@ -76,13 +76,14 @@ def decode_attention_kernel(q, k, v, pos_arr, *, softcap: float,
         num_scalar_prefetch=1,
         grid=(B, K, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, kv, ik, pos: (b, kv, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, kv, ik, pos: (b, ik, kv, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, kv, ik, pos: (b, ik, kv, 0)),
+            pl.BlockSpec((None, None, G, hd),
+                         lambda b, kv, ik, pos: (b, kv, 0, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, kv, ik, pos: (b, kv, ik, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, kv, ik, pos: (b, kv, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
+        out_specs=pl.BlockSpec((None, None, G, hd),
                                lambda b, kv, ik, pos: (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, hd), jnp.float32),
@@ -94,7 +95,7 @@ def decode_attention_kernel(q, k, v, pos_arr, *, softcap: float,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, q, k, v)

@@ -25,10 +25,11 @@ def decode_attention(q, k, v, *, pos, window: int, softcap: float = 0.0,
     G = H // K
     bk = min(block_k, max(8, W))
     pad = (-W) % bk
-    if pad:
-        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-        k = jnp.pad(k, widths)
-        v = jnp.pad(v, widths)
+    # head-major cache view (B, K, W, hd): (block, hd) are the last two
+    # dims of every k/v block
+    widths = ((0, 0), (0, 0), (0, pad), (0, 0))
+    k = jnp.pad(jnp.swapaxes(k, 1, 2), widths)
+    v = jnp.pad(jnp.swapaxes(v, 1, 2), widths)
     qg = q.reshape(B, K, G, hd)
     pos_arr = jnp.asarray([pos], jnp.int32)
     out = decode_attention_kernel(qg, k, v, pos_arr, softcap=softcap,

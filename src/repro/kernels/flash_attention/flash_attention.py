@@ -5,7 +5,10 @@ scratch and persists across the sequential innermost grid dimension (the
 k-block loop), so the S x T score matrix never exists in HBM. Block shapes
 are MXU-aligned (bq = bk = 128 default; head_dim is the contraction dim).
 Grid: (batch, q_head, q_blocks, k_blocks) — the first three are parallel,
-the last is an "arbitrary" (sequential) accumulation dimension.
+the last is an "arbitrary" (sequential) accumulation dimension. Operands are
+head-major (B, H, S, hd) so every block's last two dims are (block, hd):
+the batch and head axes are squeezed block dims, which is the tiling the
+TPU compiler accepts for any head count.
 
 Causal and sliding-window masks are applied from global positions computed
 off program_id; blocks that cannot contribute are skipped with pl.when.
@@ -18,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -47,9 +48,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
     @pl.when(contrib)
     def _step():
-        q = q_ref[0, :, 0, :]                       # (bq, hd)
-        k = k_ref[0, :, 0, :]                       # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[...]                              # (bq, hd)
+        k = k_ref[...]                              # (bk, hd)
+        v = v_ref[...]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
@@ -78,16 +79,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(q, k, v, *, causal: bool, window: int,
                            softcap: float, kv_len: int,
                            block_q: int, block_k: int,
                            interpret: bool = False):
-    """q: (B, Sp, H, hd); k/v: (B, Tp, K, hd). Sp/Tp pre-padded to blocks."""
-    B, Sp, H, hd = q.shape
-    Tp, K = k.shape[1], k.shape[2]
+    """q: (B, H, Sp, hd); k/v: (B, K, Tp, hd). Sp/Tp pre-padded to blocks."""
+    B, H, Sp, hd = q.shape
+    K, Tp = k.shape[1], k.shape[2]
     G = H // K
     nq, nk = Sp // block_q, Tp // block_k
     scale = hd ** -0.5
@@ -100,22 +101,22 @@ def flash_attention_kernel(q, k, v, *, causal: bool, window: int,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((None, None, block_q, hd),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sp, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, hd),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

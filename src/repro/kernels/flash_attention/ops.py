@@ -30,10 +30,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     T = k.shape[1]
     bq = min(block_q, max(8, S))
     bk = min(block_k, max(8, T))
-    qp = _pad_to(q, 1, bq)
-    kp = _pad_to(k, 1, bk)
-    vp = _pad_to(v, 1, bk)
+    # the kernel is head-major: (B, H, S, hd) keeps (block, hd) as the
+    # last two dims of every block
+    head_major = lambda x: jnp.swapaxes(x, 1, 2)      # noqa: E731
+    qp = _pad_to(head_major(q), 2, bq)
+    kp = _pad_to(head_major(k), 2, bk)
+    vp = _pad_to(head_major(v), 2, bk)
     out = flash_attention_kernel(
         qp, kp, vp, causal=causal, window=window, softcap=softcap,
         kv_len=T, block_q=bq, block_k=bk, interpret=interpret)
-    return out[:, :S]
+    return head_major(out[:, :, :S])

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(x_ref, w_ref, o_ref, acc, *, nk: int):
     ik = pl.program_id(3)
@@ -54,7 +52,7 @@ def grouped_gemm_kernel(x, w, *, block_m: int, block_n: int, block_k: int,
                                lambda e, im, in_, ik: (e, im, in_)),
         out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -21,5 +21,7 @@ def rglru_scan(a, b, h0, *, block_w: int = 512, chunk: int = 128,
     while S % c:
         c //= 2
     f32 = lambda t: t.astype(jnp.float32)
-    return rglru_scan_kernel(f32(a), f32(b), f32(h0), block_w=bw, chunk=c,
-                             interpret=interpret)
+    h_all, h_last = rglru_scan_kernel(f32(a), f32(b), f32(h0)[:, None],
+                                      block_w=bw, chunk=c,
+                                      interpret=interpret)
+    return h_all, h_last[:, 0]

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(a_ref, b_ref, h0_ref, y_ref, h_last_ref, h_s, *,
             chunk: int, nc: int):
@@ -40,7 +38,10 @@ def _kernel(a_ref, b_ref, h0_ref, y_ref, h_last_ref, h_s, *,
 
 def rglru_scan_kernel(a, b, h0, *, block_w: int, chunk: int,
                       interpret: bool = False):
-    """a/b: (B,S,W) f32; h0: (B,W) f32 -> (h_all (B,S,W), h_last (B,W))."""
+    """a/b: (B,S,W) f32; h0: (B,1,W) f32 -> (h_all (B,S,W), h_last (B,1,W)).
+
+    The state rides as (B, 1, W) so its blocks are (1, bw): a unit dim
+    equal to the array's, which the TPU tiling accepts for any batch."""
     B, S, W = a.shape
     nw, nc = W // block_w, S // chunk
     kernel = functools.partial(_kernel, chunk=chunk, nc=nc)
@@ -50,18 +51,18 @@ def rglru_scan_kernel(a, b, h0, *, block_w: int, chunk: int,
         in_specs=[
             pl.BlockSpec((1, chunk, block_w), lambda b_, w, c: (b_, c, w)),
             pl.BlockSpec((1, chunk, block_w), lambda b_, w, c: (b_, c, w)),
-            pl.BlockSpec((1, block_w), lambda b_, w, c: (b_, w)),
+            pl.BlockSpec((None, 1, block_w), lambda b_, w, c: (b_, 0, w)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_w), lambda b_, w, c: (b_, c, w)),
-            pl.BlockSpec((1, block_w), lambda b_, w, c: (b_, w)),
+            pl.BlockSpec((None, 1, block_w), lambda b_, w, c: (b_, 0, w)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, W), jnp.float32),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

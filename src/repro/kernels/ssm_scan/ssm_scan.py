@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _kernel(dt_ref, A_ref, B_ref, C_ref, x_ref, y_ref, h_last_ref, h_s, *,
             chunk: int, nc: int, N: int):
@@ -39,9 +37,9 @@ def _kernel(dt_ref, A_ref, B_ref, C_ref, x_ref, y_ref, h_last_ref, h_s, *,
         x_t = x_ref[0, t, :]            # (bd,)
         dA = jnp.exp(dt_t[:, None] * A)             # (bd, N)
         h = dA * h + (dt_t * x_t)[:, None] * B_t[None, :]
-        y_ref[0, t, :] = jax.lax.dot_general(
-            h, C_t[:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]
+        # an f32 lane reduction, not an MXU dot: at default precision
+        # the MXU rounds f32 operands to bf16
+        y_ref[0, t, :] = jnp.sum(h * C_t[None, :], axis=1)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_s[...])
@@ -79,7 +77,7 @@ def ssm_scan_kernel(dt, A, B_, C_, x, *, block_d: int, chunk: int,
             jax.ShapeDtypeStruct((B, Din, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, A, B_, C_, x)

@@ -108,6 +108,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
+import hashlib
 import json
 import os
 import signal
@@ -129,6 +131,7 @@ from repro.data import SyntheticPipeline
 from repro.farm import (FailurePolicy, FarmJob, FarmLedger, FarmManager,
                         JobSpec, register)
 from repro.farm.chaos import ChaosHarness, ChaosInjector, Injection
+from repro.farm.placement import enumerate_slots
 from repro.launch.serve import decode_shell_config, make_decode_engine
 from repro.models import build_model
 from repro.models.runtime import Runtime
@@ -136,7 +139,7 @@ from repro.roofline import WindowCapture
 from repro.serve import make_prefill_step
 from repro.train.optim import OptConfig
 from repro.train.step import init_state, make_group_step
-from repro.utils import dtype_of
+from repro.utils import dtype_of, enable_compile_cache
 
 
 class _SignalDrain:
@@ -184,23 +187,27 @@ class _SignalDrain:
 
 def _train_board_parts(cfg, steps, interval, batch=2, seq=16, seed=0):
     """Fused train engine's job parts: P-Shell drain + stack_batches per
-    window (donate=False so requeue can replay from the initial state).
-    Shared by the CLI submit path and the ``zp.train_board`` registered
-    factory — everything here is rebuilt from plain kwargs, which is what
-    lets crash recovery re-instantiate the board from its journaled
-    JobSpec instead of a dead process's closures."""
+    window. The engine donates the train state: a published-width train
+    state cannot sit on a chip twice, as input and as output of one
+    window. So the initial state is a factory, initialised on the device
+    per attempt, and requeue replays never read a donated buffer. Shared
+    by the CLI submit path and the ``zp.train_board`` registered factory
+    — everything here is rebuilt from plain kwargs, which is what lets
+    crash recovery re-instantiate the board from its journaled JobSpec
+    instead of a dead process's closures."""
     model = build_model(cfg, Runtime(taps=frozenset({"commits"})))
     ingest = make_ingest(cfg)
     shell = PShell(default_shell_config(cfg, sample_interval=interval),
                    ingest)
     engine = shell.compile_group(
-        make_group_step(model, OptConfig(), ingest=ingest), donate=False)
+        make_group_step(model, OptConfig(), ingest=ingest))
     pipe = SyntheticPipeline(cfg, batch, seq, seed=seed)
     windows = [[next(pipe) for _ in range(p.size)]
                for p in plan_windows(steps, interval)]
     pipe.close()
-    state = init_state(model, jax.random.key(seed))
-    return dict(engine=engine, windows=windows, state=state,
+    init = jax.jit(functools.partial(init_state, model))
+    return dict(engine=engine, windows=windows,
+                state=lambda: init(jax.random.key(seed)),
                 shell=shell.init(), drain_fn=drain,
                 stack_fn=stack_batches)
 
@@ -241,21 +248,23 @@ def submit_train_job(mgr, cfg, steps, interval, batch=2, seq=16, seed=0,
     return losses
 
 
-def submit_decode_job(mgr, cfg, gen, interval, batch=2, prompt_len=16,
-                      seed=0):
-    """Scan-fused decode engine as a farm job (prefill runs up front; the
-    farm schedules the windowed decode with its telemetry shell)."""
+def submit_decode_job(mgr, cfg, params, gen, interval, batch=2,
+                      prompt_len=16, seed=0):
+    """Scan-fused decode engine over ``params`` as a farm job (prefill
+    runs up front; the farm schedules the windowed decode with its
+    telemetry shell). The weights ride in the board's state, so they live
+    on whichever slot's device the board is placed on. The engine donates
+    that state, so each attempt starts from its own copy (a factory)."""
     from repro.data.pipeline import make_batch_fn
 
     model = build_model(cfg, Runtime())
-    params = model.init(jax.random.key(seed))
     bf = make_batch_fn(cfg, batch, prompt_len, seed)
     b = {k: jnp.asarray(v) for k, v in bf(0).items() if k != "labels"}
     max_len = prompt_len + (cfg.num_patches if cfg.family == "vlm" else 0) \
         + gen + 8
     cache, logits = jax.jit(make_prefill_step(model, max_len))(params, b)
     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-    engine = make_decode_engine(model, params, donate=False)
+    engine = make_decode_engine(model)
     windows = [list(range(p.start, p.boundary))
                for p in plan_windows(gen - 1, interval)]
     toks: list = [np.asarray(tok)]
@@ -265,36 +274,36 @@ def submit_decode_job(mgr, cfg, gen, interval, batch=2, prompt_len=16,
 
     mgr.submit(FarmJob(
         name="decode", engine=engine, windows=windows,
-        state=(cache, tok), shell=shell_init(decode_shell_config(interval)),
+        state=lambda: jax.tree.map(jnp.copy, (params, cache, tok)),
+        shell=shell_init(decode_shell_config(interval)),
         drain_fn=drain, stack_fn=stack_batches, on_drain=sink))
     return toks
 
 
-def prewarm(mgr) -> float:
+def prewarm(mgr, devices) -> float:
     """Build every board's bitstream before the farm runs: call each
-    submitted job's engine once on its first window (results discarded —
-    farm engines never donate, so the initial state is untouched) so jit
-    compilation happens up front, not on the boards. The paper's farm
-    synthesizes bitstreams before deployment; the host analog matters
-    doubly on a virtual-slot (single-device) host, where one board's
-    in-run compile contends with every other board's windows and pollutes
-    the wall-time samples the straggler detector compares. Returns the
-    total prewarm seconds.
-
-    Caveat: compilation happens on the DEFAULT device (jobs have no slot
-    yet at prewarm time), so a real multi-device farm still pays a
-    per-device specialization at each board's window 0 — which is why
-    window 0 is excluded from straggler observation regardless. Full
-    coverage there would prewarm per device once placement is known."""
+    submitted job's engine once on its first window, on every device in
+    ``devices`` (the distinct devices of the farm's slots — a board may
+    land on any of them), so jit compilation happens up front, not on the
+    boards. Each call runs on a fresh initial state placed as the farm
+    places it (committed to the device), and its results are discarded.
+    The paper's farm synthesizes bitstreams before deployment; the host
+    analog matters doubly on a virtual-slot (single-device) host, where
+    one board's in-run compile contends with every other board's windows
+    and pollutes the wall-time samples the straggler detector compares.
+    Returns the total prewarm seconds."""
     t0 = time.perf_counter()
-    for job in mgr.jobs:
-        items = next(job._window_iter(), None)
-        if not items:
-            continue
-        stack = job.stack_fn(items) if job.stack_fn else items
-        out = job.engine(job._initial("state"), job._initial("shell"),
-                         stack)
-        jax.block_until_ready(out)
+    for device in devices:
+        with jax.default_device(device):
+            for job in mgr.jobs:
+                items = next(job._window_iter(), None)
+                if not items:
+                    continue
+                stack = job.stack_fn(items) if job.stack_fn else items
+                args = jax.device_put((job._initial("state"),
+                                       job._initial("shell"), stack),
+                                      device)
+                jax.block_until_ready(job.engine(*args))
     return time.perf_counter() - t0
 
 
@@ -854,14 +863,16 @@ def run_killrestart_smoke(mode: str = "async", n_boards: int = 3,
                           n_windows: int = 24, kill_after: int = 8,
                           slots: int = 2) -> dict:
     """The ``farm-killrestart-smoke`` gate: whole-process crash recovery.
-    Three subprocess-visible phases: (1) a fault-free oracle run
-    in-process; (2) a victim subprocess armed with ``process_kill`` at
-    the ``kill_after``-th journaled commit — it must die by SIGKILL with
-    delivery already in flight; (3) a ``--recover`` subprocess over the
-    victim's ledger that must finish the campaign. ``ok`` requires the
-    recovery resumed at least one board mid-stream (window > 0), replayed
-    fewer windows than the campaign committed, delivered every window
-    exactly once across both lifetimes (per-board cursors reach exactly
+    Three subprocess phases, run one after another so that one process
+    at a time holds the device (this process never initialises a JAX
+    backend): (1) a fault-free oracle run; (2) a victim armed with
+    ``process_kill`` at the ``kill_after``-th journaled commit — it must
+    die by SIGKILL with delivery already in flight; (3) a ``--recover``
+    subprocess over the victim's ledger that must finish the campaign.
+    ``ok`` requires the recovery resumed at least one board mid-stream
+    (window > 0), replayed fewer windows than the campaign committed,
+    delivered every window exactly once across both lifetimes (per-board
+    cursors reach exactly
     ``n_windows``), and produced per-window output files bit-identical to
     the oracle's."""
     import shutil
@@ -874,21 +885,26 @@ def run_killrestart_smoke(mode: str = "async", n_boards: int = 3,
     problems: list = []
     out: dict = {"mode": mode, "kill_after": kill_after}
     try:
-        oracle_dir = os.path.join(base, "oracle")
-        oracle = run_ledger_farm(oracle_dir, mode=mode, n_boards=n_boards,
-                                 n_windows=n_windows, slots=slots)
-        if not oracle["ok"]:
-            problems.append("fault-free oracle run failed")
-
-        victim_dir = os.path.join(base, "victim")
         env = dict(os.environ)
         env["PYTHONPATH"] = src_root + os.pathsep \
             + env.get("PYTHONPATH", "")
-        common = [sys.executable, "-m", "repro.launch.farm",
-                  "--ledger", victim_dir, f"--{mode}",
-                  "--slots", str(slots),
-                  "--ledger-boards", str(n_boards),
-                  "--ledger-windows", str(n_windows)]
+
+        def ledger_cmd(ledger_dir):
+            return [sys.executable, "-m", "repro.launch.farm",
+                    "--ledger", ledger_dir, f"--{mode}",
+                    "--slots", str(slots),
+                    "--ledger-boards", str(n_boards),
+                    "--ledger-windows", str(n_windows)]
+
+        oracle_dir = os.path.join(base, "oracle")
+        oracle = subprocess.run(ledger_cmd(oracle_dir), env=env,
+                                capture_output=True, text=True, timeout=600)
+        if oracle.returncode != 0:
+            problems.append(f"fault-free oracle run exited "
+                            f"{oracle.returncode}: {oracle.stderr[-500:]}")
+
+        victim_dir = os.path.join(base, "victim")
+        common = ledger_cmd(victim_dir)
         victim = subprocess.run(
             common + ["--kill-after-commits", str(kill_after)],
             env=env, capture_output=True, text=True, timeout=600)
@@ -1084,12 +1100,39 @@ def write_telemetry(path: str, out: dict, run_key: str) -> str:
     return key
 
 
-def run_farm(arch: str, steps: int, slots, interval: int = 2,
+def _delivered_digest(outputs) -> str:
+    """SHA-256 over every delivered window's drained records and outputs,
+    in window order: equal digests mean bit-identical delivered streams."""
+    h = hashlib.sha256()
+    for _, records, ys in outputs:
+        for leaf in jax.tree.leaves((records, ys)):
+            a = np.asarray(leaf)
+            h.update(str((a.dtype, a.shape)).encode())
+            h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def _tree_devices(tree) -> list:
+    return sorted({d.id for leaf in jax.tree.leaves(tree)
+                   if isinstance(leaf, jax.Array) for d in leaf.devices()})
+
+
+def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
+             prompt_len: int, verify_seq: int, interval: int = 2,
              synthetic_straggler: bool = False, straggler_factor: float = 6.0,
              roofline: bool = False, seed: int = 0,
              mode: str = "async", handle_sigint: bool = False,
              scope: ScopeSpec = None, certify: bool = False) -> dict:
-    cfg = get_smoke_config(arch)
+    """The mixed farm over ``cfg``: one fused train board (``steps``
+    steps of ``batch`` x ``seq``), one decode board (``gen`` tokens per
+    sequence after a ``prompt_len`` prompt) and two subsystem verify
+    boards (layers 0 and 1, ``batch`` x ``verify_seq`` activations),
+    windows of ``interval`` steps. ``slots`` is a slot count (one slot
+    per device; virtual slots fill in on a smaller host) or a
+    :class:`~repro.farm.placement.DeviceSlot` list."""
+    t_setup = time.perf_counter()
+    if isinstance(slots, int):
+        slots = enumerate_slots(min_slots=slots)
     # min_s floors the straggler RATIO check: the mixed workload's boards
     # legitimately differ in window cost (a decode window costs more than
     # a one-layer verify window), so sub-200ms medians are never flagged
@@ -1098,18 +1141,20 @@ def run_farm(arch: str, steps: int, slots, interval: int = 2,
                       straggler_min_s=0.2, mode=mode, certify=certify)
 
     capture = WindowCapture() if roofline else None
-    losses = submit_train_job(mgr, cfg, steps, interval, seed=seed,
-                              capture=capture)
-    toks = submit_decode_job(mgr, cfg, gen=steps, interval=interval,
-                             seed=seed)
+    losses = submit_train_job(mgr, cfg, steps, interval, batch=batch,
+                              seq=seq, seed=seed, capture=capture)
+    # one weight tree, initialised on the device, serves the decode board
+    # and the verify boards' in-situ oracle capture
+    params = jax.jit(build_model(cfg, Runtime()).init)(jax.random.key(seed))
+    toks = submit_decode_job(mgr, cfg, params, gen=gen, interval=interval,
+                             batch=batch, prompt_len=prompt_len, seed=seed)
 
-    model = build_model(cfg, Runtime())
-    params = model.init(jax.random.key(seed))
-    B, S = 2, 16
     n_verify = max(2, steps // 4)
-    xs = [jax.random.normal(jax.random.key(i), (B, S, cfg.d_model))
+    xs = [jax.random.normal(jax.random.key(i), (batch, verify_seq,
+                                                 cfg.d_model))
           .astype(dtype_of(cfg.dtype)) for i in range(n_verify)]
-    pos = jnp.tile(jnp.arange(S, dtype=jnp.int32)[None], (B, 1))
+    pos = jnp.tile(jnp.arange(verify_seq, dtype=jnp.int32)[None],
+                   (batch, 1))
     finalize = submit_subsystem_jobs(mgr, params, cfg, Runtime(), xs, pos,
                                      layer_idxs=[0, 1],
                                      group_size=interval)
@@ -1143,13 +1188,16 @@ def run_farm(arch: str, steps: int, slots, interval: int = 2,
             straggler.engine = slow_engine
             mgr.force_evict(straggler.name)
 
-    prewarm_s = prewarm(mgr)
+    setup_s = time.perf_counter() - t_setup
+    prewarm_s = prewarm(mgr, list(dict.fromkeys(s.device for s in slots)))
     drainer = _SignalDrain(mgr).install() if handle_sigint else None
+    t_run = time.perf_counter()
     try:
         report = mgr.run(strict=False)
     finally:
         if drainer is not None:
             drainer.restore()
+    run_s = time.perf_counter() - t_run
     if report["interrupted"]:
         # graceful stop: partial report + telemetry, no pass/fail gating —
         # committed prefixes and published snapshots were kept
@@ -1167,15 +1215,28 @@ def run_farm(arch: str, steps: int, slots, interval: int = 2,
 
     out = {
         "mode": mode,
+        "setup_s": setup_s,
         "prewarm_s": round(prewarm_s, 3),
+        "run_s": run_s,
         "jobs": report["jobs"],
         "telemetry": report["telemetry"],
         "summary": mgr.telemetry.summary(),
         "train": {"steps": len(losses),
                   "loss_first": losses[0] if losses else None,
-                  "loss_last": losses[-1] if losses else None},
-        "decode": {"tokens": int(np.concatenate(toks, axis=1).size)},
-        "verify": {k: r.summary() for k, r in reps.items()},
+                  "loss_last": losses[-1] if losses else None,
+                  "all_finite": bool(np.all(np.isfinite(losses)))},
+        "decode": {"tokens": int(np.concatenate(toks, axis=1).size),
+                   "expected": batch * gen},
+        "verify": {k: {"summary": r.summary(),
+                       "max_rel_err": r.max_rel_err}
+                   for k, r in reps.items()},
+        # where each board's final carry lives, and a bit-level digest of
+        # what it delivered: the multi-device placement checks read these
+        "placement": {n: {"slot": report["jobs"][n]["slot"],
+                          "devices": _tree_devices(mgr.results[n])}
+                      for n in mgr.results},
+        "delivered_sha256": {n: _delivered_digest(o)
+                             for n, o in mgr.outputs.items()},
     }
     if capture is not None:
         out["roofline"] = capture.report()
@@ -1284,6 +1345,7 @@ def main():
                    help="single-thread round-robin host loop (the "
                         "bit-identity oracle)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.certify_smoke:
         out = run_certify_smoke(mode=args.mode, slots=args.slots)
@@ -1350,7 +1412,9 @@ def main():
     scope = (ScopeSpec(every_n_windows=args.scope)
              if args.scope is not None else None)
     try:
-        out = run_farm(args.arch, args.steps, args.slots,
+        out = run_farm(get_smoke_config(args.arch), args.slots,
+                       steps=args.steps, gen=args.steps, batch=2, seq=16,
+                       prompt_len=16, verify_seq=16,
                        interval=args.sample_interval,
                        synthetic_straggler=args.synthetic_straggler,
                        straggler_factor=args.straggler_factor,

@@ -8,20 +8,17 @@ import; nothing else in the codebase does.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.37; older jax defaults to Auto anyway
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:
-    _AXIS_KW = lambda n: {}  # noqa: E731
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh for CPU tests (1 device unless the test forced more)."""
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -32,6 +32,7 @@ from repro.models import build_model
 from repro.models.runtime import Runtime
 from repro.roofline.capture import WindowCapture
 from repro.serve import make_prefill_step
+from repro.utils import enable_compile_cache
 
 
 def decode_shell_config(sample_interval: int) -> ShellConfig:
@@ -44,13 +45,17 @@ def decode_shell_config(sample_interval: int) -> ShellConfig:
         sample_interval=sample_interval)
 
 
-def make_decode_engine(model, params, donate: bool = True):
-    """Scheduler engine for decode: state=(cache, last_token); scans one
-    decode step per window slot, pushing telemetry into the shell. Donates
-    the cache/token state ONLY — the shell snapshot must survive on the
-    host until its overlapped drain. ``donate=False`` keeps the initial
-    state alive (the farm's requeue path replays from it)."""
+def make_decode_engine(model):
+    """Scheduler engine for decode: state=(params, cache, last_token);
+    scans one decode step per window slot, pushing telemetry into the
+    shell. The weights ride in the state, so placing a board's state on
+    its slot's device places its weights there too. Donates the state
+    ONLY — the shell snapshot must survive on the host until its
+    overlapped drain — so the weights pass through each window in place
+    instead of being copied to a new output buffer."""
     def engine(state, shell, idx_stack):
+        params = state[0]
+
         def body(carry, idx):
             cache, tok, sh = carry
             cache, logits = model.decode_step(params, cache, tok)
@@ -63,10 +68,10 @@ def make_decode_engine(model, params, donate: bool = True):
             return (cache, tok, sh), tok
 
         (cache, tok, shell), toks = jax.lax.scan(
-            body, (state[0], state[1], shell), idx_stack)
-        return (cache, tok), shell, toks
+            body, (state[1], state[2], shell), idx_stack)
+        return (params, cache, tok), shell, toks
 
-    return jax.jit(engine, donate_argnums=(0,) if donate else ())
+    return jax.jit(engine, donate_argnums=(0,))
 
 
 def serve(cfg, batch: int, prompt_len: int, gen: int, seed: int = 0,
@@ -88,7 +93,7 @@ def serve(cfg, batch: int, prompt_len: int, gen: int, seed: int = 0,
     # measured-window roofline capture rides the decode loop by default;
     # attach_engine makes the loop's own first compile the HLO cost source
     capture = WindowCapture()
-    engine = capture.attach_engine(make_decode_engine(model, params))
+    engine = capture.attach_engine(make_decode_engine(model))
     # reset defaults to the cached jitted group_reset (P-Shell drain_fn)
     sched = WindowScheduler(interval=max(1, sample_interval), overlap=True,
                             drain_fn=drain)
@@ -120,8 +125,8 @@ def serve(cfg, batch: int, prompt_len: int, gen: int, seed: int = 0,
         scope_plane = as_plane(scope)
         capture.attach_scope(scope_plane)
     od, odr = capture.callbacks(on_dispatch=on_dispatch, on_drain=on_drain)
-    (cache, tok), _, sh = sched.run(
-        engine, sched.windows(range(gen - 1)), (cache, tok), sh,
+    (_, cache, tok), _, sh = sched.run(
+        engine, sched.windows(range(gen - 1)), (params, cache, tok), sh,
         on_dispatch=od, on_drain=odr, scope=scope_plane)
     t2 = time.perf_counter()
     toks = np.concatenate(out_tokens, axis=1)
@@ -155,6 +160,7 @@ def main():
                     help="persist the run's measured-window roofline "
                          "record for repro.roofline.report")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     scope = None
     if args.scope > 0:

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils import dtype_of, fold_key, shard_map
+from repro.utils import dtype_of, fold_key
 from repro.models.layers import init_dense, dense_apply, apply_rope
 
 NEG_INF = -1e30
@@ -223,7 +223,7 @@ def _decode_attention_sharded(cfg, q, k_new, v_new, cache, pos, *,
     # leaving it replicated makes XLA's cost model gather the 2D o-proj
     # WEIGHT instead at small batch (observed: 63 MB f32 per layer at B=1)
     out_slice = model_axis if (H * hd) % msize == 0 else None
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep4, rep4, rep4, cache_spec_, cache_spec_, P()),
         out_specs=(P(cache_spec_[0], None, out_slice),

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils import axis_size, dtype_of, fold_key, shard_map
+from repro.utils import dtype_of, fold_key
 from repro.models.layers import init_dense
 
 
@@ -144,7 +144,7 @@ def _moe_a2a_local(p, cfg, x_block, axis: str, all_axes):
     """Per-device body under shard_map. x_block: (B_loc, S_loc, D)."""
     B, S, D = x_block.shape
     E = cfg.num_experts
-    ep = axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     e_loc = E // ep                              # local experts per device
     x2 = x_block.reshape(B * S, D)
     gates, idx, probs = _route(p, cfg, x2)
@@ -198,7 +198,7 @@ def _moe_a2a(p, cfg, x, mesh, data_axes, model_axis):
     def body(p_blk, x_blk):
         return _moe_a2a_local(p_blk, cfg, x_blk, model_axis, all_axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, xspec),
         out_specs=(xspec, {"expert_toggles": P(), "load": P(),
@@ -245,7 +245,7 @@ def _moe_sort_local(p, cfg, x, mesh, data_axes, model_axis="model"):
               for k, v in st.items()}
         return y.reshape(b, s, d), st
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(wspec, P(dp, None, None)),
         out_specs=(P(dp, None, None), {k: P() for k in

@@ -25,7 +25,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from repro.roofline.hw import Hardware, HW_V5E
+from repro.roofline.hw import Hardware, device_peaks
 
 
 def save_measured(report: Dict[str, Any], arch: str, source: str,
@@ -118,11 +118,15 @@ class WindowCapture:
     full-size window dispatch (and the window size it was measured at);
     tail windows scale linearly by size. Without a cost source the rows
     still carry wall times (cost fields stay None).
+
+    Peak fractions use ``hw``, by default the peaks of the device the run
+    is on (:func:`~repro.roofline.hw.device_peaks`): off the TPU they are
+    reported as ``None``, and a TPU kind with no peak row raises.
     """
 
-    def __init__(self, hw: Hardware = HW_V5E,
+    def __init__(self, hw: Optional[Hardware] = None,
                  clock: Callable[[], float] = time.perf_counter):
-        self.hw = hw
+        self.hw = hw or device_peaks(jax.devices()[0])
         self.clock = clock
         self.rows: List[Dict[str, Any]] = []
         self._t: Dict[int, float] = {}
@@ -242,8 +246,10 @@ class WindowCapture:
                 "hlo_bytes": bts,
                 "achieved_flops_s": flops / cw,
                 "achieved_bytes_s": bts / cw,
-                "peak_flops_fraction": flops / cw / self.hw.peak_flops_bf16,
-                "peak_hbm_fraction": bts / cw / self.hw.hbm_bw,
+                "peak_flops_fraction": (None if self.hw is None else
+                                        flops / cw / self.hw.peak_flops_bf16),
+                "peak_hbm_fraction": (None if self.hw is None else
+                                      bts / cw / self.hw.hbm_bw),
             })
         if self._scope is not None:
             sc = self._scope.report()

@@ -1,11 +1,14 @@
-"""Target-hardware models (TPU v5e) for the roofline / timing engine.
+"""Hardware peaks for the roofline / timing engine, keyed by the
+``device_kind`` JAX reports.
 
-The container runs on CPU; these constants describe the TARGET, per the
-assignment: 197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+TPU v5e (``"TPU v5 lite"``), per chip, from the Google Cloud "TPU v5e"
+documentation: 197 TFLOP/s bf16, 819 GB/s HBM (16 GB), 1,600 Gbit/s of
+inter-chip interconnect (4 links of ~50 GB/s).
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,3 +31,25 @@ HW_V5E = Hardware(
     hbm_bytes=16e9,
     vmem_bytes=128 * 2**20,
 )
+
+#: device_kind -> peaks. A TPU kind missing here is an error, never a
+#: silent default to another chip's numbers.
+PEAKS = {"TPU v5 lite": HW_V5E}
+
+
+def peaks(device_kind: str) -> Hardware:
+    """Peaks of the chip JAX reports as ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
+
+
+def device_peaks(device) -> Optional[Hardware]:
+    """Peaks of a ``jax.Device``: ``None`` off the TPU (a CPU run has no
+    device roofline share), the table row for a known TPU, and an error
+    for a TPU kind the table does not hold."""
+    if device.platform != "tpu":
+        return None
+    return peaks(device.device_kind)

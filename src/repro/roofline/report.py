@@ -171,10 +171,13 @@ def measured_section(measured) -> str:
         row = (f"| {arch} | {source} | {r.get('windows', 0)} "
                f"| {r.get('steps', 0)} "
                f"| {f'{sps:.4f}' if sps is not None else 'n/a'} ")
-        if af is not None:
+        pf, ph = r.get("peak_flops_fraction"), r.get("peak_hbm_fraction")
+        if af is not None and pf is not None:
             row += (f"| {af/1e12:.3f} "
-                    f"| {r['peak_flops_fraction']*100:.2f}% "
-                    f"| {r['peak_hbm_fraction']*100:.2f}% |")
+                    f"| {pf*100:.2f}% "
+                    f"| {ph*100:.2f}% |")
+        elif af is not None:
+            row += f"| {af/1e12:.3f} | not measured | not measured |"
         else:
             row += "| | | |"
         lines.append(row)

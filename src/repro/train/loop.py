@@ -44,6 +44,7 @@ import dataclasses
 from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (PShell, default_shell_config, make_ingest,
@@ -80,7 +81,7 @@ def train_loop(model, loop_cfg: LoopConfig,
                resume: bool = True,
                oracle_step: Optional[Callable] = None,
                oracle_state: Any = None,
-               oracle_rtol: float = 1e-5) -> Dict[str, Any]:
+               oracle_rtol: float = 1e-3) -> Dict[str, Any]:
     """``oracle_step`` arms the verified-snapshot workflow: a
     ``CommitStreamVerifier`` replays the same deterministic batch stream
     through the oracle and checks the drained commit FIFO rows at every
@@ -89,7 +90,15 @@ def train_loop(model, loop_cfg: LoopConfig,
     ``oracle_state`` defaults to the DUT's own starting state — the fresh
     seed init, or the restored checkpoint on resume — so the oracle
     replays from the same weights the engine continues from; pass a
-    different state to model a faulted engine."""
+    different state to model a faulted engine.
+
+    ``oracle_rtol`` bounds the relative error of each commit checksum. The
+    fused engine and a separately compiled oracle step fuse differently,
+    so on a TPU their bf16 weight updates round differently: on a v5e at
+    internvl2-1b widths (6 layers, batch 1 x 512, 8 steps at interval 2)
+    the largest error read 9.2e-5, and the default leaves ten times that.
+    On the CPU the two agree exactly. ``out["oracle_max_rel_err"]`` reports
+    the largest error of the run."""
     cfg = model.cfg
 
     state = init_state(model, jax.random.key(loop_cfg.seed), opt_cfg,
@@ -131,8 +140,10 @@ def train_loop(model, loop_cfg: LoopConfig,
         if oracle_state is None:
             # the DUT's own starting state — fresh init, or the restored
             # checkpoint on resume, so the oracle replays from the same
-            # weights and step the engine continues from
-            oracle_state = state
+            # weights and step the engine continues from. A copy: the
+            # fused engine donates its state on the device, which deletes
+            # the buffers the oracle would otherwise read
+            oracle_state = jax.tree.map(jnp.copy, state)
         orc_pipe = SyntheticPipeline(cfg, loop_cfg.batch, loop_cfg.seq,
                                      seed=loop_cfg.seed,
                                      start_step=start_step)
@@ -170,6 +181,8 @@ def train_loop(model, loop_cfg: LoopConfig,
     }
     if scope_plane is not None:
         out["scope"] = scope_plane.report()
+    if verifier is not None:
+        out["oracle_max_rel_err"] = verifier.max_rel_err
     return out
 
 

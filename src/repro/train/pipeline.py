@@ -20,8 +20,6 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-
-from repro.utils import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import transformer as tfm
@@ -98,7 +96,7 @@ def make_pp_loss(cfg, mesh, n_stages: int, n_micro: int,
         x_mb = x.reshape(n_micro, mb, S, -1)
 
         blocks = params["stack"]["blocks"][0]
-        run = shard_map(
+        run = jax.shard_map(
             functools.partial(pipeline),
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(pipe_axis), blocks),

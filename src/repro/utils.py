@@ -1,6 +1,10 @@
 """Small shared utilities."""
 from __future__ import annotations
 
+import os
+import pathlib
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,13 +16,19 @@ def dtype_of(name: str):
 
 
 def checksum(x: jax.Array) -> jax.Array:
-    """Cheap commit-stream checksum of a tensor: (mean, mean|x|) in f32.
+    """Cheap commit-stream checksum of a tensor: (mean|x|, rms) in f32.
 
-    Used by the P-Shell commit stream (DESIGN.md C3): tolerant cross-impl
-    comparison DUT-vs-oracle, and bitwise comparison DUT-vs-DUT.
+    Used by the P-Shell commit stream (DESIGN.md C3) and the subsystem
+    verify boards: tolerant cross-impl comparison DUT-vs-oracle, and
+    bitwise comparison DUT-vs-DUT. Both statistics are positive and of
+    the activation's own scale, so a relative comparison is well
+    conditioned; a signed mean sits near zero for normalized activations
+    and turns rounding differences between two compiled programs into a
+    large relative error.
     """
     xf = x.astype(jnp.float32)
-    return jnp.stack([jnp.mean(xf), jnp.mean(jnp.abs(xf))])
+    return jnp.stack([jnp.mean(jnp.abs(xf)),
+                      jnp.sqrt(jnp.mean(jnp.square(xf)))])
 
 
 def has_nan_bit(x: jax.Array) -> jax.Array:
@@ -38,35 +48,27 @@ def tree_count(tree) -> int:
 
 
 def fold_key(key: jax.Array, *names: str) -> jax.Array:
+    """Fold each name into ``key``. Names go in by CRC-32, not ``hash()``:
+    Python salts string hashes per process, which would make a seeded
+    init differ from one run to the next."""
     for n in names:
-        key = jax.random.fold_in(key, abs(hash(n)) % (2**31))
+        key = jax.random.fold_in(key, zlib.crc32(n.encode()) & 0x7FFFFFFF)
     return key
 
 
-_NATIVE_SHARD_MAP = getattr(jax, "shard_map", None)
+#: the checkout's fixed compile-cache directory (listed in .gitignore)
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=True,
-              axis_names=None):
-    """``jax.shard_map`` across jax versions (``jax.experimental.shard_map``
-    with ``check_rep``/``auto`` spellings before it was promoted)."""
-    if _NATIVE_SHARD_MAP is not None:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _NATIVE_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {"check_rep": check_vma}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name) -> jax.Array:
-    """``jax.lax.axis_size`` across jax versions (psum-of-1 spelling on
-    older jax, which lacks the named helper)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. A set ``JAX_COMPILATION_CACHE_DIR`` wins and is
+    left alone (JAX reads it itself); otherwise the cache lives at the
+    checkout's fixed ``.jax_cache/``. A later run finds the entries only
+    at the same path, so it is never temporary, per-process or
+    time-based. Called from ``main``, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

@@ -227,6 +227,30 @@ def test_coemu_localizes_injected_fault(fault_layer):
     assert rep.first.layer == fault_layer, rep.summary()
 
 
+def test_commit_checksum_is_relative_to_the_activation_scale():
+    """The commit checksum is (abs-mean, rms), both of the activation's
+    own scale: rounding every element of a zero-mean tensor by a bf16 ulp
+    moves it by far less than the verifier's tolerance, where a signed
+    mean, which sits near zero, would move by its own size."""
+    from repro.core.coemu import _rel_err
+    from repro.utils import checksum
+
+    kx, kn = jax.random.split(jax.random.key(0))
+    x = jax.random.normal(kx, (2, 512, 896))
+    x = x - jnp.mean(x)
+    sign = jnp.where(jax.random.bernoulli(kn, 0.5, x.shape), 1.0, -1.0)
+    y = x * (1.0 + 2.0 ** -8 * sign)
+    np.testing.assert_allclose(
+        np.asarray(checksum(x)),
+        [float(jnp.mean(jnp.abs(x))), float(jnp.sqrt(jnp.mean(x * x)))],
+        rtol=1e-6)
+    assert np.all(np.asarray(checksum(x)) > 0)
+    assert _rel_err(np.asarray(checksum(y), np.float64),
+                    np.asarray(checksum(x), np.float64)).max() < 1e-3
+    signed = _rel_err(np.float64(jnp.mean(y)), np.float64(jnp.mean(x)))
+    assert signed > 1e-2
+
+
 # ---------------------------------------------------------------- coverage --
 def test_coverage_accumulates_and_saturates():
     cfg = get_smoke_config("mixtral-8x7b")

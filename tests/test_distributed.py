@@ -21,18 +21,6 @@ def run_sub(body: str, devices: int = 8, timeout: int = 420) -> dict:
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        if not hasattr(jax, "shard_map"):
-            from repro.utils import shard_map as _shard_map_compat
-            jax.shard_map = _shard_map_compat
-        if not hasattr(jax.sharding, "AxisType"):
-            # jax <= 0.4.x: no explicit axis types; meshes default to Auto
-            class _AxisType:
-                Auto = None
-            jax.sharding.AxisType = _AxisType
-            _orig_make_mesh = jax.make_mesh
-            def _make_mesh(shape, axes, axis_types=None, **kw):
-                return _orig_make_mesh(shape, axes, **kw)
-            jax.make_mesh = _make_mesh
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         print("RESULT::" + json.dumps(out, default=float))
     """)

@@ -471,3 +471,29 @@ def test_signal_drain_second_sigint_raises_keyboard_interrupt():
             signal.raise_signal(signal.SIGINT)
     finally:
         drainer.restore()
+
+
+def test_killrestart_gate_keeps_jax_out_of_the_parent():
+    """One process per device: the kill/restart gate runs its oracle,
+    victim and recovery as child processes one after another, so the
+    parent never initialises a JAX backend (on a chip it would hold the
+    device its children need)."""
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    probe = ("import json\n"
+             "from repro.launch.farm import run_killrestart_smoke\n"
+             "out = run_killrestart_smoke()\n"
+             "from jax._src import xla_bridge\n"
+             "print(json.dumps({'backend': "
+             "xla_bridge.backends_are_initialized(),\n"
+             "                  'problems': out['problems']}))\n")
+    proc = subprocess.run([sys.executable, "-c", probe],
+                          env={**os.environ, "JAX_PLATFORMS": "cpu",
+                               "PYTHONPATH": src},
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["backend"] is False
+    assert not [p for p in out["problems"] if "oracle" in p], out

@@ -104,6 +104,26 @@ def test_interface_timer_and_dominants():
     assert out["total_s"] == pytest.approx(0.3)
 
 
+def test_peaks_are_keyed_by_device_kind():
+    """v5e's peaks come from its ``device_kind`` row; a CPU device has no
+    roofline peaks, and a TPU kind missing from the table is an error."""
+    import types
+
+    from repro.roofline.capture import WindowCapture
+    from repro.roofline.hw import device_peaks, peaks
+    assert peaks("TPU v5 lite") is HW_V5E
+    assert InterfaceTimer().hw is HW_V5E
+    cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert device_peaks(cpu) is None
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peaks(v5e) is HW_V5E
+    with pytest.raises(KeyError, match="TPU v9"):
+        device_peaks(types.SimpleNamespace(platform="tpu",
+                                           device_kind="TPU v9"))
+    assert WindowCapture().hw is None       # these tests run on the CPU
+    assert WindowCapture(hw=HW_V5E).hw is HW_V5E
+
+
 def test_report_generation_from_records(tmp_path, monkeypatch):
     from repro.roofline import report as rep
     rec = {"arch": "granite-8b", "shape": "train_4k", "mesh": "16x16",
