@@ -40,7 +40,7 @@ def main():
         "steps": n,
         "loss_start": sum(out["losses"][:10]) / min(10, n),
         "loss_end": sum(out["losses"][-10:]) / min(10, n),
-        "profile_s": out["profile"],
+        "profile": out["profile"],
         "coverage": out["coverage"],
     }, indent=1, default=float))
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.commit import layer_checksums
+from repro.core.profiler import phase
 from repro.core.schedule import WindowScheduler, iter_windows
 from repro.utils import checksum
 
@@ -290,6 +291,11 @@ class CommitStreamVerifier:
     ``digest_hits`` counts fast-path windows; ``max_rel_err`` is the
     largest relative error of any compared row so far.
 
+    Each replayed step opens three phases (``repro.core.profiler``):
+    ``oracle.dispatch`` (the ``oracle_step`` call), ``oracle.wait`` (the
+    blocking read of its checksums) and ``oracle.compare`` (the host
+    compare); a digest hit opens only the first.
+
     Mid-stream resume (the farm's checkpointed-requeue protocol):
     :meth:`snapshot` captures the oracle's position — host-copied state,
     global step, and the number of batches consumed — and
@@ -339,14 +345,17 @@ class CommitStreamVerifier:
                      and int(digest) == int(self.expected_digests[window]))
         for s in range(steps):
             batch = self._next_batch()
-            self.state, _, aux = self.oracle_step(self.state, batch)
+            with phase("oracle.dispatch"):
+                self.state, _, aux = self.oracle_step(self.state, batch)
             if skip_rows:
                 continue
-            exp = np.asarray(layer_checksums(aux), np.float64)   # (L, 2)
-            got = rows[s * self.L:(s + 1) * self.L, 1:]
-            err = _rel_err(got, exp).max(axis=1)                 # (L,)
-            self.max_rel_err = max(self.max_rel_err, float(err.max()))
-            bad = np.nonzero(err > self.rtol)[0]
+            with phase("oracle.wait"):
+                exp = np.asarray(layer_checksums(aux), np.float64)  # (L, 2)
+            with phase("oracle.compare"):
+                got = rows[s * self.L:(s + 1) * self.L, 1:]
+                err = _rel_err(got, exp).max(axis=1)                # (L,)
+                self.max_rel_err = max(self.max_rel_err, float(err.max()))
+                bad = np.nonzero(err > self.rtol)[0]
             if bad.size:
                 l = int(bad[0])
                 raise CommitDivergence(step=self.step + s, layer=l,

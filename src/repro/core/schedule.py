@@ -51,14 +51,14 @@ the bare 4-tuple form is unchanged):
 from __future__ import annotations
 
 import dataclasses
+from contextlib import nullcontext
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
-
-from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
 
 from repro.analysis.annotations import thread_confined
+from repro.core.profiler import Profiler, phase
 from repro.core.pshell import _reset_jitted
 from repro.core.pshell import drain as shell_drain
 from repro.core.pshell import stack_batches
@@ -195,12 +195,6 @@ def iter_windows(items: Iterable[Any], interval: int):
         yield buf
 
 
-class _NullTimer:
-    @contextmanager
-    def phase(self, name: str):
-        yield
-
-
 class WindowScheduler:
     """Owns the host loop shared by training, co-emulation, and serving.
 
@@ -223,18 +217,21 @@ class WindowScheduler:
     stack_fn : stacks a window's item list into the engine payload;
         ``None`` hands the engine the raw item list (per-step engines —
         no redundant window copy).
-    timer : object with a ``phase(name)`` context manager (the live
-        stall-stack profiler duck-types this); attribution follows the
-        fused train engine: "data" = window assembly, "device" = dispatch
-        (the enqueue), "host" = drains and barriers — the wait for window
-        *i* lands in "host" at its drain, concurrent with window *i+1*.
+    timer : a :class:`~repro.core.profiler.Profiler` bound to the calling
+        thread for each :meth:`run` (``None``: whatever the thread has
+        bound). Its phases are the farm slot thread's: ``slot.stack``
+        (window assembly), ``slot.dispatch`` (engine call, shell reset,
+        ``on_dispatch``), ``slot.fetch`` (the drain's blocking read),
+        ``slot.verify`` (``on_drain``) and ``slot.commit`` (barrier
+        actions). With ``overlap`` the fetch of window *i* runs while
+        window *i+1* is in flight.
     """
 
     def __init__(self, interval: int = 1, *, overlap: bool = True,
                  reset: Optional[Callable] = None,
                  drain_fn: Optional[Callable] = shell_drain,
                  stack_fn: Optional[Callable] = stack_batches,
-                 timer: Any = None):
+                 timer: Optional[Profiler] = None):
         self.interval = max(1, interval)
         self.overlap = overlap
         if overlap and reset is None and drain_fn is not None:
@@ -251,7 +248,7 @@ class WindowScheduler:
         self.reset = reset
         self.drain_fn = drain_fn
         self.stack_fn = stack_fn
-        self.timer = timer if timer is not None else _NullTimer()
+        self.timer = timer
 
     def windows(self, items: Iterable[Any]):
         return iter_windows(items, self.interval)
@@ -260,7 +257,6 @@ class WindowScheduler:
     def run(self, engine, windows, state, shell, *, start_step: int = 0,
             on_drain: Optional[Callable] = None,
             on_dispatch: Optional[Callable] = None,
-            on_window: Optional[Callable] = None,
             barriers: Sequence[DrainBarrier] = (),
             scope: Any = None):
         """Drive ``engine`` over ``windows`` (an iterable of per-step item
@@ -271,9 +267,7 @@ class WindowScheduler:
         window's dispatch is enqueued (watchdog heartbeats);
         ``on_drain(plan, records, ys)`` fires once per window in window
         order with the drained shell records and the window's ys — raising
-        here vetoes any barrier commit that depends on the window;
-        ``on_window(plan, state)`` fires after the window's host phase
-        (profiler step accounting).
+        here vetoes any barrier commit that depends on the window.
 
         ``scope`` (a ``ScopeSpec`` or ``ScopePlane``) opts this pass into
         the ZP-Scope instrumentation plane: on-device counters ride beside
@@ -281,56 +275,60 @@ class WindowScheduler:
         state/ys/shell are bit-identical to an un-instrumented pass
         (``plane.finalize`` unwraps the composite before returning).
         """
-        timer = self.timer
         drain_fn, reset = self.drain_fn, self.reset
         plane = None
         if scope is not None:
             plane = as_plane(scope)
             engine, shell, drain_fn, reset = plane.bind(
                 engine, shell, drain_fn, reset)
+        verify = None
+        if on_drain is not None:
+            def verify(plan, records, ys):
+                with phase("slot.verify"):
+                    on_drain(plan, records, ys)
         pending = None              # (plan, shell_snapshot, ys)
         last_ys = None
         step = start_step
         index = 0
         it = iter(windows)
-        while True:
-            with timer.phase("data"):
-                try:
-                    items = next(it)
-                except StopIteration:
-                    break
-                if not items:
-                    continue
-                stack = self.stack_fn(items) if self.stack_fn else items
-            plan = WindowPlan(index=index, start=step, size=len(items))
-            with timer.phase("device"):
-                state, snap, ys = engine(state, shell, stack)
+        with self.timer.bind() if self.timer is not None else nullcontext():
+            while True:
+                with phase("slot.stack"):
+                    try:
+                        items = next(it)
+                    except StopIteration:
+                        break
+                    if not items:
+                        continue
+                    stack = self.stack_fn(items) if self.stack_fn else items
+                plan = WindowPlan(index=index, start=step, size=len(items))
+                with phase("slot.dispatch"):
+                    state, snap, ys = engine(state, shell, stack)
+                    if self.overlap:
+                        shell = reset(snap) if reset else snap
+                    if on_dispatch is not None:
+                        on_dispatch(plan, state)
                 if self.overlap:
-                    shell = reset(snap) if reset else snap
-            if on_dispatch is not None:
-                on_dispatch(plan, state)
-            with timer.phase("host"):
-                if self.overlap:
-                    self._flush(pending, on_drain, drain_fn=drain_fn)
+                    self._flush(pending, verify, drain_fn=drain_fn)
                     pending = (plan, snap, ys)
                 else:
-                    records, shell = self._drain_now(snap,
-                                                     drain_fn=drain_fn)
-                    self._emit(plan, records, ys, on_drain)
-                for b in barriers:
-                    if b.fires(plan):
-                        # commit barrier: every window up to the boundary
-                        # must be drained and accepted before the action
-                        self._flush(pending, on_drain, drain_fn=drain_fn)
-                        pending = None
-                        b.action(state, plan.boundary)
-            if on_window is not None:
-                on_window(plan, state)
-            last_ys = ys
-            step += len(items)
-            index += 1
-        with timer.phase("host"):
-            self._flush(pending, on_drain, drain_fn=drain_fn)
+                    with phase("slot.fetch"):
+                        records, shell = self._drain_now(snap,
+                                                         drain_fn=drain_fn)
+                    self._emit(plan, records, ys, verify)
+                fired = [b for b in barriers if b.fires(plan)]
+                if fired:
+                    # commit barrier: every window up to the boundary must
+                    # be drained and accepted before the action
+                    self._flush(pending, verify, drain_fn=drain_fn)
+                    pending = None
+                    with phase("slot.commit"):
+                        for b in fired:
+                            b.action(state, plan.boundary)
+                last_ys = ys
+                step += len(items)
+                index += 1
+            self._flush(pending, verify, drain_fn=drain_fn)
         if plane is not None:
             shell = plane.finalize(shell)
         return state, last_ys, shell
@@ -487,10 +485,12 @@ class WindowScheduler:
             return
         drain_fn = self.drain_fn if drain_fn is _INHERIT else drain_fn
         plan, snap, ys = pending
+        records = {}
         if drain_fn is not None:
-            records, _ = drain_fn(snap)        # snapshot's reset state is
-        else:                                  # discarded: the live shell
-            records = {}                       # was reset on device
+            # the snapshot's reset state is discarded: the live shell was
+            # reset on device
+            with phase("slot.fetch"):
+                records, _ = drain_fn(snap)
         self._emit(plan, records, ys, on_drain, client=client)
 
     @staticmethod
@@ -577,26 +577,28 @@ class ClientDriver:
     def dispatch(self) -> Optional[WindowPlan]:
         if self.exhausted:
             return None
-        items = None
-        while not items:                # skip empty windows, don't stall
-            try:
-                items = next(self._it)
-            except StopIteration:
-                self.exhausted = True
-                return None
         c = self.c
-        stack = c.stack_fn(items) if c.stack_fn else items
-        if self.place_fn is not None:
-            stack = self.place_fn(self.key, stack)
+        with phase("slot.stack"):
+            items = None
+            while not items:            # skip empty windows, don't stall
+                try:
+                    items = next(self._it)
+                except StopIteration:
+                    self.exhausted = True
+                    return None
+            stack = c.stack_fn(items) if c.stack_fn else items
+            if self.place_fn is not None:
+                stack = self.place_fn(self.key, stack)
         plan = WindowPlan(index=self.index, start=self.step,
                           size=len(items))
-        if self.inject is not None:
-            self.inject(self.key, "dispatch", plan)
-        self.state, snap, ys = c.engine(self.state, self.shell, stack)
-        if self.sched.overlap:
-            self.shell = c.reset(snap) if c.reset else snap
-        if self.on_dispatch is not None:
-            self.on_dispatch(self.key, plan, self.state)
+        with phase("slot.dispatch"):
+            if self.inject is not None:
+                self.inject(self.key, "dispatch", plan)
+            self.state, snap, ys = c.engine(self.state, self.shell, stack)
+            if self.sched.overlap:
+                self.shell = c.reset(snap) if c.reset else snap
+            if self.on_dispatch is not None:
+                self.on_dispatch(self.key, plan, self.state)
         self._dispatched = (plan, snap, ys)
         self.step += len(items)
         self.index += 1
@@ -614,23 +616,24 @@ class ClientDriver:
             self.pending = cur
         else:
             _, snap, ys = cur
-            records, self.shell = self.sched._drain_now(
-                snap, drain_fn=self.c.drain_fn)
+            with phase("slot.fetch"):
+                records, self.shell = self.sched._drain_now(
+                    snap, drain_fn=self.c.drain_fn)
             self.sched._emit(plan, records, ys, self.on_drain,
                              client=self.key)
-        committed = False
-        for b in self.c.barriers:
-            if b.fires(plan):
-                # commit barrier: every window up to the boundary must be
-                # drained and accepted before the action (forfeits ONE
-                # window's drain/compute overlap)
-                self.flush()
-                if not committed and self.inject is not None:
+        fired = [b for b in self.c.barriers if b.fires(plan)]
+        if fired:
+            # commit barrier: every window up to the boundary must be
+            # drained and accepted before the action (forfeits ONE
+            # window's drain/compute overlap)
+            self.flush()
+            with phase("slot.commit"):
+                if self.inject is not None:
                     self.inject(self.key, "commit", plan)
-                b.action(self.state, plan.boundary)
-                committed = True
-        if committed and self.on_commit is not None:
-            self.on_commit(self.key, plan, self.state, self.shell)
+                for b in fired:
+                    b.action(self.state, plan.boundary)
+                if self.on_commit is not None:
+                    self.on_commit(self.key, plan, self.state, self.shell)
 
     def flush(self):
         pending, self.pending = self.pending, None

@@ -120,13 +120,14 @@ from repro.analysis.annotations import (any_thread, control_thread_only,
 from repro.checkpoint.manager import (MemorySnapshotStore,
                                       SnapshotIntegrityError)
 from repro.core import scope as zp_scope
+from repro.core.profiler import phase, set_context
 from repro.core.pshell import drain as _shell_drain
 from repro.core.schedule import (Client, ClientPolicy, DrainBarrier,
                                  LaneBatch, WindowScheduler)
 from repro.core.watchdog import Watchdog
 from repro.farm.placement import (DeviceSlot, enumerate_slots, pick_slot,
                                   place, place_stack)
-from repro.farm.telemetry import FarmTelemetry
+from repro.farm.telemetry import FarmTelemetry, set_last_report
 
 
 class FarmError(RuntimeError):
@@ -395,19 +396,21 @@ class _SlotWorker(threading.Thread):
         self._idle_since: Optional[float] = None
 
     def run(self):
-        while True:
-            task = self.inbox.get()
-            if task is _STOP:
-                return
-            if isinstance(task, _Canary):
-                self._canary()
-                continue
-            # worker.loop: an injected raise here kills the THREAD itself
-            # (no crash message ever posts) — the liveness watchdog is the
-            # only thing that can notice, exactly the failure it exists for
-            self.mgr._inject("worker.loop", slot=self.slot.name,
-                             job=task.job.name)
-            self._drive(task)
+        with self.mgr.telemetry.slot_profiler(self.slot.name).bind():
+            while True:
+                task = self.inbox.get()
+                if task is _STOP:
+                    return
+                if isinstance(task, _Canary):
+                    self._canary()
+                    continue
+                # worker.loop: an injected raise here kills the THREAD
+                # itself (no crash message ever posts) — the liveness
+                # watchdog is the only thing that can notice, exactly the
+                # failure it exists for
+                self.mgr._inject("worker.loop", slot=self.slot.name,
+                                 job=task.job.name)
+                self._drive(task)
 
     def _canary(self):
         """Run the breaker probe on the slot's own thread (the same thread
@@ -425,13 +428,32 @@ class _SlotWorker(threading.Thread):
 
     # ------------------------------------------------------------ driving --
     def _drive(self, run: _Run):
+        """One assignment, from pickup to the terminal message. Its wall
+        is partitioned by the slot phases (``repro.core.profiler``):
+        ``slot.start`` builds the client, the driver's ``slot.stack`` /
+        ``slot.dispatch`` / ``slot.fetch`` / ``slot.commit`` run the
+        windows, ``slot.fetch`` here also waits on each window's ys,
+        ``slot.verify`` runs ``job.verify`` and ``slot.post`` is the
+        watchdog, telemetry and results hand-off."""
+        mgr = self.mgr
+        t_pickup = mgr.clock()
+        try:
+            self._drive_phases(run)
+        finally:
+            self._idle_since = mgr.clock()
+            mgr.telemetry.assignment(self.slot.name,
+                                     self._idle_since - t_pickup)
+
+    def _drive_phases(self, run: _Run):
         mgr = self.mgr
         job = run.job
-        now = mgr.clock()
-        mgr.telemetry.queue_wait(self.slot.name, now - run.t_assigned)
-        if self._idle_since is not None:
-            mgr.telemetry.idle(self.slot.name, now - self._idle_since)
-        mgr.wd.heartbeat(self.slot.name, gap=False)   # picked up: alive
+        set_context(job.name)
+        with phase("slot.post"):
+            now = mgr.clock()
+            mgr.telemetry.queue_wait(self.slot.name, now - run.t_assigned)
+            if self._idle_since is not None:
+                mgr.telemetry.idle(self.slot.name, now - self._idle_since)
+            mgr.wd.heartbeat(self.slot.name, gap=False)   # picked up: alive
         t_dispatched: Dict[int, float] = {}           # window idx -> t0
 
         def on_dispatch(k, plan, state):
@@ -443,54 +465,64 @@ class _SlotWorker(threading.Thread):
         def on_drain(k, plan, records, ys):
             if run.closed:
                 return
+            set_context(job.name, plan.index)
             t0 = mgr.clock()
-            jax.block_until_ready(ys)     # results truly in hand, HERE —
-            # the blocking fetch stays on the slot's own thread
-            mgr.wd.heartbeat(self.slot.name, gap=False)
-            td = t_dispatched.pop(plan.index, None)
-            if td is not None and plan.index > 0:
-                # measured window WALL (dispatch -> results in hand) is the
-                # async straggler signal; window 0 pays jit compilation
-                # (the farm analog of bitstream build time), a known
-                # one-off, not slowness; a lane-batched window is N boards
-                # of work, normalized to per-board cost
-                wall = mgr.clock() - td
-                mgr.wd.observe(self.slot.name, wall,
-                               lanes=run.lane_count)
-                if run.scope_plane is not None:
-                    # accumulate measured walls over the scope interval;
-                    # consumed (and zeroed) when the plane's next sample
-                    # drains (_scope_observe)
-                    run.scope_wall_acc += wall
-            if job.capture is not None:
-                job.capture.on_drain(plan, records, ys)
+            with phase("slot.fetch"):
+                jax.block_until_ready(ys)   # results truly in hand, HERE —
+                # the blocking fetch stays on the slot's own thread
+            with phase("slot.post"):
+                mgr.wd.heartbeat(self.slot.name, gap=False)
+                td = t_dispatched.pop(plan.index, None)
+                if td is not None and plan.index > 0:
+                    # measured window WALL (dispatch -> results in hand) is
+                    # the async straggler signal; window 0 pays jit
+                    # compilation (the farm analog of bitstream build
+                    # time), a known one-off, not slowness; a lane-batched
+                    # window is N boards of work, normalized to per-board
+                    # cost
+                    wall = mgr.clock() - td
+                    mgr.wd.observe(self.slot.name, wall,
+                                   lanes=run.lane_count)
+                    if run.scope_plane is not None:
+                        # accumulate measured walls over the scope
+                        # interval; consumed (and zeroed) when the plane's
+                        # next sample drains (_scope_observe)
+                        run.scope_wall_acc += wall
+                if job.capture is not None:
+                    job.capture.on_drain(plan, records, ys)
             if run.lanes is not None:
                 # per-lane fan-out + verify on the slot thread; a veto
                 # masks ITS lane only (this thread owns lane_faults, so
                 # later commits on this run already skip the lane)
-                delivered, faulted = mgr._lane_ingest(run, plan,
-                                                      records, ys)
-                if faulted and len(run.lane_faults) == len(run.lanes):
-                    run.fault = faulted[-1][1]      # every lane dead
-                mgr.telemetry.drain(self.slot.name, mgr._key(run, plan),
-                                    wall_s=mgr.clock() - t0)
-                mgr._inject("results.post", job=job.name,
-                            slot=self.slot.name)
-                mgr._results.put(("lane_drain", run, plan, delivered,
-                                  faulted))
+                with phase("slot.verify"):
+                    delivered, faulted = mgr._lane_ingest(run, plan,
+                                                          records, ys)
+                with phase("slot.post"):
+                    if faulted and len(run.lane_faults) == len(run.lanes):
+                        run.fault = faulted[-1][1]      # every lane dead
+                    mgr.telemetry.drain(self.slot.name, mgr._key(run, plan),
+                                        wall_s=mgr.clock() - t0)
+                    mgr._inject("results.post", job=job.name,
+                                slot=self.slot.name)
+                    mgr._results.put(("lane_drain", run, plan, delivered,
+                                      faulted))
                 return
             if job.verify is not None and run.fault is None:
-                try:
-                    job.verify(plan, records, ys)
-                except Exception as e:  # noqa: BLE001 — veto, not crash
-                    mgr.telemetry.veto(self.slot.name)
-                    run.fault = e
-            mgr.telemetry.drain(self.slot.name, mgr._key(run, plan),
-                                wall_s=mgr.clock() - t0)
-            # results.post: an injected stall here models a results-queue
-            # hand-off delay — the control plane simply sees the drain late
-            mgr._inject("results.post", job=job.name, slot=self.slot.name)
-            mgr._results.put(("drain", run, plan, records, ys))
+                with phase("slot.verify"):
+                    try:
+                        job.verify(plan, records, ys)
+                    except Exception as e:  # noqa: BLE001 — veto, not crash
+                        mgr.telemetry.veto(self.slot.name)
+                        run.fault = e
+            with phase("slot.post"):
+                mgr.telemetry.drain(self.slot.name, mgr._key(run, plan),
+                                    wall_s=mgr.clock() - t0)
+                # results.post: an injected stall here models a
+                # results-queue hand-off delay — the control plane simply
+                # sees the drain late
+                mgr._inject("results.post", job=job.name,
+                            slot=self.slot.name)
+                mgr._results.put(("drain", run, plan, records, ys))
 
         def on_commit(k, plan, state, shell):
             # an accepted barrier commit publishes the job's resume point;
@@ -507,44 +539,50 @@ class _SlotWorker(threading.Thread):
                 mgr._inject("slot." + point, job=job.name,
                             slot=self.slot.name, window=plan.index)
         try:
-            client = mgr._client_for(run, self.slot)
-            driver = mgr.sched.driver(
-                client, key=run.idx, on_drain=on_drain,
-                on_dispatch=on_dispatch, on_commit=on_commit,
-                place_fn=lambda k, stack: place_stack(stack, self.slot),
-                inject=inject)
+            with phase("slot.start"):
+                client = mgr._client_for(run, self.slot)
+                driver = mgr.sched.driver(
+                    client, key=run.idx, on_drain=on_drain,
+                    on_dispatch=on_dispatch, on_commit=on_commit,
+                    place_fn=lambda k, stack: place_stack(stack, self.slot),
+                    inject=inject)
             while True:
+                set_context(job.name, driver.index)
                 t0 = mgr.clock()
                 plan = driver.dispatch()
                 if plan is None:
                     driver.flush()        # final window's deferred drain
-                    if run.fault is not None:
-                        mgr._results.put(("fault", run))
-                    else:
-                        mgr._results.put(
-                            ("done", run, driver.state, driver.shell))
+                    with phase("slot.post"):
+                        if run.fault is not None:
+                            mgr._results.put(("fault", run))
+                        else:
+                            mgr._results.put(
+                                ("done", run, driver.state, driver.shell))
                     break
-                t_dispatched[plan.index] = t0
-                mgr.telemetry.dispatch(self.slot.name, mgr._key(run, plan),
-                                       mgr.clock() - t0)
+                cost = mgr.clock() - t0
+                with phase("slot.post"):
+                    t_dispatched[plan.index] = t0
+                    mgr.telemetry.dispatch(self.slot.name,
+                                           mgr._key(run, plan), cost)
                 driver.advance()          # drains window i-1 on THIS thread
                 # drain boundary: the only cancellation points — a job is
                 # never cut mid-dispatch, its in-flight window is simply
                 # discarded undelivered
                 if run.fault is not None:
                     driver.cancel()
-                    mgr._results.put(("fault", run))
+                    with phase("slot.post"):
+                        mgr._results.put(("fault", run))
                     break
                 # a requested shutdown cuts here too, without waiting for
                 # the control plane's next sweep to mark the run: a short
                 # job could otherwise finish every window in between
                 if run.evict_flag.is_set() or mgr._shutdown.is_set():
                     driver.cancel()
-                    mgr._results.put(("evicted", run))
+                    with phase("slot.post"):
+                        mgr._results.put(("evicted", run))
                     break
         except BaseException as e:  # noqa: BLE001 — report, don't die
             mgr._results.put(("crash", run, e))
-        self._idle_since = mgr.clock()
 
 
 class FarmManager(ClientPolicy):
@@ -900,6 +938,26 @@ class FarmManager(ClientPolicy):
     # ------------------------------------------------------------ running --
     @control_thread_only
     def run(self, strict: bool = True) -> dict:
+        """Run every submitted job; returns :meth:`report`. The pass is
+        the span ``zp.farm.run``, whose start on the manager's clock
+        (``perf_counter`` by default) the telemetry keeps as
+        ``clock_origin``; the calling thread's phases
+        (``ctl.*``; in lockstep also the slots' ``slot.*``) go to the
+        telemetry's control profiler. Whether it returns or raises, its
+        telemetry report becomes
+        :func:`repro.farm.telemetry.last_report`."""
+        report = None
+        try:
+            with phase("farm.run"), self.telemetry.control.bind():
+                self.telemetry.clock_origin = self.clock()
+                report = self._run(strict)
+            return report
+        finally:
+            set_last_report(report["telemetry"] if report is not None
+                            else self.telemetry.report())
+
+    @control_thread_only
+    def _run(self, strict: bool) -> dict:
         if not self.jobs:
             return {"jobs": {}, "telemetry": self.telemetry.report()}
         if isinstance(self._slots_arg, int):
@@ -970,19 +1028,24 @@ class FarmManager(ClientPolicy):
         for w in self._workers.values():
             w.start()
         try:
-            self._assign_async()
+            with phase("ctl.admit"):
+                self._assign_async()
             while self._running or self.queue:
                 if self._shutdown.is_set():
-                    self._shutdown_async()
+                    with phase("ctl.sweep"):
+                        self._shutdown_async()
                 try:
                     msg = self._results.get(timeout=self.poll_s)
                 except queue_mod.Empty:
                     msg = None
                 if msg is not None:
-                    self._handle_async(msg)
-                self._sweep_async()
-                self._probe_async()
-                self._assign_async()
+                    with phase("ctl.ingest"):
+                        self._handle_async(msg)
+                with phase("ctl.sweep"):
+                    self._sweep_async()
+                    self._probe_async()
+                with phase("ctl.admit"):
+                    self._assign_async()
         finally:
             for w in self._workers.values():
                 try:
@@ -1986,11 +2049,12 @@ class FarmManager(ClientPolicy):
                 run.fault = faulted[-1][1]          # every lane dead
             return
         if run.job.verify is not None and run.fault is None:
-            try:
-                run.job.verify(plan, records, ys)
-            except Exception as e:          # noqa: BLE001 — veto, not crash
-                self.telemetry.veto(run.slot.name)
-                run.fault = e
+            with phase("slot.verify"):
+                try:
+                    run.job.verify(plan, records, ys)
+                except Exception as e:      # noqa: BLE001 — veto, not crash
+                    self.telemetry.veto(run.slot.name)
+                    run.fault = e
         run.outputs.append((plan, records, ys))
 
     # ----------------------------------------------------------- internals --

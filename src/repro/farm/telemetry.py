@@ -36,6 +36,21 @@ rather than just measured):
       slowness);
   queue_depth — slot work-queue depth sampled at every assignment.
 
+Phase channels (``repro.core.profiler``): each slot thread binds the
+slot's :class:`~repro.core.profiler.Profiler` (:meth:`slot_profiler`) and
+the thread running ``FarmManager.run`` binds :attr:`control`. The report
+gives each slot's phase table (``devices[slot]["phases"]``: ``{phase:
+{"n", "wall_ms", "cpu_ms"}}``), ``unspanned_ms`` — the slot's assignment
+wall (:meth:`assignment`, pickup to terminal message) that no top-level
+``slot.*`` phase covers — and its backend ``compiles``; ``control``
+holds the control thread's table, and ``compiles`` the totals with a
+bounded log of recompiles (``{slot, job, window, s}``: a compile inside a
+window after a job's window 0). ``clock_origin`` is the ``perf_counter``
+start of the ``zp.farm.run`` span, so a host-clock stamp ``t`` lies at
+``t - clock_origin`` into that span on the profiler's timeline.
+:func:`last_report` returns the report of the last ``FarmManager.run``
+to return or raise in this process.
+
 Failure-policy channels (filled by the :class:`FailurePolicy` layer and
 the chaos harness): per-job retry counts with their backoff, quarantined
 (dead-lettered) jobs, circuit-breaker trips/probes per slot, snapshot
@@ -53,9 +68,23 @@ from __future__ import annotations
 import threading
 import time
 from collections import defaultdict, deque
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
-from repro.core.profiler import StallStack
+from repro.core.profiler import Profiler, StallStack
+
+_last_report: Optional[dict] = None
+
+
+def last_report() -> Optional[dict]:
+    """The telemetry report of the last ``FarmManager.run`` to return or
+    raise in this process (``None`` before the first): the post-mortem
+    handle on a run whose manager is gone."""
+    return _last_report
+
+
+def set_last_report(report: dict):
+    global _last_report
+    _last_report = report
 
 
 def _pct(s: List[float], q: float) -> float:
@@ -132,6 +161,11 @@ class FarmTelemetry:
         self.scope_samples = _BoundedLog(max_events)  # {slot, job, sample}
         self.scope_jobs: Dict[str, dict] = {}   # job -> latest cumulative
         self.scope_quiet = defaultdict(int)     # job -> quiet samples seen
+        # ----- phase channels (repro.core.profiler) -----
+        self.profilers: Dict[str, Profiler] = {}    # slot -> its thread's
+        self.control = Profiler()       # the thread running FarmManager.run
+        self.assign_s = defaultdict(float)  # slot -> assignment wall
+        self.clock_origin: Optional[float] = None
         self._t: Dict[Tuple[str, object], float] = {}
         self._lock = threading.Lock()
 
@@ -155,6 +189,18 @@ class FarmTelemetry:
             if wall_s is not None:
                 self.drain_wall_ms[slot].append(wall_s * 1e3)
             self.windows[slot] += 1
+
+    def slot_profiler(self, slot: str) -> Profiler:
+        """The profiler ``slot``'s dispatcher thread binds."""
+        with self._lock:
+            if slot not in self.profilers:
+                self.profilers[slot] = Profiler()
+            return self.profilers[slot]
+
+    def assignment(self, slot: str, wall_s: float):
+        """One assignment's wall on ``slot``: pickup to terminal message."""
+        with self._lock:
+            self.assign_s[slot] += wall_s
 
     def queue_wait(self, slot: str, wait_s: float):
         with self._lock:
@@ -324,6 +370,7 @@ class FarmTelemetry:
             slots = sorted(set(self.windows) | set(self.dispatch_ms)
                            | set(self.lanes_per_dispatch))
             devices = {}
+            recompiles = []
             for slot in slots:
                 lanes = self.lanes_per_dispatch.get(slot, [])
                 # Fold the slot's host-overhead channel SUMS into a stall
@@ -336,6 +383,12 @@ class FarmTelemetry:
                     "idle": sum(self.idle_ms.get(slot, [])),
                 })
                 has_stall = any(v > 0 for v in stack.seconds.values())
+                prof = self.profilers.get(slot, Profiler()).report()
+                recompiles += [{"slot": slot, **r}
+                               for r in prof["recompiles"]]
+                spanned = sum(p["wall_ms"] for name, p in
+                              prof["phases"].items()
+                              if name.startswith("slot."))
                 devices[slot] = {
                     "windows": self.windows.get(slot, 0),
                     "lanes_per_dispatch": _stats([float(x) for x in lanes]),
@@ -351,6 +404,10 @@ class FarmTelemetry:
                     "stall_ms": dict(stack.seconds),
                     "dominant_stall": (stack.dominant() if has_stall
                                        else None),
+                    "phases": prof["phases"],
+                    "unspanned_ms": (self.assign_s.get(slot, 0.0) * 1e3
+                                     - spanned),
+                    "compiles": prof["compiles"],
                 }
             occ = list(self.occupancy_samples)
             lane_vetoes = [dict(v) for v in self.lane_vetoes]
@@ -381,6 +438,16 @@ class FarmTelemetry:
                 ("recoveries", self.recoveries),
                 ("scope_samples", self.scope_samples)) if log.dropped}
             scope = self._scope_report_locked()
+            control = self.control.report()
+            per_thread = ([d["compiles"] for d in devices.values()]
+                          + [control["compiles"]])
+            compiles = {
+                "n": sum(c["n"] for c in per_thread),
+                "s": sum(c["s"] for c in per_thread),
+                "recompiles": recompiles,
+                "recompiles_dropped": sum(
+                    p.recompiles_dropped for p in self.profilers.values()),
+            }
         return {
             "devices": devices,
             "occupancy_mean": (sum(a / t for a, t in occ if t) / len(occ)
@@ -405,6 +472,10 @@ class FarmTelemetry:
             "recoveries": recoveries,
             "scope": scope,
             "events_dropped": dropped,
+            "control": {"phases": control["phases"],
+                        "compiles": control["compiles"]},
+            "compiles": compiles,
+            "clock_origin": self.clock_origin,
         }
 
     def summary(self) -> str:
@@ -472,4 +543,21 @@ class FarmTelemetry:
                 line += (f" | stall: {dom} "
                          f"{d['stall_ms'][dom] / tot:.0%}")
             lines.append(line)
+            top = {k: p for k, p in d["phases"].items()
+                   if k.startswith("slot.")}
+            if top:
+                dom = max(top, key=lambda k: top[k]["wall_ms"])
+                tot = sum(p["wall_ms"] for p in top.values()) or 1.0
+                line = (f"    phases: {dom} {top[dom]['wall_ms'] / tot:.0%}"
+                        f" of {tot:.1f}ms, unspanned "
+                        f"{d['unspanned_ms']:.1f}ms, "
+                        f"{d['compiles']['n']} compiles")
+                mine = [c for c in r["compiles"]["recompiles"]
+                        if c["slot"] == slot]
+                if mine:
+                    line += ", recompiles " + " ".join(
+                        f"{c['job']}@{c['window']}" for c in mine[:4])
+                    if len(mine) > 4:
+                        line += f" (+{len(mine) - 4})"
+                lines.append(line)
         return "\n".join(lines)

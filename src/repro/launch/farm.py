@@ -1076,8 +1076,8 @@ def run_certify_smoke(work_dir: str | None = None, mode: str = "async",
 
 def write_telemetry(path: str, out: dict, run_key: str) -> str:
     """Dump a farm run's merged telemetry + scope report as JSON, keyed
-    by run so repeated invocations MERGE into one file (the
-    BENCH_results.json convention — one mergeable record per run)."""
+    by run so repeated invocations MERGE into one file (one mergeable
+    record per run)."""
     data = {}
     if os.path.exists(path):
         try:
@@ -1296,8 +1296,7 @@ def main():
                          "lane-coalesced variant)")
     ap.add_argument("--telemetry-out", metavar="PATH", default=None,
                     help="dump the run's merged telemetry + scope report "
-                         "as JSON at PATH (repeated runs merge by key, "
-                         "like BENCH_results.json)")
+                         "as JSON at PATH (repeated runs merge by key)")
     ap.add_argument("--ledger", metavar="DIR", default=None,
                     help="attach a ZP-Ledger write-ahead journal at DIR "
                          "and run the durable toy workload (outputs, "

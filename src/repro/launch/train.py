@@ -52,7 +52,7 @@ def main():
     print(json.dumps({
         "arch": cfg.name,
         "loss_first": out["losses"][0], "loss_last": out["losses"][-1],
-        "coverage": out["coverage"], "profile_s": out["profile"],
+        "coverage": out["coverage"], "profile": out["profile"],
         "roofline": out["roofline"],
     }, indent=1, default=float))
 

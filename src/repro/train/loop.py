@@ -2,7 +2,7 @@
 
 Wires together every substrate: data pipeline (prefetch), P-Shell
 instrumentation (drain at the gating granularity -> coverage + commit
-verification hooks), profiler phases (device/host/data attribution),
+verification hooks), profiler phases (the farm slot thread's phases),
 watchdog heartbeats, async checkpointing, and restart-from-latest.
 
 Both execution engines run through the core ``WindowScheduler``
@@ -19,9 +19,9 @@ window/drain/barrier machinery is shared and bit-identical by construction
 
   per-step — one dispatch per batch inside the window (``overlap=False``),
       kept as the equivalence baseline. Even here nothing blocks inside the
-      "device" phase: loss arrays are held on device and materialized only
-      at drain boundaries, so the profiler's device phase measures
-      dispatch/compute, not a forced host<->device sync per step.
+      ``slot.dispatch`` phase: loss arrays are held on device and
+      materialized only at drain boundaries, so the phase measures the
+      enqueue, not a forced host<->device sync per step.
 
 Profiler, watchdog, coverage, and checkpointing hook in via scheduler
 callbacks: the profiler IS the scheduler's phase timer, the watchdog
@@ -32,11 +32,13 @@ and ACCEPTED by the host (an on_drain verifier that raises vetoes it).
 Both engines share the barrier semantics: saves commit at the first window
 boundary at/after each ``checkpoint_every`` mark.
 
-Profiler attribution under async dispatch: "device" is dispatch time (the
-enqueue), and the wait for a window's results lands in the "host" phase at
-its drain — by design, since that wait runs concurrently with the NEXT
-window's in-flight compute. A host-dominated live stack therefore means
-"host is waiting on the device", not "host work dominates".
+``out["profile"]`` is the profiler's phase table, ``{phase: {"n",
+"wall_ms", "cpu_ms"}}`` (``repro.core.profiler``): ``slot.stack`` is
+window assembly, ``slot.dispatch`` the enqueue, ``slot.fetch`` the drain's
+blocking read — the wait for a window's results, concurrent with the NEXT
+window's in-flight compute — ``slot.verify`` the drain hooks (the oracle's
+``oracle.*`` phases nest inside it) and ``slot.commit`` the checkpoint
+barriers.
 """
 from __future__ import annotations
 
@@ -116,7 +118,7 @@ def train_loop(model, loop_cfg: LoopConfig,
     shell = PShell(shell_cfg, ingest)
     sh = shell.init()
 
-    prof = Profiler(sample_interval=loop_cfg.sample_interval)
+    prof = Profiler()
     wd = Watchdog(timeout_s=loop_cfg.watchdog_timeout_s)
     cov = CoverageMap()
     # measured-window roofline capture rides every run by default; the
@@ -174,7 +176,7 @@ def train_loop(model, loop_cfg: LoopConfig,
         "state": state,
         "losses": losses,
         "coverage": cov.summary(),
-        "profile": prof.live_stack().seconds,
+        "profile": prof.report()["phases"],
         "stragglers": wd.stragglers(),
         "final_step": loop_cfg.steps,
         "roofline": capture.report(),
@@ -188,7 +190,7 @@ def train_loop(model, loop_cfg: LoopConfig,
 
 def _pipe_windows(pipe, loop_cfg, start_step):
     """Window source: pull each planned window's batches from the pipeline
-    (consumed inside the scheduler's "data" phase)."""
+    (consumed inside the scheduler's ``slot.stack`` phase)."""
     for plan in plan_windows(loop_cfg.steps, loop_cfg.sample_interval,
                              start=start_step):
         yield [next(pipe) for _ in range(plan.size)]
@@ -199,14 +201,6 @@ def _barriers(ckpt, loop_cfg):
         return ()
     return (DrainBarrier(every=loop_cfg.checkpoint_every,
                          action=lambda state, step: ckpt.save(state, step)),)
-
-
-def _step_counter(prof):
-    """on_window hook: one profiler step per step of the drained window."""
-    def step_done(plan, state):
-        for _ in range(plan.size):
-            prof.step_done()
-    return step_done
 
 
 def _run_fused(model, loop_cfg, opt_cfg, state, shell, sh, ingest, pipe,
@@ -236,7 +230,7 @@ def _run_fused(model, loop_cfg, opt_cfg, state, shell, sh, ingest, pipe,
     state, _, _ = sched.run(
         group_fn, _pipe_windows(pipe, loop_cfg, start_step), state, sh,
         start_step=start_step, on_drain=odr, on_dispatch=od,
-        on_window=_step_counter(prof), barriers=_barriers(ckpt, loop_cfg),
+        barriers=_barriers(ckpt, loop_cfg),
         scope=scope_plane)
     return state
 
@@ -254,7 +248,7 @@ def _run_per_step(model, loop_cfg, opt_cfg, state, shell, sh, ingest, pipe,
                   verifier=None, capture=None, scope_plane=None):
     """Per-step dispatch baseline (``overlap=False``: serial in-place
     drains at window boundaries). Loss materialization is deferred to drain
-    boundaries — no blocking sync inside the device phase."""
+    boundaries — no blocking sync inside the dispatch phase."""
     step_fn = jax.jit(make_train_step(
         model, opt_cfg, with_aux=True,
         grad_compress=loop_cfg.grad_compress,
@@ -287,6 +281,6 @@ def _run_per_step(model, loop_cfg, opt_cfg, state, shell, sh, ingest, pipe,
     state, _, _ = sched.run(
         engine, _pipe_windows(pipe, loop_cfg, start_step), state, sh,
         start_step=start_step, on_drain=odr, on_dispatch=od,
-        on_window=_step_counter(prof), barriers=_barriers(ckpt, loop_cfg),
+        barriers=_barriers(ckpt, loop_cfg),
         scope=scope_plane)
     return state
