@@ -1061,13 +1061,18 @@ class FarmManager(ClientPolicy):
         """Admission: feed queued jobs into slot work queues, honoring the
         requeue avoid-slot preference and each job's backoff gate, with
         the same progress guarantee as lockstep admit (the preference
-        yields when nothing else can ever free a different slot)."""
-        assigned = 0
+        yields when nothing else can ever free a different slot). The walk
+        stops once no seat is free, since every job behind that point
+        could only be deferred: a tick costs O(free seats), not O(queue).
+        Each tick's counts go to the telemetry's ``admission`` counter."""
+        free = self._free_seats()
+        examined = assigned = 0
         deferred = []
         backing_off = False
         now = self.clock()
-        while self.queue:
+        while free and self.queue:
             job = self.queue.popleft()
+            examined += 1
             if job.not_before > now:    # backoff: re-admission must wait
                 deferred.append(job)
                 backing_off = True
@@ -1077,9 +1082,12 @@ class FarmManager(ClientPolicy):
                 deferred.append(job)    # wait for a DIFFERENT one
                 continue
             self._avoid.pop(job.name, None)
-            self._dispatch_to_slot(job, slot)
-            assigned += 1
+            assigned += self._dispatch_to_slot(job, slot)
+            free -= 1                   # a run takes exactly one seat
         self.queue.extendleft(reversed(deferred))
+        if not (free or assigned or self._running) and self.queue:
+            # no walk ran: read the backoff gate for the progress guarantee
+            backing_off = any(j.not_before > now for j in self.queue)
         if not assigned and not self._running and self.queue \
                 and not backing_off:
             # nothing running, nothing assigned: no other slot will ever
@@ -1087,17 +1095,26 @@ class FarmManager(ClientPolicy):
             slot = self._pick_async_slot(None)
             if slot is not None:
                 job = self.queue.popleft()
+                examined += 1
                 self._avoid.pop(job.name, None)
-                self._dispatch_to_slot(job, slot)
-                assigned += 1
+                assigned += self._dispatch_to_slot(job, slot)
             elif not (set(self._benched) | self._probing):
                 # no capacity anywhere and no benched slot a canary could
                 # still heal: the farm is genuinely out of seats
                 raise FarmError(
                     "no live slots left to place queued jobs "
                     f"(lost: {sorted(self._lost)})")
+        self.telemetry.admission(examined, assigned)
         if assigned:
             self.telemetry.occupancy(len(self._running), len(self.slots))
+
+    @control_thread_only
+    def _free_seats(self) -> int:
+        """Runs the farm could seat now: room under ``slot_queue_depth``
+        on every slot that is not lost, benched or out on a probe."""
+        out = self._unavailable()
+        return sum(max(0, self.slot_queue_depth - self._slot_load[s.name])
+                   for s in self.slots if s.name not in out)
 
     @control_thread_only
     def _pick_async_slot(self, avoid: Optional[str]) -> Optional[DeviceSlot]:
@@ -1154,7 +1171,9 @@ class FarmManager(ClientPolicy):
                 run.evict_flag.set()
 
     @control_thread_only
-    def _dispatch_to_slot(self, job: FarmJob, slot: DeviceSlot):
+    def _dispatch_to_slot(self, job: FarmJob, slot: DeviceSlot) -> int:
+        """Seat ``job`` (fused with compatible queued jobs when the slot
+        takes lanes) on ``slot`` as one run; returns the jobs seated."""
         members = self._gather_lanes(job, slot)
         run = self._new_run(members, slot, t_assigned=self.clock())
         with self._mu:
@@ -1174,6 +1193,7 @@ class FarmManager(ClientPolicy):
         self.telemetry.depth(slot.name,
                              self._workers[slot.name].inbox.qsize() + 1)
         self._workers[slot.name].inbox.put(run)
+        return len(members)
 
     # ---------------------------------------------------- lane coalescing --
     @control_thread_only

@@ -43,11 +43,13 @@ gives each slot's phase table (``devices[slot]["phases"]``: ``{phase:
 {"n", "wall_ms", "cpu_ms"}}``), ``unspanned_ms`` — the slot's assignment
 wall (:meth:`assignment`, pickup to terminal message) that no top-level
 ``slot.*`` phase covers — and its backend ``compiles``; ``control``
-holds the control thread's table, and ``compiles`` the totals with a
-bounded log of recompiles (``{slot, job, window, s}``: a compile inside a
-window after a job's window 0). ``clock_origin`` is the ``perf_counter``
-start of the ``zp.farm.run`` span, so a host-clock stamp ``t`` lies at
-``t - clock_origin`` into that span on the profiler's timeline.
+holds the control thread's table and its ``admission`` counter (async
+admission ticks, jobs examined, jobs assigned: :meth:`admission`), and
+``compiles`` the totals with a bounded log of recompiles (``{slot, job,
+window, s}``: a compile inside a window after a job's window 0).
+``clock_origin`` is the ``perf_counter`` start of the ``zp.farm.run``
+span, so a host-clock stamp ``t`` lies at ``t - clock_origin`` into that
+span on the profiler's timeline.
 :func:`last_report` returns the report of the last ``FarmManager.run``
 to return or raise in this process.
 
@@ -166,6 +168,8 @@ class FarmTelemetry:
         self.control = Profiler()       # the thread running FarmManager.run
         self.assign_s = defaultdict(float)  # slot -> assignment wall
         self.clock_origin: Optional[float] = None
+        # ----- control-plane counters (async admission) -----
+        self.admission_counts = {"ticks": 0, "examined": 0, "assigned": 0}
         self._t: Dict[Tuple[str, object], float] = {}
         self._lock = threading.Lock()
 
@@ -201,6 +205,16 @@ class FarmTelemetry:
         """One assignment's wall on ``slot``: pickup to terminal message."""
         with self._lock:
             self.assign_s[slot] += wall_s
+
+    def admission(self, examined: int, assigned: int):
+        """One async admission tick on the control thread: ``examined``
+        queued jobs taken up for placement, ``assigned`` jobs seated (each
+        member of a fused lane run counts)."""
+        with self._lock:
+            c = self.admission_counts
+            c["ticks"] += 1
+            c["examined"] += examined
+            c["assigned"] += assigned
 
     def queue_wait(self, slot: str, wait_s: float):
         with self._lock:
@@ -439,6 +453,7 @@ class FarmTelemetry:
                 ("scope_samples", self.scope_samples)) if log.dropped}
             scope = self._scope_report_locked()
             control = self.control.report()
+            admission = dict(self.admission_counts)
             per_thread = ([d["compiles"] for d in devices.values()]
                           + [control["compiles"]])
             compiles = {
@@ -473,7 +488,8 @@ class FarmTelemetry:
             "scope": scope,
             "events_dropped": dropped,
             "control": {"phases": control["phases"],
-                        "compiles": control["compiles"]},
+                        "compiles": control["compiles"],
+                        "admission": admission},
             "compiles": compiles,
             "clock_origin": self.clock_origin,
         }

@@ -1,8 +1,10 @@
 """Async ZP-Farm tests: per-slot dispatcher threads vs the lockstep
 oracle — bit-identical outputs (plain runs, forced eviction + requeue,
 checkpoint DrainBarrier veto mid-stream), wall-time straggler eviction,
-thread confinement of each job's dispatches, hung-board abandonment, and
-the per-slot host-overhead telemetry."""
+thread confinement of each job's dispatches, hung-board abandonment, the
+per-slot host-overhead telemetry, and admission that stops walking the
+queue once no seat is free."""
+import random
 import threading
 import time
 
@@ -13,7 +15,9 @@ import pytest
 
 from repro.core import DrainBarrier, iter_windows
 from repro.core.watchdog import Watchdog
-from repro.farm import FarmJob, FarmManager
+from repro.farm import FarmError, FarmJob, FarmManager
+from repro.farm.manager import _SlotWorker
+from repro.farm.placement import enumerate_slots
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -260,3 +264,230 @@ def test_async_telemetry_reports_host_overhead_channels():
     # 4 jobs over 2 slots: at least one slot went idle between assignments
     assert any(d["idle_ms"]["n"] > 0 for d in t["devices"].values())
     assert "host:" in mgr.telemetry.summary()
+
+
+# ----------------------------------------------------- admission walk ----
+def _control_state(n_jobs, n_slots=4, full=(0, 1, 2, 3), running=True):
+    """An async farm's control-plane state with no slot thread started:
+    ``n_jobs`` queued, the slots in ``full`` at capacity (depth 1) and, if
+    ``running``, runs in flight. One admission tick can then be driven by
+    hand and its decisions read off the slot inboxes."""
+    mgr = FarmManager(slots=n_slots, mode="async")
+    for j in range(n_jobs):
+        mgr.submit(FarmJob(name=f"q{j}", engine=_engine,
+                           windows=_windows(j, n_items=2),
+                           state=jnp.float32(0), shell={}, stack_fn=_stack))
+    mgr.slots = enumerate_slots(min_slots=n_slots)
+    mgr._workers = {s.name: _SlotWorker(mgr, s, 1) for s in mgr.slots}
+    mgr._slot_load = {s.name: int(s.index in full) for s in mgr.slots}
+    if running:
+        mgr._running = {-1 - i: object() for i in range(len(full))}
+    return mgr
+
+
+def _seated(mgr):
+    """slot -> names of the jobs the tick put in its inbox."""
+    out = {}
+    for name, w in mgr._workers.items():
+        while not w.inbox.empty():
+            out.setdefault(name, []).append(w.inbox.get_nowait().job.name)
+    return out
+
+
+def _admission(mgr):
+    return mgr.telemetry.report()["control"]["admission"]
+
+
+@pytest.mark.parametrize("fourth", ["full", "lost", "benched", "probing"])
+def test_admission_tick_with_every_seat_full_examines_nothing(fourth):
+    """All seats full and runs in flight: a tick over 2000 queued jobs
+    takes none of them up and leaves the queue exactly as it was. An idle
+    slot that is lost, benched or out on a probe offers no seat."""
+    if fourth == "full":
+        mgr = _control_state(2000)
+    else:
+        mgr = _control_state(2000, full=(0, 1, 2))
+        name = mgr.slots[3].name
+        if fourth == "benched":
+            mgr._benched[name] = 0.0
+        else:
+            getattr(mgr, "_" + fourth).add(name)
+    before = [j.name for j in mgr.queue]
+    mgr._assign_async()
+    assert [j.name for j in mgr.queue] == before
+    assert _seated(mgr) == {}
+    assert _admission(mgr) == {"ticks": 1, "examined": 0, "assigned": 0}
+
+
+def test_admission_walk_stops_at_the_last_free_seat():
+    """One seat free: a job backing off and a job that avoids that seat
+    are deferred, the third takes the seat, and the walk stops there: the
+    deferred pair goes back ahead of the untouched rest, in order."""
+    mgr = _control_state(50, full=(0, 1, 2))
+    free = mgr.slots[3].name
+    mgr.queue[0].not_before = float("inf")      # backing off
+    mgr._avoid["q1"] = free                     # only seat is its avoided one
+    mgr._assign_async()
+    assert _seated(mgr) == {free: ["q2"]}
+    assert [j.name for j in mgr.queue] == (
+        ["q0", "q1"] + [f"q{j}" for j in range(3, 50)])
+    assert _admission(mgr) == {"ticks": 1, "examined": 3, "assigned": 1}
+
+
+def test_admission_passes_over_a_job_whose_only_seat_is_its_avoided_one():
+    """The avoid preference holds while something runs: the job whose only
+    free seat is its old one stays queued with its preference, and the job
+    behind it takes the seat."""
+    mgr = _control_state(2, full=(0, 1, 2))
+    free = mgr.slots[3].name
+    mgr._avoid["q0"] = free
+    mgr._assign_async()
+    assert _seated(mgr) == {free: ["q1"]}
+    assert [j.name for j in mgr.queue] == ["q0"]
+    assert mgr._avoid == {"q0": free}
+
+
+@pytest.mark.parametrize("backing_off", [False, True])
+def test_admission_with_no_seat_and_nothing_running(backing_off):
+    """No seat anywhere and nothing running: the farm is out of seats and
+    says so, unless a job is backing off, when the tick yields and waits
+    for the gate as it always did."""
+    mgr = _control_state(20, full=(), running=False)
+    mgr._lost = {s.name for s in mgr.slots}
+    if backing_off:
+        mgr.queue[7].not_before = float("inf")
+        mgr._assign_async()
+        assert len(mgr.queue) == 20 and _seated(mgr) == {}
+    else:
+        with pytest.raises(FarmError, match="no live slots"):
+            mgr._assign_async()
+
+
+def _full_walk(mgr):
+    """Reference admission tick: take up every queued job, whether or not
+    a seat is left (the walk before it learned to stop)."""
+    assigned, deferred, backing_off = 0, [], False
+    now = mgr.clock()
+    while mgr.queue:
+        job = mgr.queue.popleft()
+        if job.not_before > now:
+            deferred.append(job)
+            backing_off = True
+            continue
+        slot = mgr._pick_async_slot(mgr._avoid.get(job.name))
+        if slot is None:
+            deferred.append(job)
+            continue
+        mgr._avoid.pop(job.name, None)
+        mgr._dispatch_to_slot(job, slot)
+        assigned += 1
+    mgr.queue.extendleft(reversed(deferred))
+    if not assigned and not mgr._running and mgr.queue and not backing_off:
+        slot = mgr._pick_async_slot(None)
+        if slot is not None:
+            job = mgr.queue.popleft()
+            mgr._avoid.pop(job.name, None)
+            mgr._dispatch_to_slot(job, slot)
+        elif not (set(mgr._benched) | mgr._probing):
+            raise FarmError("no live slots left to place queued jobs")
+
+
+def _random_control_state(seed):
+    """A control-plane state drawn from ``seed``: slots lost, benched,
+    probing or loaded up to a random depth; runs in flight or not; queued
+    jobs backing off, avoiding a slot, or lane-coalescible."""
+    rng = random.Random(seed)
+    depth = rng.choice([1, 1, 2, 3])
+    n_slots = rng.randint(1, 5)
+    mgr = FarmManager(slots=n_slots, mode="async", slot_queue_depth=depth,
+                      clock=lambda: 100.0)
+    mgr.slots = enumerate_slots(min_slots=n_slots,
+                                lane_capacity=rng.choice([1, 1, 2, 3]))
+    mgr._workers = {s.name: _SlotWorker(mgr, s, depth) for s in mgr.slots}
+    mgr._slot_load = {s.name: 0 for s in mgr.slots}
+    names = [s.name for s in mgr.slots]
+    for name in names:
+        r = rng.random()
+        if r < 0.1:
+            mgr._lost.add(name)
+        elif r < 0.2:
+            mgr._benched[name] = 0.0
+        elif r < 0.25:
+            mgr._probing.add(name)
+        else:
+            mgr._slot_load[name] = rng.randint(0, depth)
+    if rng.random() < 0.6:
+        mgr._running = {-1 - i: object() for i in range(rng.randint(1, 3))}
+    for j in range(rng.choice([0, 1, 3, 10, 50])):
+        job = mgr.submit(FarmJob(
+            name=f"q{j}", engine=_engine, windows=_windows(j, n_items=2),
+            state=jnp.float32(0), shell={}, stack_fn=_stack,
+            lane_key="k" if rng.random() < 0.5 else None))
+        if rng.random() < 0.15:
+            job.not_before = 200.0
+        if rng.random() < 0.2:
+            mgr._avoid[job.name] = rng.choice(names)
+    return mgr
+
+
+def _decisions(mgr, tick):
+    try:
+        tick()
+        err = None
+    except FarmError:
+        err = "no seats"
+    placed = {}
+    for name, w in mgr._workers.items():
+        while not w.inbox.empty():
+            run = w.inbox.get_nowait()
+            placed.setdefault(name, []).append(
+                [m.name for m in (run.lanes or [run.job])])
+    return (placed, [j.name for j in mgr.queue], sorted(mgr._avoid.items()),
+            mgr._slot_load, [j.attempts for j in mgr.jobs], err)
+
+
+def test_admission_tick_decides_as_the_full_walk_does():
+    """Over random control states (seat loads, lost / benched / probing
+    slots, backoff, avoid marks, lane coalescing, runs in flight or
+    none), one tick seats the same jobs on the same slots, leaves the
+    same queue order and raises where the full walk raises."""
+    seated = raised = 0
+    for seed in range(300):
+        got = _random_control_state(seed)
+        want = _random_control_state(seed)
+        d_got = _decisions(got, got._assign_async)
+        d_want = _decisions(want, lambda: _full_walk(want))
+        assert d_got == d_want, seed
+        seated += bool(d_got[0])
+        raised += d_got[-1] is not None
+    assert seated > 100 and raised > 0     # both branches were exercised
+
+
+def test_fault_free_farm_counts_admission_and_matches_lockstep():
+    """A fault-free 4-slot farm of 48 short boards seats every board once,
+    in queue order, examining no job it does not seat, and delivers the
+    lockstep oracle's outputs. Straggler eviction is off: on a loaded host
+    it would requeue a board, and the farm would not be fault-free."""
+    lock_mgr = FarmManager(slots=4, mode="lockstep")
+    base = _submit(lock_mgr, n_jobs=48, n_items=4)
+    lock_mgr.run()
+    mgr = FarmManager(slots=4, mode="async", evict_stragglers=False)
+    col = _submit(mgr, n_jobs=48, n_items=4)
+    order = []
+    dispatch = mgr._dispatch_to_slot
+
+    def spy(job, slot):
+        order.append(job.name)
+        return dispatch(job, slot)
+
+    mgr._dispatch_to_slot = spy
+    rep = mgr.run()
+    assert all(j["status"] == "done" for j in rep["jobs"].values())
+    assert order == [f"job{s}" for s in range(48)]
+    adm = rep["telemetry"]["control"]["admission"]
+    assert adm["examined"] == adm["assigned"] == 48
+    assert adm["ticks"] >= 12        # 4 seats: at least 12 ticks seat jobs
+    for name in base:
+        assert len(col[name]) == len(base[name]) == 2
+        for a, b in zip(base[name], col[name]):
+            np.testing.assert_array_equal(a, b)

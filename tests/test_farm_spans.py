@@ -209,6 +209,23 @@ def test_last_report_is_the_newest_run():
     assert last["drain_vetoes"] >= 1
 
 
+def test_control_report_counts_admission():
+    """The control table carries the async admission counter: integer
+    ticks, jobs examined and jobs assigned, where every assignment is one
+    attempt of one job, and a fault-free farm examines only what it
+    seats (straggler eviction off, so that no board is requeued)."""
+    mgr = FarmManager(slots=2, mode="async", evict_stragglers=False)
+    for j in range(6):
+        mgr.submit(_job(f"job{j}", j))
+    rep = mgr.run()
+    adm = rep["telemetry"]["control"]["admission"]
+    assert set(adm) == {"ticks", "examined", "assigned"}
+    assert all(isinstance(v, int) for v in adm.values())
+    assert adm["assigned"] == sum(j.attempts for j in mgr.jobs) == 6
+    assert adm["examined"] == adm["assigned"]
+    assert adm["ticks"] >= 3
+
+
 def test_window_scheduler_profile_uses_slot_phase_names():
     """The solo WindowScheduler times the slot thread's phases into the
     profiler it is given: stack, dispatch, fetch, verify and commit."""

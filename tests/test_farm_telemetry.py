@@ -42,6 +42,19 @@ def test_report_channel_schema_includes_percentiles():
     assert dev["dispatch_ms"]["p99"] == pytest.approx(20.0)
 
 
+def test_admission_counter_accumulates_ticks():
+    """The control table's admission counter sums every tick, and reads
+    zero on a farm that never admitted asynchronously."""
+    tm = FarmTelemetry()
+    assert tm.report()["control"]["admission"] == {
+        "ticks": 0, "examined": 0, "assigned": 0}
+    tm.admission(examined=3, assigned=1)
+    tm.admission(examined=0, assigned=0)
+    tm.admission(examined=2, assigned=4)    # a fused lane run seats four
+    assert tm.report()["control"]["admission"] == {
+        "ticks": 3, "examined": 5, "assigned": 5}
+
+
 # ------------------------------------------------------------ stall stack --
 def test_dominant_stall_attribution_per_slot():
     """The slot's host-overhead channel sums fold into a StallStack whose
