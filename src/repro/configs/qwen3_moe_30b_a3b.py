@@ -1,5 +1,7 @@
 """Qwen3-30B-A3B: 48L d_model=2048 32H (GQA kv=4) moe_d_ff=768 vocab=151936,
-MoE 128 experts top-8. [hf:Qwen/Qwen3-30B-A3B]"""
+MoE 128 experts top-8 (renormalised), q/k head RMSNorm, rms_norm_eps 1e-6.
+Source: https://huggingface.co/Qwen/Qwen3-30B-A3B (config.json) and the
+Qwen3 Technical Report (arXiv:2505.09388)."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -18,6 +20,7 @@ CONFIG = ModelConfig(
     moe_d_ff=768,
     use_qk_norm=True,
     rope_theta=1e6,
+    norm_eps=1e-6,
 )
 
 SMOKE = ModelConfig(
@@ -36,4 +39,5 @@ SMOKE = ModelConfig(
     moe_d_ff=96,
     use_qk_norm=True,
     rope_theta=1e6,
+    norm_eps=1e-6,
 )

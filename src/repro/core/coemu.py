@@ -296,6 +296,13 @@ class CommitStreamVerifier:
     blocking read of its checksums) and ``oracle.compare`` (the host
     compare); a digest hit opens only the first.
 
+    Placement: the oracle runs on the device that was JAX's default
+    device when the verifier was built (``jax.default_device``). The farm
+    builds a board's initial state under its slot's device, so a verifier
+    made by a job's state factory replays on its DUT's chip: before the
+    first window it verifies it moves its oracle state there, and each
+    batch as it is taken. With no default device set, nothing is moved.
+
     Mid-stream resume (the farm's checkpointed-requeue protocol):
     :meth:`snapshot` captures the oracle's position — host-copied state,
     global step, and the number of batches consumed — and
@@ -323,6 +330,9 @@ class CommitStreamVerifier:
         self.expected_digests = expected_digests or {}
         self.digest_hits = 0        # windows verified by digest alone
         self.max_rel_err = 0.0
+        dev = jax.config.jax_default_device
+        self.device = dev if isinstance(dev, jax.Device) else None
+        self._placed = self.device is None
 
     def _iter_batches(self):
         b = self._batches_src
@@ -331,10 +341,15 @@ class CommitStreamVerifier:
     def _next_batch(self):
         batch = next(self.batches)
         self._consumed += 1
+        if self.device is not None:
+            batch = jax.device_put(batch, self.device)
         return batch
 
     def __call__(self, last_step: int, records, digest: Optional[int] = None,
                  window: Optional[int] = None):
+        if not self._placed:
+            self.state = jax.device_put(self.state, self.device)
+            self._placed = True
         rows = np.asarray(records["fifos"]["commits"]["data"], np.float64)
         steps = rows.shape[0] // self.L
         # Digest first pass: the on-device fold matched the precomputed
@@ -384,6 +399,7 @@ class CommitStreamVerifier:
                 "source (sequence or zero-arg factory); a one-shot "
                 "iterator cannot be rewound to the snapshot position")
         self.state = snap["state"]
+        self._placed = self.device is None
         self.step = int(snap["step"])
         self._consumed = int(snap["consumed"])
         self.batches = itertools.islice(self._iter_batches(),

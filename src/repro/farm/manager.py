@@ -490,6 +490,7 @@ class _SlotWorker(threading.Thread):
                         run.scope_wall_acc += wall
                 if job.capture is not None:
                     job.capture.on_drain(plan, records, ys)
+                mgr.telemetry.moe_routing(job.name, records)
             if run.lanes is not None:
                 # per-lane fan-out + verify on the slot thread; a veto
                 # masks ITS lane only (this thread owns lane_faults, so
@@ -2059,6 +2060,7 @@ class FarmManager(ClientPolicy):
         self.telemetry.drain(run.slot.name, self._key(run, plan))
         if run.job.capture is not None:
             run.job.capture.on_drain(plan, records, ys)
+        self.telemetry.moe_routing(run.job.name, records)
         if run.lanes is not None:
             delivered, faulted = self._lane_ingest(run, plan, records, ys)
             for lane, rec, y in delivered:

@@ -47,6 +47,10 @@ holds the control thread's table and its ``admission`` counter (async
 admission ticks, jobs examined, jobs assigned: :meth:`admission`), and
 ``compiles`` the totals with a bounded log of recompiles (``{slot, job,
 window, s}``: a compile inside a window after a job's window 0).
+``moe`` holds the routing counter ``routing`` (:meth:`moe_routing`):
+decode steps, token-expert pairs, distinct experts touched summed over
+layer-steps, and the largest group, summed over jobs (the largest group
+the largest) from the ``moe_routing`` CSR of the decode shell.
 ``clock_origin`` is the ``perf_counter`` start of the ``zp.farm.run``
 span, so a host-clock stamp ``t`` lies at ``t - clock_origin`` into that
 span on the profiler's timeline.
@@ -71,6 +75,8 @@ import threading
 import time
 from collections import defaultdict, deque
 from typing import Callable, Dict, List, Optional, Tuple
+
+import numpy as np
 
 from repro.core.profiler import Profiler, StallStack
 
@@ -170,6 +176,8 @@ class FarmTelemetry:
         self.clock_origin: Optional[float] = None
         # ----- control-plane counters (async admission) -----
         self.admission_counts = {"ticks": 0, "examined": 0, "assigned": 0}
+        # ----- shell counters: job -> latest cumulative moe_routing CSR -----
+        self.routing_jobs: Dict[str, tuple] = {}
         self._t: Dict[Tuple[str, object], float] = {}
         self._lock = threading.Lock()
 
@@ -215,6 +223,21 @@ class FarmTelemetry:
             c["ticks"] += 1
             c["examined"] += examined
             c["assigned"] += assigned
+
+    def moe_routing(self, job: str, records):
+        """From a drained window's shell ``records``, keep the job's
+        cumulative ``moe_routing`` CSR ([steps, pairs, touched, largest];
+        a lane run carries one row per lane), read from the drain already
+        in hand. Records without it are left alone."""
+        csrs = records.get("csrs") if isinstance(records, dict) else None
+        if not csrs or "moe_routing" not in csrs:
+            return
+        rows = [[int(v) for v in row] for row in
+                np.asarray(csrs["moe_routing"]).reshape(-1, 4)]
+        row = (sum(r[0] for r in rows), sum(r[1] for r in rows),
+               sum(r[2] for r in rows), max(r[3] for r in rows))
+        with self._lock:
+            self.routing_jobs[job] = row
 
     def queue_wait(self, slot: str, wait_s: float):
         with self._lock:
@@ -454,6 +477,11 @@ class FarmTelemetry:
             scope = self._scope_report_locked()
             control = self.control.report()
             admission = dict(self.admission_counts)
+            rows = list(self.routing_jobs.values())
+            routing = {"steps": sum(r[0] for r in rows),
+                       "pairs": sum(r[1] for r in rows),
+                       "touched": sum(r[2] for r in rows),
+                       "largest": max((r[3] for r in rows), default=0)}
             per_thread = ([d["compiles"] for d in devices.values()]
                           + [control["compiles"]])
             compiles = {
@@ -490,6 +518,7 @@ class FarmTelemetry:
             "control": {"phases": control["phases"],
                         "compiles": control["compiles"],
                         "admission": admission},
+            "moe": {"routing": routing},
             "compiles": compiles,
             "clock_origin": self.clock_origin,
         }

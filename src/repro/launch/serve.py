@@ -5,7 +5,9 @@ workload-agnostic, not a training-loop special case.
 Decode runs as scan-fused windows of ``sample_interval`` autoregressive
 steps: ONE jit dispatch per window (donated cache), with a decode FIFO in
 the P-Shell carrying per-token telemetry ([step, mean token id, max
-logit]) and a ``tokens`` CSR counting emissions. The scheduler
+logit]), a ``tokens`` CSR counting emissions and a ``moe_routing`` CSR
+counting the experts' routing (decode steps, token-expert pairs, distinct
+experts touched summed over layer-steps, the largest group). The scheduler
 double-buffers the shell so the host drain of window *i* — where the
 blocking token fetch and the per-window decode-latency sample land —
 overlaps window *i+1*'s in-flight decode.
@@ -25,8 +27,8 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import Watchdog, WindowScheduler
-from repro.core.pshell import (FifoSpec, ShellConfig, csr_accum, drain,
-                               fifo_push, shell_init)
+from repro.core.pshell import (FifoSpec, ShellConfig, csr_accum, csr_read,
+                               csr_write, drain, fifo_push, shell_init)
 from repro.data.pipeline import make_batch_fn
 from repro.models import build_model
 from repro.models.runtime import Runtime
@@ -37,12 +39,24 @@ from repro.utils import enable_compile_cache
 
 def decode_shell_config(sample_interval: int) -> ShellConfig:
     """Decode-telemetry shell: one FIFO row per generated token (depth one
-    clock-gated window — lossless at any interval), plus a token counter."""
+    clock-gated window — lossless at any interval), a token counter and
+    the routing counter (:func:`count_routing`)."""
     return ShellConfig(
-        csrs={"tokens": jax.ShapeDtypeStruct((), jnp.int32)},
+        csrs={"tokens": jax.ShapeDtypeStruct((), jnp.int32),
+              "moe_routing": jax.ShapeDtypeStruct((4,), jnp.int32)},
         fifos={"decode": FifoSpec(depth=max(1, sample_interval), shape=(3,),
                                   dtype=jnp.float32)},
         sample_interval=sample_interval)
+
+
+def count_routing(shell, routing):
+    """Add one decode step's routing (``Model.decode_step_routed``) to the
+    ``moe_routing`` CSR: [steps, pairs, experts touched, largest group],
+    the first three summed, the last the largest seen."""
+    cur = csr_read(shell, "moe_routing")
+    step = jnp.concatenate([jnp.ones((1,), jnp.int32), routing[:2]])
+    return csr_write(shell, "moe_routing", jnp.concatenate(
+        [cur[:3] + step, jnp.maximum(cur[3:], routing[2:])]))
 
 
 def make_decode_engine(model):
@@ -58,13 +72,15 @@ def make_decode_engine(model):
 
         def body(carry, idx):
             cache, tok, sh = carry
-            cache, logits = model.decode_step(params, cache, tok)
+            cache, logits, routing = model.decode_step_routed(params, cache,
+                                                              tok)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
             payload = jnp.stack([idx.astype(jnp.float32),
                                  jnp.mean(tok.astype(jnp.float32)),
                                  jnp.max(logits).astype(jnp.float32)])
             sh = fifo_push(sh, "decode", payload)
             sh = csr_accum(sh, "tokens", jnp.int32(tok.shape[0]), op="add")
+            sh = count_routing(sh, routing)
             return (cache, tok, sh), tok
 
         (cache, tok, shell), toks = jax.lax.scan(

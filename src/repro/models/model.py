@@ -6,6 +6,7 @@
   logits(params, batch) -> (logits, aux)
   prefill(params, batch, max_len) -> (cache, last_logits)
   decode_step(params, cache, tokens1) -> (cache, logits)   [serve_step]
+  decode_step_routed(params, cache, tokens1) -> (cache, logits, routing)
   cache_spec(batch, max_len) -> ShapeDtypeStruct tree
 
 ``input_specs(cfg, shape)`` returns ShapeDtypeStruct stand-ins for every
@@ -122,17 +123,28 @@ class Model:
 
     def decode_step(self, params, cache, tokens1):
         """serve_step: one new token against the standing cache."""
+        cache, logits, _ = self.decode_step_routed(params, cache, tokens1)
+        return cache, logits
+
+    def decode_step_routed(self, params, cache, tokens1):
+        """:meth:`decode_step` plus the step's expert routing, a (3,)
+        int32: token-expert pairs and distinct experts touched, each
+        summed over the MoE layers, and the largest group of any layer
+        (zeros for a model without experts)."""
         cfg, rt = self.cfg, self.rt
         if cfg.family == "encdec":
-            return ed.encdec_decode_step(params, cfg, cache, tokens1, rt)
+            cache, logits = ed.encdec_decode_step(params, cfg, cache,
+                                                  tokens1, rt)
+            return cache, logits, jnp.zeros((3,), jnp.int32)
         pos = cache["pos"]
         B = tokens1.shape[0]
         x = embed_apply(params["embed"], tokens1,
                         jnp.full((B, 1), pos, jnp.int32)
                         if cfg.learned_pos else None)
-        x, cache = tfm.stack_decode(params["stack"], cfg, x, cache, rt)
+        x, cache, routing = tfm.stack_decode(params["stack"], cfg, x, cache,
+                                             rt)
         x = norm_apply(cfg, params["final_norm"], x)
-        return cache, logits_apply(params, cfg, x)
+        return cache, logits_apply(params, cfg, x), routing
 
 
 def build_model(cfg: ModelConfig, rt: Runtime = Runtime()) -> Model:

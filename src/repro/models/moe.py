@@ -2,17 +2,24 @@
 
 - ``dense``: one-hot all-experts oracle. O(T*E) compute — smoke/test configs
   only; the golden model for the other two.
-- ``sort``:  capacity-based sort dispatch, single-shard semantics. Under pjit
-  with expert weights F-sharded over "model" this becomes Expert-TP ("etp"):
-  no all-to-all, one all-reduce, zero load imbalance — the right strategy for
+- ``sort``:  with no mesh, DROPLESS sort dispatch: the T*k token-expert
+  pairs are sorted by expert and the SwiGLU expert FFN runs as grouped
+  products over the contiguous ragged groups (``jax.lax.ragged_dot``), so
+  no pair is dropped at any load and ``dropped_frac`` is 0. Under a mesh
+  it is capacity-based and SPMD-local (``_moe_sort_local``); with expert
+  weights F-sharded over "model" this is Expert-TP ("etp"): no all-to-all,
+  one all-reduce, zero load imbalance — the right strategy for
   few-large-expert archs (mixtral: 8 experts of d_ff 14336).
 - ``a2a``:   shard_map expert parallelism over the "model" mesh axis with
   explicit all_to_all dispatch/return — the right strategy for
-  many-small-expert archs (qwen3: 128 experts of d_ff 768).
+  many-small-expert archs (qwen3: 128 experts of d_ff 768). Capacity-based.
 
 All impls share the same router and emit the same stats pytree, which feeds
 the P-Shell commit stream (router decisions) and coverage bitmaps (expert
-toggles) — DESIGN.md C3/C6.
+toggles) — DESIGN.md C3/C6. The stages run under the named scopes
+``zp.moe.route``, ``zp.moe.dispatch``, ``zp.moe.experts`` and
+``zp.moe.combine``, so they carry those names in the op metadata of every
+program that runs the layer.
 """
 from __future__ import annotations
 
@@ -45,6 +52,7 @@ def init_moe(key, cfg):
     }
 
 
+@jax.named_scope("zp.moe.route")
 def _route(p, cfg, x2):
     """x2: (T, D) -> gates (T,k) f32, idx (T,k) i32, probs (T,E) f32."""
     logits = (x2.astype(jnp.float32) @ p["router"]["w"])
@@ -52,6 +60,15 @@ def _route(p, cfg, x2):
     gates, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
     gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates, idx, probs
+
+
+def routing_counts(stats, pairs: int):
+    """``(3,)`` int32 of one layer-step's routing: token-expert ``pairs``,
+    distinct experts touched, and the largest group (pairs on one
+    expert), read from the router ``stats`` of that layer-step."""
+    touched = jnp.sum(stats["expert_toggles"].astype(jnp.int32))
+    largest = jnp.round(jnp.max(stats["load"]) * pairs).astype(jnp.int32)
+    return jnp.stack([jnp.int32(pairs), touched, largest])
 
 
 def _stats(cfg, idx, probs, dropped_frac):
@@ -76,10 +93,12 @@ def _moe_dense(p, cfg, x2):
     gates, idx, probs = _route(p, cfg, x2)
     combine = jnp.zeros((x2.shape[0], E), jnp.float32)
     combine = combine.at[jnp.arange(x2.shape[0])[:, None], idx].add(gates)
-    g = jax.nn.silu(jnp.einsum("td,edf->tef", x2, p["gate"]))
-    u = jnp.einsum("td,edf->tef", x2, p["up"])
-    y_e = jnp.einsum("tef,efd->ted", g * u, p["down"])
-    y = jnp.einsum("ted,te->td", y_e.astype(jnp.float32), combine)
+    with jax.named_scope("zp.moe.experts"):
+        g = jax.nn.silu(jnp.einsum("td,edf->tef", x2, p["gate"]))
+        u = jnp.einsum("td,edf->tef", x2, p["up"])
+        y_e = jnp.einsum("tef,efd->ted", g * u, p["down"])
+    with jax.named_scope("zp.moe.combine"):
+        y = jnp.einsum("ted,te->td", y_e.astype(jnp.float32), combine)
     return y.astype(x2.dtype), _stats(cfg, idx, probs, jnp.float32(0.0))
 
 
@@ -90,6 +109,7 @@ def _capacity(cfg, n_tokens: int, n_experts: int) -> int:
     return max(8, -(-c // 8) * 8)  # round up to 8
 
 
+@jax.named_scope("zp.moe.dispatch")
 def _sort_dispatch(cfg, x2, idx):
     """Returns (disp (E,C,D), gather_idx (T*k,), keep (T*k,), inv_order)."""
     T, D = x2.shape
@@ -111,6 +131,7 @@ def _sort_dispatch(cfg, x2, idx):
     return disp[:-1].reshape(E, C, D), slot, keep, inv_order, counts
 
 
+@jax.named_scope("zp.moe.combine")
 def _sort_combine(cfg, y_ecd, slot, keep, inv_order, gates, T, D):
     flat = jnp.concatenate(
         [y_ecd.reshape(-1, D), jnp.zeros((1, D), y_ecd.dtype)], axis=0)
@@ -123,6 +144,7 @@ def _sort_combine(cfg, y_ecd, slot, keep, inv_order, gates, T, D):
     return y
 
 
+@jax.named_scope("zp.moe.experts")
 def _expert_ffn(p, h_ecd):
     g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", h_ecd, p["gate"]))
     u = jnp.einsum("ecd,edf->ecf", h_ecd, p["up"])
@@ -137,6 +159,40 @@ def _moe_sort(p, cfg, x2):
     y = _sort_combine(cfg, y_ecd, slot, keep, inv_order, gates, T, D)
     dropped = 1.0 - jnp.mean(keep.astype(jnp.float32))
     return y.astype(x2.dtype), _stats(cfg, idx, probs, dropped)
+
+
+# --------------------------------------------------------------- dropless ---
+def _moe_dropless(p, cfg, x2, layer=None):
+    """Every token-expert pair computed: the pairs sorted by expert form
+    contiguous ragged groups, one grouped product per expert weight.
+
+    With ``layer``, ``p``'s expert weights are a stack of ``n`` layers'
+    (``(n, E, D, F)``) and this is layer ``layer`` of it: the stack is
+    read in place as ``n * E`` groups, all empty but this layer's, so only
+    the experts the tokens touch are read. (A slice of the stack would be
+    a copy of every expert of the layer first.)"""
+    T, D = x2.shape
+    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    gates, idx, probs = _route(p, cfg, x2)
+    w = {n: p[n] for n in ("gate", "up", "down")}
+    with jax.named_scope("zp.moe.dispatch"):
+        flat_e = idx.reshape(-1)                              # (T*k,)
+        order = jnp.argsort(flat_e, stable=True)
+        sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+        xs = x2[order // k]                                   # (T*k, D)
+        if layer is not None:
+            n = w["gate"].shape[0]
+            w = {a: v.reshape((n * E,) + v.shape[2:]) for a, v in w.items()}
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * E,), jnp.int32), sizes, (layer * E,))
+    with jax.named_scope("zp.moe.experts"):
+        g = jax.nn.silu(jax.lax.ragged_dot(xs, w["gate"], sizes))
+        u = jax.lax.ragged_dot(xs, w["up"], sizes)
+        ys = jax.lax.ragged_dot(g * u, w["down"], sizes)      # (T*k, D)
+    with jax.named_scope("zp.moe.combine"):
+        vals = ys[jnp.argsort(order)].reshape(T, k, D)
+        y = jnp.sum(vals.astype(jnp.float32) * gates[..., None], axis=1)
+    return y.astype(x2.dtype), _stats(cfg, idx, probs, jnp.float32(0.0))
 
 
 # -------------------------------------------------------------------- a2a ---
@@ -257,9 +313,14 @@ def _moe_sort_local(p, cfg, x, mesh, data_axes, model_axis="model"):
 
 # ------------------------------------------------------------------ entry ---
 def moe_apply(p, cfg, x, *, impl: str = "sort", mesh=None,
-              data_axes=("data",), model_axis: str = "model"):
-    """x: (B, S, D) -> (y, stats)."""
+              data_axes=("data",), model_axis: str = "model", layer=None):
+    """x: (B, S, D) -> (y, stats). With no mesh, ``sort`` is dropless.
+    ``layer`` (dropless only): ``p``'s expert weights are a stack of
+    layers' and this is that layer of it (see ``_moe_dropless``)."""
     B, S, D = x.shape
+    if layer is not None and (impl != "sort" or mesh is not None):
+        raise ValueError("a layer of an expert stack is read in place only "
+                         "by the dropless path (impl='sort', no mesh)")
     if impl == "a2a":
         if mesh is None:
             raise ValueError("a2a MoE dispatch requires a mesh")
@@ -270,7 +331,7 @@ def moe_apply(p, cfg, x, *, impl: str = "sort", mesh=None,
     if impl == "dense":
         y, st = _moe_dense(p, cfg, x2)
     elif impl == "sort":
-        y, st = _moe_sort(p, cfg, x2)
+        y, st = _moe_dropless(p, cfg, x2, layer)
     else:
         raise ValueError(f"unknown moe impl {impl!r}")
     return y.reshape(B, S, D), st

@@ -105,7 +105,11 @@ def block_cache_spec(cfg, spec, batch: int, max_len: int):
     return ssm_mod.mamba_state_spec(cfg, batch)
 
 
-def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime):
+def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime, layer=None):
+    """One-token decode of one block: (x1, cache, routing), ``routing``
+    the layer-step's (3,) :func:`~repro.models.moe.routing_counts`
+    (zeros for a block without experts). ``layer``: see
+    :func:`_hoist_experts`."""
     mixer, ffn = spec
     h = norm_apply(cfg, p["norm1"], x1)
     if mixer in _ATTN_KINDS:
@@ -118,6 +122,7 @@ def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime):
     else:
         y, cache = ssm_mod.mamba_decode(p["mamba"], cfg, h, cache)
     x1 = x1 + y
+    routing = jnp.zeros((3,), jnp.int32)
     if ffn is not None:
         h2 = norm_apply(cfg, p["norm2"], x1)
         if ffn == "mlp":
@@ -125,14 +130,28 @@ def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime):
         else:
             # decode uses shard-local sort dispatch (B tokens; a2a is a
             # prefill/train strategy — the sequence dim is 1 here)
-            y2, _ = moe_mod.moe_apply(p["moe"], cfg, h2, impl="sort",
-                                      mesh=rt.mesh, data_axes=rt.data_axes)
+            y2, stats = moe_mod.moe_apply(p["moe"], cfg, h2, impl="sort",
+                                          mesh=rt.mesh,
+                                          data_axes=rt.data_axes,
+                                          layer=layer)
+            pairs = h2.shape[0] * h2.shape[1] * cfg.num_experts_per_tok
+            routing = moe_mod.routing_counts(stats, pairs)
         x1 = x1 + y2
-    return x1, cache
+    return x1, cache, routing
 
 
-def block_prefill(p, cfg, spec, x, positions, max_len: int, rt: Runtime):
-    """Full-seq forward that also emits this block's decode cache."""
+def fold_routing(rows):
+    """Routing counts of several layer-steps (..., 3) -> (3,): pairs and
+    experts touched summed, the largest group the largest."""
+    rows = rows.reshape(-1, 3)
+    return jnp.concatenate([jnp.sum(rows[:, :2], axis=0),
+                            jnp.max(rows[:, 2:], axis=0, initial=0)])
+
+
+def block_prefill(p, cfg, spec, x, positions, max_len: int, rt: Runtime,
+                  layer=None):
+    """Full-seq forward that also emits this block's decode cache.
+    ``layer``: see :func:`_hoist_experts`."""
     mixer, ffn = spec
     h = norm_apply(cfg, p["norm1"], x)
     if mixer in _ATTN_KINDS:
@@ -171,7 +190,8 @@ def block_prefill(p, cfg, spec, x, positions, max_len: int, rt: Runtime):
         else:
             y2, _ = moe_mod.moe_apply(
                 p["moe"], cfg, h2, impl=rt.moe_impl, mesh=rt.mesh,
-                data_axes=rt.data_axes, model_axis=rt.model_axis)
+                data_axes=rt.data_axes, model_axis=rt.model_axis,
+                layer=layer)
         x = x + y2
     return x, cache
 
@@ -182,6 +202,32 @@ def _partition(cfg):
     n_periods = cfg.num_layers // P_len
     remainder = cfg.num_layers % P_len
     return P_len, n_periods, remainder
+
+
+_EXPERT_STACKS = ("gate", "up", "down")
+
+
+def _hoist_experts(blocks, spec, rt: Runtime, impl: str):
+    """For serving (prefill and decode) with no mesh: split a scanned
+    period position's routed-expert weights out of the scan's inputs.
+    Returns (the position's stacked params without them, the whole expert
+    stacks or None). A scan over a stack slices each layer's params out
+    of it, and a slice of an expert stack is a copy of every expert of
+    the layer, a read and a write of all of them per layer-step, where a
+    decode step needs only the experts its tokens touch. The dropless
+    expert layer instead reads layer ``i`` of the whole stacks in place
+    (``moe_apply(..., layer=i)``). Training keeps the slices: its
+    gradients come back per layer from the scan."""
+    if spec[1] != "moe" or rt.mesh is not None or impl != "sort":
+        return blocks, None
+    moe = blocks["moe"]
+    experts = {k: moe[k] for k in _EXPERT_STACKS}
+    rest = {k: v for k, v in moe.items() if k not in _EXPERT_STACKS}
+    return {**blocks, "moe": rest}, experts
+
+
+def _with_experts(p, experts):
+    return p if experts is None else {**p, "moe": {**p["moe"], **experts}}
 
 
 def init_stack(key, cfg):
@@ -242,33 +288,47 @@ def stack_cache_spec(cfg, batch: int, max_len: int):
 
 
 def stack_decode(stack, cfg, x1, cache, rt: Runtime):
-    """One-token decode through all layers; returns (x1, new_cache)."""
+    """One-token decode through all layers; returns (x1, new_cache,
+    routing), ``routing`` the step's :func:`fold_routing` over layers."""
     P_len, n_periods, remainder = _partition(cfg)
     pattern = cfg.layer_pattern
     pos = cache["pos"]
 
     new_cache = dict(cache)
+    rows = []
     if n_periods > 0:
-        def period_body(x, inp):
-            params_p, cache_p = inp
-            new_c = []
-            for i in range(P_len):
-                x, c = block_decode(params_p[i], cfg, pattern[i],
-                                    x, cache_p[i], pos, rt)
-                new_c.append(c)
-            return x, tuple(new_c)
+        split = [_hoist_experts(stack["blocks"][i], pattern[i], rt,
+                                "sort") for i in range(P_len)]
 
-        x1, new_scanned = jax.lax.scan(
-            period_body, x1, (stack["blocks"], cache["scanned"]))
+        def period_body(x, inp):
+            params_p, cache_p, period = inp
+            new_c, routes = [], []
+            for i in range(P_len):
+                experts = split[i][1]
+                x, c, r = block_decode(
+                    _with_experts(params_p[i], experts), cfg, pattern[i],
+                    x, cache_p[i], pos, rt,
+                    layer=None if experts is None else period)
+                new_c.append(c)
+                routes.append(r)
+            return x, (tuple(new_c), jnp.stack(routes))
+
+        x1, (new_scanned, routes) = jax.lax.scan(
+            period_body, x1, (tuple(b for b, _ in split), cache["scanned"],
+                              jnp.arange(n_periods)))
         new_cache["scanned"] = new_scanned
+        rows.append(routes.reshape(-1, 3))
     tail_new = []
     for i, p in enumerate(stack["tail"]):
-        x1, c = block_decode(p, cfg, pattern[i % P_len], x1,
-                             cache["tail"][i], pos, rt)
+        x1, c, r = block_decode(p, cfg, pattern[i % P_len], x1,
+                                cache["tail"][i], pos, rt)
         tail_new.append(c)
+        rows.append(r[None])
     new_cache["tail"] = tuple(tail_new)
     new_cache["pos"] = pos + 1
-    return x1, new_cache
+    routing = (fold_routing(jnp.concatenate(rows)) if rows
+               else jnp.zeros((3,), jnp.int32))
+    return x1, new_cache, routing
 
 
 def stack_prefill(stack, cfg, x, positions, max_len: int, rt: Runtime):
@@ -277,16 +337,24 @@ def stack_prefill(stack, cfg, x, positions, max_len: int, rt: Runtime):
 
     cache: Dict[str, Any] = {}
     if n_periods > 0:
-        def period_body(x, params_p):
+        split = [_hoist_experts(stack["blocks"][i], pattern[i], rt,
+                                rt.moe_impl) for i in range(P_len)]
+
+        def period_body(x, inp):
+            params_p, period = inp
             caches = []
             for i in range(P_len):
-                x, c = block_prefill(params_p[i], cfg, pattern[i], x,
-                                     positions, max_len, rt)
+                experts = split[i][1]
+                x, c = block_prefill(
+                    _with_experts(params_p[i], experts), cfg, pattern[i], x,
+                    positions, max_len, rt,
+                    layer=None if experts is None else period)
                 caches.append(c)
             return x, tuple(caches)
 
         body = rt.checkpoint(period_body)
-        x, cache["scanned"] = jax.lax.scan(body, x, stack["blocks"])
+        x, cache["scanned"] = jax.lax.scan(
+            body, x, (tuple(b for b, _ in split), jnp.arange(n_periods)))
     else:
         cache["scanned"] = ()
     tail_c = []

@@ -207,8 +207,8 @@ def decode_cost(cfg, shape, mesh, rt: Runtime) -> Dict[str, float]:
         pos = jnp.asarray(shape.seq_len, jnp.int32)
         new = []
         for i in range(len(pattern)):
-            x, c = tfm.block_decode(pp[i], cfg, pattern[i], x, caches[i],
-                                    pos, rt_cost)
+            x, c, _ = tfm.block_decode(pp[i], cfg, pattern[i], x,
+                                       caches[i], pos, rt_cost)
             new.append(c)
         return x, tuple(new)
 

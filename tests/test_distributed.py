@@ -237,3 +237,58 @@ def test_dryrun_cell_small_mesh():
                "n_sites": colls["n_sites"]}
     """)
     assert out["n_sites"] > 0 and out["eff_bytes"] > 0
+
+
+def test_train_boards_verify_on_their_own_chip():
+    """Four verified train boards on four devices: each board's oracle
+    (a CommitStreamVerifier built by its state factory from state and
+    batches that live on device 0) replays on its DUT's device, and every
+    commit is accepted."""
+    out = run_sub("""
+        from repro.configs import get_smoke_config
+        from repro.core.coemu import CommitStreamVerifier
+        from repro.farm import FarmJob, FarmManager
+        from repro.launch.farm import _train_board_parts
+        from repro.models import build_model
+        from repro.models.runtime import Runtime
+        from repro.train.step import init_state, make_train_step
+        cfg = get_smoke_config("granite-8b")
+        parts = _train_board_parts(cfg, 4, 2, batch=2, seq=16, seed=0)
+        model = build_model(cfg, Runtime(taps=frozenset({"commits"})))
+        s0 = jax.jit(lambda k: init_state(model, k))(jax.random.key(0))
+        oracle = jax.jit(make_train_step(model))
+        batches = [jax.device_put(b) for w in parts["windows"] for b in w]
+        dev = lambda tree: sorted({str(d) for leaf in jax.tree.leaves(tree)
+                                   for d in leaf.devices()})
+        seen = {}
+        mgr = FarmManager(slots=4, mode="async", evict_stragglers=False)
+        for b in range(4):
+            box = {}
+
+            def state(box=box):
+                box["v"] = CommitStreamVerifier(
+                    oracle, s0, batches=lambda: iter(batches),
+                    layers=cfg.num_layers, rtol=1e-3)
+                return jax.tree.map(jnp.copy, s0)
+
+            def verify(plan, records, ys, box=box, name=f"train{b}"):
+                box["v"](plan.last, records)
+                seen[name] = {"dut": dev(ys), "oracle": dev(box["v"].state)}
+
+            mgr.submit(FarmJob(
+                name=f"train{b}", engine=parts["engine"],
+                windows=parts["windows"], state=state, shell=parts["shell"],
+                drain_fn=parts["drain_fn"], stack_fn=parts["stack_fn"],
+                verify=verify, max_requeues=0))
+        report = mgr.run()
+        out = {"seen": seen, "status": {n: j["status"]
+                                        for n, j in report["jobs"].items()},
+               "s0": dev(s0)}
+    """, devices=4)
+    assert set(out["status"].values()) == {"done"}, out
+    assert out["s0"] == ["TFRT_CPU_0"]
+    duts = [v["dut"] for v in out["seen"].values()]
+    assert all(len(d) == 1 for d in duts)
+    assert len({d[0] for d in duts}) == 4, out
+    for v in out["seen"].values():
+        assert v["oracle"] == v["dut"], out
