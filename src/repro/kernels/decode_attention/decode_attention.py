@@ -8,9 +8,14 @@ one kv head are processed together so the score matmul is (G x hd)·(hd x bk)
 so each k/v block's last two dims are (block, hd), with the batch and kv-head
 axes squeezed: the tiling the TPU compiler accepts for any kv-head count.
 
-The current position ``pos`` arrives via SMEM (scalar memory), mirroring how
-a CSR would parameterize a ZynqParrot hardware timer: the kernel masks ring
-slots that are not yet valid (slot > pos while the ring is not full).
+The cache may be a stack of layers' caches (n, B, K, W, hd): the layer to
+read is a scalar, so a decode loop that carries the stack hands it over
+whole and the kernel reads its layer in place, with no copy of the slice.
+
+The current position ``pos`` and the ``layer`` arrive via SMEM (scalar
+memory), mirroring how a CSR would parameterize a ZynqParrot hardware
+timer: the kernel masks ring slots that are not yet valid (slot > pos
+while the ring is not full).
 """
 from __future__ import annotations
 
@@ -24,7 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
+def _kernel(scalars, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
             bk: int, nk: int, softcap: float, scale: float, W: int):
     ik = pl.program_id(2)
 
@@ -34,7 +39,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    pos = pos_ref[0]
+    pos = scalars[0]
     q = q_ref[...]                                   # (G, hd)
     k = k_ref[...]                                   # (bk, hd)
     v = v_ref[...]
@@ -64,27 +69,28 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         o_ref[...] = (acc[...] / l).astype(o_ref.dtype)
 
 
-def decode_attention_kernel(q, k, v, pos_arr, *, softcap: float,
+def decode_attention_kernel(q, k, v, scalars, *, softcap: float,
                             block_k: int, W: int, interpret: bool = False):
-    """q: (B, K, G, hd); k/v: (B, K, Wp, hd); pos_arr: (1,) i32."""
+    """q: (B, K, G, hd); k/v: (n, B, K, Wp, hd), a stack of n layers';
+    scalars: (2,) i32, [pos, layer]."""
     B, K, G, hd = q.shape
-    Wp = k.shape[2]
+    Wp = k.shape[3]
     nk = Wp // block_k
     kernel = functools.partial(_kernel, bk=block_k, nk=nk, softcap=softcap,
                                scale=hd ** -0.5, W=W)
+    kv_spec = pl.BlockSpec((None, None, None, block_k, hd),
+                           lambda b, kv, ik, s: (s[1], b, kv, ik, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, K, nk),
         in_specs=[
             pl.BlockSpec((None, None, G, hd),
-                         lambda b, kv, ik, pos: (b, kv, 0, 0)),
-            pl.BlockSpec((None, None, block_k, hd),
-                         lambda b, kv, ik, pos: (b, kv, ik, 0)),
-            pl.BlockSpec((None, None, block_k, hd),
-                         lambda b, kv, ik, pos: (b, kv, ik, 0)),
+                         lambda b, kv, ik, s: (b, kv, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((None, None, G, hd),
-                               lambda b, kv, ik, pos: (b, kv, 0, 0)),
+                               lambda b, kv, ik, s: (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, hd), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -98,4 +104,4 @@ def decode_attention_kernel(q, k, v, pos_arr, *, softcap: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pos_arr, q, k, v)
+    )(scalars, q, k, v)

@@ -59,6 +59,15 @@ def count_routing(shell, routing):
         [cur[:3] + step, jnp.maximum(cur[3:], routing[2:])]))
 
 
+#: Compiler options of the decode window on a TPU. XLA's memory-space
+#: assignment can hold a weight stack that the layer scan reads one layer
+#: at a time in fast memory across the window, then evict it and fetch it
+#: back every layer step: two copies of the whole stack for a read of one
+#: layer, in a window bound by memory bandwidth. A ratio of 1 refuses any
+#: such placement whose copies move more bytes than its uses read.
+TPU_DECODE_OPTIONS = {"xla_tpu_msa_inefficient_use_to_copy_ratio": 1.0}
+
+
 def make_decode_engine(model):
     """Scheduler engine for decode: state=(params, cache, last_token);
     scans one decode step per window slot, pushing telemetry into the
@@ -87,7 +96,8 @@ def make_decode_engine(model):
             body, (state[1], state[2], shell), idx_stack)
         return (params, cache, tok), shell, toks
 
-    return jax.jit(engine, donate_argnums=(0,))
+    return jax.jit(engine, donate_argnums=(0,), compiler_options=(
+        TPU_DECODE_OPTIONS if jax.default_backend() == "tpu" else None))
 
 
 def serve(cfg, batch: int, prompt_len: int, gen: int, seed: int = 0,

@@ -253,41 +253,64 @@ def init_cache(cfg, batch: int, max_len: int, window: int):
                         cache_spec(cfg, batch, max_len, window))
 
 
+def _write_token(c, t, slot, layer):
+    """Write the new token ``t`` (B,1,K,hd) at ring slot ``slot`` of the
+    cache ``c``: one layer's (B,W,K,hd), or with ``layer`` a stack of
+    layers' (n,B,W,K,hd), updated in place at ``layer``."""
+    if layer is None:
+        return jax.lax.dynamic_update_slice(c, t, (0, slot, 0, 0))
+    return jax.lax.dynamic_update_slice(c, t[None], (layer, 0, slot, 0, 0))
+
+
 def decode_attention_apply(p, cfg, x, cache, pos, *, window: int = 0,
                            impl: str = "xla", mesh=None,
-                           data_axes=("data",)):
+                           data_axes=("data",), layer=None):
     """One-token decode. x: (B,1,D); pos: scalar int32 (current index).
 
     Appends the new k/v at ring slot ``pos % W`` then attends over the cache.
     Keys stored post-RoPE at absolute positions (relative-correct under ring).
-    With a mesh, uses the distributed flash-decode path (sequence-sharded
-    cache, psum-combined softmax stats — §Perf change #3).
+    With ``layer``, ``cache`` holds a stack of layers' caches (leading
+    axis) and this is layer ``layer`` of it: the token is written into the
+    stack in place and the whole stack comes back, so a scan that carries
+    the stack never copies it. With a mesh, uses the distributed
+    flash-decode path (sequence-sharded cache, psum-combined softmax stats
+    — §Perf change #3), on the layer's slice written back whole.
     """
     B = x.shape[0]
-    W = cache["k"].shape[1]
+    W = cache["k"].shape[-3]
     pvec = jnp.full((B, 1), pos, jnp.int32)
     q, k, v = _project_qkv(p, cfg, x, x, pvec, pvec, rope=True)
     if mesh is not None and impl == "xla" \
             and W % mesh.shape.get("model", 1) == 0:
-        out, cache = _decode_attention_sharded(
-            cfg, q, k, v, cache, pos, mesh=mesh, data_axes=data_axes,
+        one = cache if layer is None else jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False),
+            cache)
+        out, one = _decode_attention_sharded(
+            cfg, q, k, v, one, pos, mesh=mesh, data_axes=data_axes,
             softcap=cfg.attn_logit_softcap)
-        return dense_apply(p["o"], out), cache
+        if layer is not None:
+            one = jax.tree.map(
+                lambda c, o: jax.lax.dynamic_update_index_in_dim(
+                    c, o, layer, 0), cache, one)
+        return dense_apply(p["o"], out), one
     slot = jnp.mod(pos, W)
-    ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
+    cache = {"k": _write_token(cache["k"], k, slot, layer),
+             "v": _write_token(cache["v"], v, slot, layer)}
 
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels.decode_attention import ops as da_ops
+        # the kernel reads the layer of the carried stack in place
         out = da_ops.decode_attention(
-            q[:, 0], ck, cv, pos=pos, window=W,
+            q[:, 0], cache["k"], cache["v"], pos=pos, window=W, layer=layer,
             softcap=cfg.attn_logit_softcap,
             interpret=(impl == "pallas_interpret"))[:, None]
         out = out.reshape(B, 1, -1)
     else:
+        ck, cv = (c if layer is None else
+                  jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+                  for c in (cache["k"], cache["v"]))
         slots = jnp.arange(W)
         valid = slots <= pos  # ring full once pos+1 >= W: all true anyway
         mask = jnp.broadcast_to(valid[None, :], (1, W))
         out = _attend(cfg, q, ck, cv, mask)
-    y = dense_apply(p["o"], out)
-    return y, {"k": ck, "v": cv}
+    return dense_apply(p["o"], out), cache

@@ -108,19 +108,30 @@ def block_cache_spec(cfg, spec, batch: int, max_len: int):
 def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime, layer=None):
     """One-token decode of one block: (x1, cache, routing), ``routing``
     the layer-step's (3,) :func:`~repro.models.moe.routing_counts`
-    (zeros for a block without experts). ``layer``: see
-    :func:`_hoist_experts`."""
+    (zeros for a block without experts). ``layer``: None for a block
+    outside the scanned stack; else this block's stacked inputs are read
+    at index ``layer``: its cache (a stack of layers' caches, returned
+    whole with layer ``layer`` updated in place) and the routed experts
+    :func:`_hoist_experts` took out of the scan."""
     mixer, ffn = spec
     h = norm_apply(cfg, p["norm1"], x1)
     if mixer in _ATTN_KINDS:
         y, cache = attn.decode_attention_apply(
             p["attn"], cfg, h, cache, pos,
             window=_mixer_window(cfg, mixer), impl=rt.attention_impl,
-            mesh=rt.mesh, data_axes=rt.data_axes)
-    elif mixer == "rglru":
-        y, cache = rec_mod.rglru_decode(p["rglru"], cfg, h, cache)
+            mesh=rt.mesh, data_axes=rt.data_axes, layer=layer)
     else:
-        y, cache = ssm_mod.mamba_decode(p["mamba"], cfg, h, cache)
+        # recurrent states are small: read the layer's, write it back whole
+        state = cache if layer is None else jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, layer, keepdims=False),
+            cache)
+        if mixer == "rglru":
+            y, state = rec_mod.rglru_decode(p["rglru"], cfg, h, state)
+        else:
+            y, state = ssm_mod.mamba_decode(p["mamba"], cfg, h, state)
+        cache = state if layer is None else jax.tree.map(
+            lambda c, s: jax.lax.dynamic_update_index_in_dim(c, s, layer, 0),
+            cache, state)
     x1 = x1 + y
     routing = jnp.zeros((3,), jnp.int32)
     if ffn is not None:
@@ -129,11 +140,12 @@ def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime, layer=None):
             y2 = mlp_apply(p["mlp"], h2)
         else:
             # decode uses shard-local sort dispatch (B tokens; a2a is a
-            # prefill/train strategy — the sequence dim is 1 here)
-            y2, stats = moe_mod.moe_apply(p["moe"], cfg, h2, impl="sort",
-                                          mesh=rt.mesh,
-                                          data_axes=rt.data_axes,
-                                          layer=layer)
+            # prefill/train strategy — the sequence dim is 1 here); with a
+            # mesh the scan slices the experts per layer (_hoist_experts)
+            y2, stats = moe_mod.moe_apply(
+                p["moe"], cfg, h2, impl="sort", mesh=rt.mesh,
+                data_axes=rt.data_axes,
+                layer=None if rt.mesh is not None else layer)
             pairs = h2.shape[0] * h2.shape[1] * cfg.num_experts_per_tok
             routing = moe_mod.routing_counts(stats, pairs)
         x1 = x1 + y2
@@ -300,23 +312,23 @@ def stack_decode(stack, cfg, x1, cache, rt: Runtime):
         split = [_hoist_experts(stack["blocks"][i], pattern[i], rt,
                                 "sort") for i in range(P_len)]
 
-        def period_body(x, inp):
-            params_p, cache_p, period = inp
-            new_c, routes = [], []
+        def period_body(carry, inp):
+            x, stacks = carry
+            params_p, period = inp
+            new_s, routes = [], []
             for i in range(P_len):
-                experts = split[i][1]
                 x, c, r = block_decode(
-                    _with_experts(params_p[i], experts), cfg, pattern[i],
-                    x, cache_p[i], pos, rt,
-                    layer=None if experts is None else period)
-                new_c.append(c)
+                    _with_experts(params_p[i], split[i][1]), cfg,
+                    pattern[i], x, stacks[i], pos, rt, layer=period)
+                new_s.append(c)
                 routes.append(r)
-            return x, (tuple(new_c), jnp.stack(routes))
+            return (x, tuple(new_s)), jnp.stack(routes)
 
-        x1, (new_scanned, routes) = jax.lax.scan(
-            period_body, x1, (tuple(b for b, _ in split), cache["scanned"],
-                              jnp.arange(n_periods)))
-        new_cache["scanned"] = new_scanned
+        # the cache stack rides in the carry, each layer updated in place:
+        # as the scan's xs/ys it would be copied whole every token step
+        (x1, new_cache["scanned"]), routes = jax.lax.scan(
+            period_body, (x1, cache["scanned"]),
+            (tuple(b for b, _ in split), jnp.arange(n_periods)))
         rows.append(routes.reshape(-1, 3))
     tail_new = []
     for i, p in enumerate(stack["tail"]):

@@ -206,6 +206,47 @@ def test_sequence_parallel_numerics():
     assert abs(out["l_off"] - out["l_on"]) < 1e-3, out
 
 
+def test_sharded_decode_matches_one_device():
+    """Decode under a (data, model) mesh (the sequence-sharded flash-decode
+    path, each scanned layer's cache slice read and written back into the
+    carried stack) gives one device's logits and caches, to float32
+    rounding of the psum-combined softmax: a KV cache and RG-LRU states."""
+    out = run_sub("""
+        import dataclasses
+        from repro.configs import get_smoke_config
+        from repro.models import build_model
+        from repro.models.runtime import Runtime
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        out = {}
+        for arch in ("granite-8b", "recurrentgemma-2b"):
+            cfg = dataclasses.replace(get_smoke_config(arch),
+                                      dtype="float32")
+            plain = build_model(cfg)
+            sharded = build_model(cfg, Runtime(mesh=mesh,
+                                               data_axes=("data",)))
+            params = plain.init(jax.random.key(0))
+            toks = jax.random.randint(jax.random.key(1), (2, 12), 0,
+                                      cfg.vocab_size)
+            cache, logits = jax.jit(lambda p, b: plain.prefill(p, b, 32))(
+                params, {"tokens": toks})
+            c1 = c2 = cache
+            s1 = jax.jit(plain.decode_step)
+            s2 = jax.jit(sharded.decode_step)
+            gap = 0.0
+            for _ in range(6):
+                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+                c1, logits = s1(params, c1, tok)
+                c2, l2 = s2(params, c2, tok)
+                gap = max([gap, float(jnp.max(jnp.abs(logits - l2)))] + [
+                    float(jnp.max(jnp.abs(a - b)))
+                    for a, b in zip(jax.tree.leaves(c1),
+                                    jax.tree.leaves(c2))])
+            out[arch] = gap
+    """)
+    assert all(gap < 1e-4 for gap in out.values()), out
+
+
 def test_dryrun_cell_small_mesh():
     """The dry-run machinery itself on an 8-device mesh (fast CI variant)."""
     out = run_sub("""

@@ -112,6 +112,26 @@ def test_decode_attention(W, pos, H, K):
     assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("W", [64, 100])
+def test_decode_attention_reads_a_layer_of_a_stack(W):
+    """Given a stack of layers' caches and a layer index, the kernel reads
+    that layer in place: the output of the kernel on the layer's slice
+    (a ragged W, padded, included)."""
+    ks = jax.random.split(jax.random.key(5), 3)
+    n, B, H, K, hd = 3, 2, 8, 2, 32
+    q = rand(ks[0], (B, H, hd))
+    k = rand(ks[1], (n, B, W, K, hd))
+    v = rand(ks[2], (n, B, W, K, hd))
+    for layer in range(n):
+        out = da_ops.decode_attention(q, k, v, pos=jnp.int32(W // 2),
+                                      window=W, layer=jnp.int32(layer),
+                                      block_k=32, interpret=True)
+        one = da_ops.decode_attention(q, k[layer], v[layer],
+                                      pos=jnp.int32(W // 2), window=W,
+                                      block_k=32, interpret=True)
+        assert_allclose(np.asarray(out), np.asarray(one), rtol=0, atol=0)
+
+
 # ----------------------------------------------------------- grouped gemm ---
 @pytest.mark.parametrize("E,M,K,N", [
     (4, 128, 64, 128), (3, 50, 33, 17), (1, 8, 8, 8), (8, 256, 128, 64)])

@@ -2,6 +2,9 @@
 one train-gradient step + prefill/decode on CPU; asserts shapes and no NaNs.
 
 The FULL configs are exercised only via the dry-run (ShapeDtypeStruct)."""
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +12,8 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_smoke_config, get_config
 from repro.models import build_model, input_specs
+from repro.models import transformer as tfm
+from repro.models.layers import embed_apply, logits_apply, norm_apply
 from repro.models.model import decode_cache_len
 from repro.models.runtime import Runtime
 
@@ -91,6 +96,78 @@ def test_prefill_decode(arch, rng):
         assert logits.shape == (B, 1, cfg.vocab_size)
         assert np.isfinite(np.asarray(logits)).all(), f"{arch}: NaN decode"
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+
+
+def _decode_layer_by_layer(model, params, cache, tok):
+    """Reference decode step, a plain loop over layers: each layer's
+    params and cache sliced out of the stacks, ``block_decode`` on the
+    slices (``layer=None``), the caches stacked back. -> (cache, logits)"""
+    cfg, rt = model.cfg, model.rt
+    pattern, stack, pos = cfg.layer_pattern, params["stack"], cache["pos"]
+    n = cfg.num_layers // len(pattern)
+    x = embed_apply(params["embed"], tok,
+                    jnp.full(tok.shape, pos, jnp.int32)
+                    if cfg.learned_pos else None)
+    per_layer = [[] for _ in pattern]
+    for layer in range(n):
+        for i, spec in enumerate(pattern):
+            take = lambda a: a[layer]
+            x, c, _ = tfm.block_decode(
+                jax.tree.map(take, stack["blocks"][i]), cfg, spec, x,
+                jax.tree.map(take, cache["scanned"][i]), pos, rt)
+            per_layer[i].append(c)
+    scanned = tuple(jax.tree.map(lambda *cs: jnp.stack(cs), *cs)
+                    for cs in per_layer) if n else ()
+    tail = []
+    for j, p in enumerate(stack["tail"]):
+        x, c, _ = tfm.block_decode(p, cfg, pattern[j % len(pattern)], x,
+                                   cache["tail"][j], pos, rt)
+        tail.append(c)
+    x = norm_apply(cfg, params["final_norm"], x)
+    return ({"scanned": scanned, "tail": tuple(tail), "pos": pos + 1},
+            logits_apply(params, cfg, x))
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "qwen3-moe-30b-a3b",
+                                  "recurrentgemma-2b", "falcon-mamba-7b"])
+def test_decode_step_equals_a_layer_by_layer_loop(arch, rng):
+    """The layer scan that carries the stacked cache and updates it in
+    place gives, bit for bit, the logits and every cache leaf of a plain
+    loop over layers: a KV cache (ring included: recurrentgemma's local
+    window of 16 wraps), RG-LRU and Mamba states, scanned and in the
+    unrolled tail. In float32: in bfloat16
+    the CPU compiler rounds fused intermediates differently in a scan
+    body and in unrolled layers. Routed experts agree to rounding only:
+    the scan reads layer i of the whole expert stack as n x E ragged
+    groups, which the CPU's ragged product sums in another order than a
+    slice's E groups (about 1e-6 here; a wrong layer is off by O(1))."""
+    cfg = get_smoke_config(arch)
+    # two scanned periods at least (recurrentgemma's smoke config has
+    # one), so that a wrong layer index shows; the tail kept
+    P_len = len(cfg.layer_pattern)
+    cfg = dataclasses.replace(cfg, dtype="float32", num_layers=max(
+        cfg.num_layers, 2 * P_len + cfg.num_layers % P_len))
+    model = build_model(cfg)
+    params = model.init(rng)
+    prompt, steps = 12, 8
+    assert not cfg.window or prompt + steps > cfg.window
+    if any(ffn == "moe" for _, ffn in cfg.layer_pattern):
+        same = functools.partial(np.testing.assert_allclose, rtol=0,
+                                 atol=1e-5)
+    else:
+        same = np.testing.assert_array_equal
+    cache, logits = jax.jit(lambda p, b: model.prefill(p, b, prompt + steps))(
+        params, make_batch(cfg, rng, seq=prompt, train=False))
+    ref_cache = cache
+    step = jax.jit(model.decode_step)
+    ref = jax.jit(lambda p, c, t: _decode_layer_by_layer(model, p, c, t))
+    for _ in range(steps):
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+        cache, logits = step(params, cache, tok)
+        ref_cache, ref_logits = ref(params, ref_cache, tok)
+        same(np.asarray(logits), np.asarray(ref_logits))
+        jax.tree.map(lambda a, b: same(np.asarray(a), np.asarray(b)),
+                     cache, ref_cache)
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
