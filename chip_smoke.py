@@ -103,7 +103,7 @@ def farm_phase(cfg, slots, shapes: dict) -> dict:
     from repro.launch.farm import run_farm
 
     t0 = time.perf_counter()
-    out = run_farm(cfg, slots, mode="async", **shapes)
+    out = run_farm(cfg, slots, **shapes)
     return {
         "phase": "farm", "arch": cfg.name, **shapes,
         "setup_s": out["setup_s"], "prewarm_s": out["prewarm_s"],
@@ -179,8 +179,7 @@ def multi_chip_phase(cfg, devices, shapes: dict) -> dict:
 
     runs = {}
     for label, devs in (("one_slot", devices[:1]), ("per_device", devices)):
-        out = run_farm(cfg, enumerate_slots(devices=devs), mode="async",
-                       **shapes)
+        out = run_farm(cfg, enumerate_slots(devices=devs), **shapes)
         runs[label] = out
         gc.collect()
     one, many = runs["one_slot"], runs["per_device"]

@@ -7,8 +7,8 @@ contract they declare is checked statically by
 the AST (no import of the annotated module is needed):
 
 ``@control_thread_only``
-    The method runs only on the farm's control thread (lockstep's single
-    host thread, or the async mode's admission/eviction loop). Attributes
+    The method runs only on the farm's control thread (the admission/
+    eviction loop that ``FarmManager.run`` runs on). Attributes
     it mutates are control-owned: a mutation of the same attribute from
     an unannotated or ``@any_thread`` method is a finding — the exact
     shape of the PR 7 ``force_evict`` race, where an any-thread test/CLI

@@ -8,7 +8,7 @@ from repro.core.pshell import (  # noqa: F401
     stack_batches)
 from repro.core.schedule import (  # noqa: F401
     WindowScheduler, WindowPlan, DrainBarrier, Client, ClientDriver,
-    ClientPolicy, plan_windows, iter_windows)
+    plan_windows, iter_windows)
 from repro.core.commit import default_shell_config, make_ingest  # noqa: F401
 from repro.core.coemu import CoEmulator  # noqa: F401
 from repro.core.coverage import CoverageMap  # noqa: F401

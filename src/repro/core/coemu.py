@@ -526,8 +526,8 @@ def verify_subsystems(params, cfg, rt, xs: Sequence, positions,
     """Multi-DUT (ZP-Farm) mode: verify several extracted subsystems as
     independent boards of one farm pass (see ``submit_subsystem_jobs``).
     ``farm=None`` builds a dedicated ``FarmManager`` with one slot per
-    subsystem — every board dispatches before any board's previous window
-    is fetched, exactly the paper's board-farm shape.
+    subsystem — each board runs on its own slot thread, exactly the
+    paper's board-farm shape.
 
     Note on tolerance: the scan-compiled replay may differ from the eager
     in-situ capture in low mantissa bits (XLA fusion/reassociation,

@@ -9,8 +9,8 @@ uses the same decomposition to extrapolate full-system cost from per-block
 dry-runs — the Scale-Up/Scale-Down cycle of Fig. 1.
 
 ``coemu.verify_subsystems`` drives several extracted blocks as independent
-DUT engines through one ``WindowScheduler.run_many`` pass against the
-captured boundary traffic — the multi-board ZP-Farm shape.
+DUT boards of one ZP-Farm pass (one slot thread per block) against the
+captured boundary traffic — the multi-board farm shape.
 """
 from __future__ import annotations
 

@@ -29,24 +29,21 @@ in-flight target execution):
 Engines must donate at most the model/opt state (argnum 0), never the
 shell: the snapshot must survive on the host until its deferred drain.
 
-``run_many`` schedules several engines through one pass — the ZP-Farm
-shape: many DUT boards, one host; window *w* of every engine is dispatched
-back-to-back before any engine's window *w-1* results are fetched, so every
-board's compute overlaps every board's drain. Farm hooks (all optional,
-the bare 4-tuple form is unchanged):
+``run_many`` schedules several engines through one pass on the calling
+thread — many DUT boards, one host; window *w* of every engine is
+dispatched back-to-back before any engine's window *w-1* results are
+fetched, so every board's compute overlaps every board's drain. It is the
+plain round-robin reference the farm (``repro.farm.manager``, one
+:class:`ClientDriver` per slot thread) is tested against. Its hooks (all
+optional, the bare 4-tuple form is unchanged):
 
   * per-client plumbing — a :class:`Client` carries its OWN drain_fn /
     stack_fn / reset, so one pass can mix shell-ful (train, decode) and
     shell-less (verify) boards;
   * device-aware dispatch — ``place_fn(k, stack)`` runs right before
-    client *k*'s engine call (the farm device_puts the window payload onto
-    the client's pinned device there), and ``on_dispatch(k, plan, state)``
-    fires right after the dispatch is enqueued;
-  * pluggable completion policy — a :class:`ClientPolicy` is consulted at
-    every round boundary (the farm's drain boundary): ``admit`` grows the
-    pass with new clients, ``evict`` cancels a straggling/faulted client
-    BEFORE its next dispatch (its undrained in-flight window is discarded,
-    never delivered), ``done`` frees the client's device slot.
+    client *k*'s engine call (device placement of the window payload),
+    and ``on_dispatch(k, plan, state)`` fires right after the dispatch is
+    enqueued.
 """
 from __future__ import annotations
 
@@ -130,42 +127,6 @@ class Client:
     # instrumentation plane — normalization binds the client's engine /
     # shell / drain / reset so on-device counters ride the window carry
     # (per-lane counter slices under a fused client)
-
-
-class ClientPolicy:
-    """Pluggable client-completion policy for :meth:`WindowScheduler.
-    run_many` (the ZP-Farm manager implements this). The scheduler consults
-    the policy once per scheduling round — a round is one window of every
-    live client, i.e. the farm's drain boundary:
-
-      ``admit(round_idx)`` -> iterable of new clients (tuples or
-          :class:`Client`) appended to the pass — dynamic admission; client
-          indices are assigned in admission order and never reused.
-      ``evict(k)`` -> True to cancel client *k* before its next dispatch.
-          The client's in-flight (undrained) window is DISCARDED, not
-          flushed: an evicted job is requeued and resumed elsewhere, so
-          partial results must never reach ``on_drain`` twice.
-      ``done(k, state, shell)`` — client *k* dispatched its last window and
-          its final drain was delivered; its device slot is free (the
-          admission point for the next queued job).
-      ``crashed(k, exc)`` -> True to ABSORB an exception raised while
-          driving client *k* (its dispatch/advance/flush): the client is
-          cancelled (in-flight windows discarded) and the pass continues —
-          the farm's requeue path for a crashing board. False (default)
-          re-raises: one board's crash kills the lockstep pass.
-    """
-
-    def admit(self, round_idx: int):
-        return ()
-
-    def evict(self, k: int) -> bool:
-        return False
-
-    def done(self, k: int, state, shell):
-        pass
-
-    def crashed(self, k: int, exc: BaseException) -> bool:
-        return False
 
 
 def plan_windows(steps: int, interval: int, start: int = 0) -> List[WindowPlan]:
@@ -378,10 +339,8 @@ class WindowScheduler:
     def run_many(self, clients, on_drain: Optional[Callable] = None, *,
                  on_dispatch: Optional[Callable] = None,
                  place_fn: Optional[Callable] = None,
-                 policy: Optional[ClientPolicy] = None,
-                 on_commit: Optional[Callable] = None,
-                 inject: Optional[Callable] = None):
-        """ZP-Farm pass: ``clients`` is a list of ``(engine, windows,
+                 on_commit: Optional[Callable] = None):
+        """Multi-board pass: ``clients`` is a list of ``(engine, windows,
         state, shell)`` tuples or :class:`Client`\\ s (per-client drain /
         stack / reset / barriers). Window *w* of EVERY client is dispatched
         before any client's window *w-1* is drained, so each engine's drain
@@ -392,83 +351,33 @@ class WindowScheduler:
 
         The per-client machinery lives in :class:`ClientDriver`; this
         method composes one driver per client round-robin on the CALLING
-        thread — the lockstep host loop, where one slow client's dispatch
-        delays every other client's next enqueue. The async farm composes
-        the same drivers one-per-thread instead (``repro.farm.manager``),
-        which is why the driver owns all of a client's JAX interactions.
+        thread — the plain reference the farm is tested against. The farm
+        composes the same drivers one-per-slot-thread instead
+        (``repro.farm.manager``), which is why the driver owns all of a
+        client's JAX interactions.
 
         ``on_drain(client_idx, plan, records, ys)``;
         ``on_dispatch(client_idx, plan, state)`` fires right after a
         client's window dispatch is enqueued; ``place_fn(client_idx,
         stack)`` maps the stacked window payload right before the engine
-        call (device placement); ``policy`` is a :class:`ClientPolicy` for
-        dynamic admission / eviction / slot-free notification;
-        ``on_commit(client_idx, plan, state, shell)`` fires after a
-        client's barrier actions committed a window boundary (the farm's
-        snapshot hook); ``inject(client_idx, point, plan)`` is the fault-
-        injection hook threaded into every driver (see
-        :class:`ClientDriver`). A driver raising while driven is offered
-        to ``policy.crashed(k, exc)`` — absorbed crashes cancel the client
-        and the pass continues. Returns the list of final ``(state,
-        shell)`` per client index (admitted clients included, in admission
-        order)."""
-        def make(c):
-            return self.driver(c, key=len(drivers), on_drain=on_drain,
+        call (device placement); ``on_commit(client_idx, plan, state,
+        shell)`` fires after a client's barrier actions committed a window
+        boundary. Returns the list of final ``(state, shell)`` per client
+        index."""
+        drivers = [self.driver(c, key=k, on_drain=on_drain,
                                on_dispatch=on_dispatch, place_fn=place_fn,
-                               on_commit=on_commit, inject=inject)
-
-        def absorb(d, exc):
-            # a crashing board: discard its in-flight windows and let the
-            # policy requeue it, instead of one crash killing the pass
-            if policy is not None and policy.crashed(d.key, exc):
-                d.cancel()
-                return True
-            return False
-
-        drivers: List[ClientDriver] = []
-        for c in clients:
-            drivers.append(make(c))
-        rnd = 0
-        while True:
-            if policy is not None:
-                for c in policy.admit(rnd):
-                    drivers.append(make(c))
-            if all(d.exhausted for d in drivers):
-                break
-            progressed = []
-            finished = []
-            for k, d in enumerate(drivers):
-                if d.exhausted:
-                    continue
-                if policy is not None and policy.evict(k):
-                    d.cancel()              # discard, never deliver
-                    continue
-                try:
-                    plan = d.dispatch()
-                except Exception as e:      # noqa: BLE001 — policy decides
-                    if absorb(d, e):
-                        continue
-                    raise
-                if plan is None:
-                    finished.append(d)
-                else:
-                    progressed.append(d)
+                               on_commit=on_commit)
+                   for k, c in enumerate(clients)]
+        while not all(d.exhausted for d in drivers):
+            progressed, finished = [], []
+            for d in drivers:
+                if not d.exhausted:
+                    (progressed if d.dispatch() is not None
+                     else finished).append(d)
             for d in finished:          # after every live client dispatched
-                try:
-                    d.flush()
-                except Exception as e:      # noqa: BLE001 — policy decides
-                    if absorb(d, e):
-                        continue
-                    raise
-                if policy is not None:
-                    policy.done(d.key, d.state, d.shell)
+                d.flush()
             for d in progressed:
-                try:
-                    d.advance()
-                except Exception as e:      # noqa: BLE001 — policy decides
-                    if not absorb(d, e):
-                        raise
-            rnd += 1
+                d.advance()
         for d in drivers:
             d.flush()
         return [(d.state, d.shell) for d in drivers]
@@ -513,7 +422,7 @@ class ClientDriver:
     drains, and per-client :class:`DrainBarrier` commits — so a caller can
     confine a client's JAX dispatches to one thread (the async farm's
     per-slot dispatcher threads) or compose many drivers round-robin on a
-    single thread (the lockstep :meth:`WindowScheduler.run_many`). The
+    single thread (the reference :meth:`WindowScheduler.run_many`). The
     driver itself takes no locks: it must only ever be touched from the
     thread that drives it.
 

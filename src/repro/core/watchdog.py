@@ -4,28 +4,24 @@ The paper's boards carry hardware watchdog timers so a hung DUT can never
 take down the farm; the cluster analogue is worker heartbeats with a
 checkpoint-restart policy and straggler flagging for 1000+-node runs.
 Host-side pure Python; injected clock for deterministic tests. All
-channels are lock-protected: in the async farm every slot's dispatcher
-thread beats/observes concurrently while the control plane reads.
+channels are lock-protected: in the farm every slot's dispatcher thread
+beats/observes concurrently while the control plane reads.
 
 Two channels per worker, deliberately separate:
 
   liveness  — ``heartbeat(worker)``: "this worker made progress now".
               Dead-worker detection compares the last beat against
-              ``timeout_s``. Under the async farm this is TRUE wall-time
+              ``timeout_s``. In the farm this is TRUE wall-time
               liveness: each slot thread beats at its own drain
               boundaries, so a hung board stops beating regardless of
-              what its neighbors are doing (in the lockstep loop a hung
-              board stalled everyone's beats at once).
+              what its neighbors are doing.
   duration  — inter-heartbeat gaps (the default) OR explicit
-              ``observe(worker, dt)`` samples. The LOCKSTEP host loop
-              makes inter-drain gaps the ROUND time — identical for every
-              board and useless for telling boards apart — so it observes
-              each board's own dispatch duration explicitly and beats with
-              ``gap=False``. The ASYNC farm observes each window's
-              measured WALL time (dispatch to results-in-hand, taken on
-              the slot's own thread), which is the true per-board
-              divergence signal the straggler detector keys on. Each
-              sample is tagged with the observing thread's name
+              ``observe(worker, dt)`` samples. The farm beats with
+              ``gap=False`` and observes each window's measured WALL time
+              (dispatch to results-in-hand, taken on the slot's own
+              thread) — the one duration meaning it has, and the true
+              per-board divergence signal the straggler detector keys on.
+              Each sample is tagged with the observing thread's name
               (``threads``) so per-thread attribution survives requeues.
 
 A third channel closes the wall-clock-pollution flake class (ZP-Scope):
@@ -68,7 +64,7 @@ class Watchdog:
         """Liveness beat. ``gap=True`` (default) also records the gap since
         the worker's previous beat as a duration sample; ``gap=False`` is a
         pure liveness beat for callers that feed durations via
-        :meth:`observe` instead (both farm host loops)."""
+        :meth:`observe` instead (the farm's slot threads)."""
         now = self.clock()
         with self._lock:
             if gap and worker in self.last_beat:
@@ -78,14 +74,13 @@ class Watchdog:
 
     def observe(self, worker: str, duration_s: float, lanes: int = 1,
                 work: Optional[float] = None, quiet: bool = False):
-        """Record an explicitly measured duration sample (one window's
-        dispatch cost in lockstep mode, one window's measured wall in async
-        mode) without touching liveness state. Tagged with the calling
-        thread's name — in the async farm each worker's samples must all
-        come from its own slot thread. ``lanes`` normalizes a lane-batched
-        window to per-board cost: a 16-lane dispatch does 16 boards of
-        work per window, and must not be flagged as a 16x straggler
-        against solo boards on the same fleet.
+        """Record an explicitly measured duration sample (in the farm, one
+        window's measured wall) without touching liveness state. Tagged
+        with the calling thread's name — in the farm each worker's samples
+        must all come from its own slot thread. ``lanes`` normalizes a
+        lane-batched window to per-board cost: a 16-lane dispatch does 16
+        boards of work per window, and must not be flagged as a 16x
+        straggler against solo boards on the same fleet.
 
         ``work`` switches the sample to the device-side WORK-RATE channel:
         ``duration_s`` spanned ``work`` on-device work units (scope

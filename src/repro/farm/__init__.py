@@ -1,8 +1,9 @@
 """ZP-Farm: multi-device co-emulation farm manager (DESIGN C8 scaled out).
 
 The FireSim-manager analog for this repo: a job queue + device placement +
-per-device watchdogs + straggler eviction over one
-``WindowScheduler.run_many`` pass, plus the ZP-Chaos hardening layer —
+per-device watchdogs + straggler eviction, with one dispatcher thread per
+device slot driving its boards' ``ClientDriver`` pipelines, plus the
+ZP-Chaos hardening layer —
 :class:`FailurePolicy` (retry budgets, quarantine, slot circuit breakers)
 and the deterministic fault-injection harness (``repro.farm.chaos``)."""
 from repro.core.schedule import LaneBatch  # noqa: F401

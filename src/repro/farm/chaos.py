@@ -17,8 +17,8 @@ Injection points (fired via ``FarmManager._inject`` /
   ``job.verify``      inside the job's drain verifier (harness wrapper)
   ``snapshot.store``  right after a snapshot publish (harness wrapper)
   ``snapshot.publish``  at the manager's snapshot hook
-  ``worker.loop``     a slot thread picking up an assignment (async)
-  ``results.post``    before a drain posts to the results queue (async)
+  ``worker.loop``     a slot thread picking up an assignment
+  ``results.post``    before a drain posts to the results queue
   ``slot.canary``     a circuit-breaker probe running
   ``ledger.<kind>``   right AFTER a ZP-Ledger journal record lands
                       (``ledger.commit``, ``ledger.deliver``, ...) — the
@@ -32,12 +32,10 @@ Fault kinds and the recovery each must produce:
   ``commit_divergence`` verifier raises once      -> veto evict + replay
   ``snapshot_corrupt``  published bytes flipped   -> integrity fallback
   ``snapshot_truncate`` published snapshot torn   -> integrity fallback
-  ``hung_drain``        drain sleeps past the watchdog  (async only)
+  ``hung_drain``        drain sleeps past the watchdog
                                                   -> board abandoned
-  ``thread_death``      slot thread dies pre-job  (async only)
-                                                  -> liveness requeue
-  ``results_stall``     results hand-off delayed  (async only)
-                                                  -> completion, late
+  ``thread_death``      slot thread dies pre-job  -> liveness requeue
+  ``results_stall``     results hand-off delayed  -> completion, late
   ``process_kill``      SIGKILL the whole farm process (ZP-Ledger only —
                         armed by the kill-restart harness, never by the
                         seeded menus)             -> FarmManager.recover
@@ -88,12 +86,12 @@ CORRUPT_KINDS = frozenset({"snapshot_corrupt", "snapshot_truncate"})
 #: atexit; the only recovery is FarmManager.recover in a NEW process
 KILL_KINDS = frozenset({"process_kill"})
 
-#: the full fault menu per farm mode: the lockstep control thread cannot
-#: detect its own hang, so the async-only kinds are excluded there
-LOCKSTEP_KINDS = ("dispatch_exc", "slot_crash", "commit_divergence",
-                  "snapshot_corrupt", "snapshot_truncate")
-ASYNC_KINDS = LOCKSTEP_KINDS + ("hung_drain", "thread_death",
-                                "results_stall")
+#: the seeded fault menu, in scheduling order. ``hung_drain`` and
+#: ``thread_death`` write their slot off, so only a farm with a second
+#: seat can recover from them
+KINDS = ("dispatch_exc", "slot_crash", "commit_divergence",
+         "snapshot_corrupt", "snapshot_truncate", "hung_drain",
+         "thread_death", "results_stall")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -262,20 +260,18 @@ def _n_windows(job) -> int:
     return sum(1 for _ in w)
 
 
-def build_schedule(seed: int, jobs, mode: str = "async",
-                   hang_s: float = 3.0,
+def build_schedule(seed: int, jobs, hang_s: float = 3.0,
                    stall_s: float = 0.05) -> List[Injection]:
     """Seeded fault schedule over the submitted jobs: each fault kind in
-    the mode's menu lands on a DIFFERENT job (at most one fault — or one
-    corrupt+crash pair — per job keeps the occurrence arithmetic exact),
-    at a seeded window. Jobs without barriers are skipped for the
+    the menu (:data:`KINDS`, in order) lands on a DIFFERENT job (at most
+    one fault — or one corrupt+crash pair — per job keeps the occurrence
+    arithmetic exact), at a seeded window. Jobs without barriers are skipped for the
     snapshot kinds; kinds with no eligible job left are dropped."""
     rng = random.Random(seed)
-    kinds = list(LOCKSTEP_KINDS if mode == "lockstep" else ASYNC_KINDS)
     pool = sorted(jobs, key=lambda j: j.name)
     rng.shuffle(pool)
     sched: List[Injection] = []
-    for kind in kinds:
+    for kind in KINDS:
         pick = None
         for i, j in enumerate(pool):
             if kind in CORRUPT_KINDS and not (
@@ -336,7 +332,6 @@ class ChaosHarness:
         each job's verifier and snapshot store, install the injector.
         Call after every ``submit()``, before ``run()``."""
         self.schedule = build_schedule(self.seed, self.mgr.jobs,
-                                       mode=self.mgr.mode,
                                        hang_s=self.hang_s,
                                        stall_s=self.stall_s)
         self.injector.arm(self.schedule)

@@ -3,27 +3,20 @@
 The paper's end state is a *farm* of scaled-down DUTs — many independently
 prototyped subsystems co-emulated concurrently behind one host. This
 module is the orchestration layer over the core ``WindowScheduler``
-machinery, in two host-loop modes:
+machinery, with ONE host loop — the paper's non-interference guarantee
+made real on the host side: each :class:`DeviceSlot` is driven by its own
+dispatcher thread (:class:`_SlotWorker`) with a bounded work queue. The
+manager is an admission/eviction CONTROL PLANE: it feeds job assignments
+into slot queues, and each slot thread runs its own ``ClientDriver``
+pipeline (dispatch window *i+1* while draining window *i*), posting
+completed drains back over a results queue. A slow board slows only
+itself; the watchdog's straggler signal is measured per-window WALL
+time, and liveness heartbeats are true wall-time liveness (a hung board
+is abandoned and its job requeued, without taking down the farm).
 
-  lockstep (``mode="lockstep"``) — ONE Python thread round-robins every
-      slot through ``WindowScheduler.run_many``. Deterministic round
-      structure, but one slow board's dispatch delays every other board's
-      enqueue, and "straggler" is inferred from per-board dispatch cost
-      because inter-drain gaps are the round time. Kept as the
-      bit-identity ORACLE: the async mode must deliver byte-for-byte the
-      same per-job outputs (tests assert it).
-
-  async (``mode="async"``) — the paper's non-interference guarantee made
-      real on the host side: each :class:`DeviceSlot` is driven by its own
-      dispatcher thread (:class:`_SlotWorker`) with a bounded work queue.
-      The manager becomes an admission/eviction CONTROL PLANE: it feeds
-      job assignments into slot queues, and each slot thread runs its own
-      ``ClientDriver`` pipeline (dispatch window *i+1* while draining
-      window *i*), posting completed drains back over a results queue.
-      A slow board slows only itself; the watchdog's straggler signal
-      becomes measured per-window WALL time, and liveness heartbeats
-      become true wall-time liveness (a hung board is abandoned and its
-      job requeued, without taking down the farm).
+The tests' bit-identity reference is a plain
+``WindowScheduler.run_many`` pass over the same clients, with no farm:
+the farm must deliver byte-for-byte the same per-job outputs.
 
 Threading invariants (the GIL-friendly contract):
 
@@ -38,7 +31,7 @@ Threading invariants (the GIL-friendly contract):
     at drain boundaries (between windows, never mid-dispatch), so a
     cancelled job's in-flight window is discarded, never delivered.
 
-Shared semantics (both modes):
+Farm semantics:
 
   * a job queue of :class:`FarmJob`\\ s — an engine + a replayable window
     stream + an expected-output verifier + optional per-job checkpoint
@@ -122,8 +115,8 @@ from repro.checkpoint.manager import (MemorySnapshotStore,
 from repro.core import scope as zp_scope
 from repro.core.profiler import phase, set_context
 from repro.core.pshell import drain as _shell_drain
-from repro.core.schedule import (Client, ClientPolicy, DrainBarrier,
-                                 LaneBatch, WindowScheduler)
+from repro.core.schedule import (Client, DrainBarrier, LaneBatch,
+                                 WindowScheduler)
 from repro.core.watchdog import Watchdog
 from repro.farm.placement import (DeviceSlot, enumerate_slots, pick_slot,
                                   place, place_stack)
@@ -266,7 +259,7 @@ class FarmJob:
     cannot be materialized) so a requeued attempt can re-read it from its
     resume cursor.
     ``verify(plan, records, ys)`` raises to veto a window (stateless — it
-    re-runs on replay; in async mode it runs on the job's slot thread);
+    re-runs on replay; it runs on the job's slot thread);
     ``on_drain(plan, records, ys)`` is the exactly-once, in-order sink
     delivered at completion on the control thread. ``barriers`` are
     per-job :class:`DrainBarrier`\\ s (e.g. checkpoint saves) whose
@@ -337,8 +330,8 @@ class FarmJob:
 
 
 class _Run:
-    """One admission of a job onto a slot (client index ``idx``). In async
-    mode the slot thread owns everything here until it posts a terminal
+    """One admission of a job onto a slot (client index ``idx``). The
+    slot thread owns everything here until it posts a terminal
     message; after ``closed`` is set by the control plane, late messages
     and callbacks from a stale (abandoned) thread are ignored."""
 
@@ -475,7 +468,7 @@ class _SlotWorker(threading.Thread):
                 td = t_dispatched.pop(plan.index, None)
                 if td is not None and plan.index > 0:
                     # measured window WALL (dispatch -> results in hand) is
-                    # the async straggler signal; window 0 pays jit
+                    # the straggler signal; window 0 pays jit
                     # compilation (the farm analog of bitstream build
                     # time), a known one-off, not slowness; a lane-batched
                     # window is N boards of work, normalized to per-board
@@ -574,9 +567,14 @@ class _SlotWorker(threading.Thread):
                     with phase("slot.post"):
                         mgr._results.put(("fault", run))
                     break
-                # a requested shutdown cuts here too, without waiting for
-                # the control plane's next sweep to mark the run: a short
-                # job could otherwise finish every window in between
+                # a requested shutdown or a force mark cuts here too,
+                # without waiting for the control plane's next sweep to
+                # mark the run: a short job could otherwise finish every
+                # window in between (with no marks, one set test)
+                if mgr._force and not run.evict_flag.is_set() \
+                        and mgr._forced(run):
+                    run.evict_why = "forced"
+                    run.evict_flag.set()
                 if run.evict_flag.is_set() or mgr._shutdown.is_set():
                     driver.cancel()
                     with phase("slot.post"):
@@ -586,14 +584,14 @@ class _SlotWorker(threading.Thread):
             mgr._results.put(("crash", run, e))
 
 
-class FarmManager(ClientPolicy):
-    """Job queue + placement + watchdog + eviction in two host-loop modes
-    (see module docstring). ``slots`` may be a slot list, an int (minimum
-    concurrency; virtual slots fill in on single-device hosts), or None
-    (``max(min_slots, n_devices)``, capped at the number of submitted
-    jobs). ``mode`` is ``"lockstep"`` (one round-robin host thread — the
-    bit-identity oracle) or ``"async"`` (one dispatcher thread per slot).
-    ``slot_queue_depth`` bounds each slot's async work queue (1 = admit
+class FarmManager:
+    """Job queue + placement + watchdog + eviction over one dispatcher
+    thread per slot (see module docstring). ``slots`` may be a slot list,
+    an int (minimum concurrency; virtual slots fill in on single-device
+    hosts), or None (``max(min_slots, n_devices)``, capped at the number
+    of submitted jobs). ``mode`` accepts only ``"async"``, the one host
+    loop, and changes nothing.
+    ``slot_queue_depth`` bounds each slot's work queue (1 = admit
     only to idle slots; 2 lets the next job pre-stage behind the current
     one, eliminating the idle gap between assignments). ``poll_s`` is the
     control plane's results-queue poll interval — the cadence of watchdog
@@ -612,7 +610,7 @@ class FarmManager(ClientPolicy):
                  straggler_min_s: float = 0.01,
                  evict_stragglers: bool = True,
                  telemetry: Optional[FarmTelemetry] = None,
-                 mode: str = "lockstep",
+                 mode: str = "async",
                  slot_queue_depth: int = 1,
                  poll_s: float = 0.02,
                  policy: Optional[FailurePolicy] = None,
@@ -620,8 +618,10 @@ class FarmManager(ClientPolicy):
                  ledger: Any = None,
                  certify: bool = False,
                  clock: Callable[[], float] = time.perf_counter):
-        if mode not in ("lockstep", "async"):
-            raise ValueError(f"unknown farm mode: {mode!r}")
+        if mode != "async":
+            why = (" (the lockstep host loop was removed)"
+                   if mode == "lockstep" else "")
+            raise ValueError(f"unknown farm mode: {mode!r}{why}")
         self._slots_arg = slots
         self.min_slots = min_slots
         self.lanes = max(1, lanes)      # lane capacity for auto-built slots
@@ -632,7 +632,6 @@ class FarmManager(ClientPolicy):
         self.straggler_min_s = straggler_min_s
         self.evict_stragglers = evict_stragglers
         self.telemetry = telemetry or FarmTelemetry(clock=clock)
-        self.mode = mode
         self.slot_queue_depth = max(1, slot_queue_depth)
         self.poll_s = poll_s
         self.policy = policy
@@ -647,15 +646,13 @@ class FarmManager(ClientPolicy):
         self.results: Dict[str, Any] = {}       # name -> (state, shell)
         self.outputs: Dict[str, List] = {}      # name -> [(plan, rec, ys)]
         self._running: Dict[int, _Run] = {}     # client idx -> run
-        self._free: List[DeviceSlot] = []
         self._avoid: Dict[str, str] = {}        # job -> slot to avoid
-        self._evicted: set = set()              # client idxs, confirmed out
         self._mu = threading.Lock()             # guards _force (any thread
-        self._force: set = set()                # may force_evict; the
-        # control plane reads and clears marks at drain/finish boundaries)
-        self._pre: Dict[int, float] = {}        # client idx -> t(place_fn)
+        self._force: set = set()                # may force_evict; slot
+        # threads read marks at drain boundaries, the control plane clears
+        # them at finish/requeue)
         self._next_idx = 0
-        # ----- async control plane state -----
+        # ----- control plane state -----
         self._results: queue_mod.Queue = queue_mod.Queue()
         self._workers: Dict[str, _SlotWorker] = {}
         self._slot_load: Dict[str, int] = {}    # assigned-not-finished runs
@@ -849,10 +846,26 @@ class FarmManager(ClientPolicy):
     def force_evict(self, job_name: str):
         """Mark a job for eviction at its next drain boundary (the
         deterministic test/CLI path — the watchdog path is wall-time).
-        Safe from any thread: the mark set is shared with the control
-        plane's sweep, so it is mutated under ``_mu``."""
+        Safe from any thread: the mark set is read by the slot threads
+        (:meth:`_forced`), so it is mutated under ``_mu``."""
         with self._mu:
             self._force.add(job_name)
+
+    @any_thread
+    def _forced(self, run: _Run) -> bool:
+        """Whether ``run`` is force-marked (``force_evict`` on its job or,
+        for a fused run, on any member) and may still be cut — a solo run
+        whose requeue budget is spent limps home instead. Its slot thread
+        asks at every drain boundary while any mark is set, so a mark
+        lands at the next one, whichever thread set it."""
+        names = {run.job.name}
+        if run.lanes is not None:   # force-marking a member cuts the
+            names.update(m.name for m in run.lanes)     # whole fused run
+        with self._mu:
+            if not names & self._force:
+                return False
+        return (run.lanes is not None
+                or run.job.requeues < self._budget(run.job))
 
     def request_shutdown(self):
         """Graceful stop (the SIGINT path): no new admissions, every
@@ -942,10 +955,9 @@ class FarmManager(ClientPolicy):
         """Run every submitted job; returns :meth:`report`. The pass is
         the span ``zp.farm.run``, whose start on the manager's clock
         (``perf_counter`` by default) the telemetry keeps as
-        ``clock_origin``; the calling thread's phases
-        (``ctl.*``; in lockstep also the slots' ``slot.*``) go to the
-        telemetry's control profiler. Whether it returns or raises, its
-        telemetry report becomes
+        ``clock_origin``; the calling thread's phases (``ctl.*``) go to
+        the telemetry's control profiler. Whether it returns or raises,
+        its telemetry report becomes
         :func:`repro.farm.telemetry.last_report`."""
         report = None
         try:
@@ -970,21 +982,40 @@ class FarmManager(ClientPolicy):
             self.slots = enumerate_slots(min_slots=min(
                 len(self.queue), max(self.min_slots, len(jax.devices()))),
                 lane_capacity=self.lanes)
-        if self.mode == "async":
-            self._run_async()
-        else:
-            self._free = list(self.slots)
-            # the initial client list MUST be empty: every client enters via
-            # admit(), so the scheduler's positional indices stay in lockstep
-            # with _next_idx and the callbacks route to the right _Run
-            self.sched.run_many([], on_drain=self._on_drain,
-                                on_dispatch=self._on_dispatch,
-                                place_fn=self._place, policy=self,
-                                on_commit=self._on_commit,
-                                inject=(self._inject_lockstep
-                                        if self.injector else None))
-            if self._shutdown.is_set():
-                self._drain_interrupted()
+        self._workers = {s.name: _SlotWorker(self, s, self.slot_queue_depth)
+                         for s in self.slots}
+        self._slot_load = {s.name: 0 for s in self.slots}
+        self._lost = set()
+        for w in self._workers.values():
+            w.start()
+        try:
+            with phase("ctl.admit"):
+                self._assign()
+            while self._running or self.queue:
+                if self._shutdown.is_set():
+                    with phase("ctl.sweep"):
+                        self._shutdown_sweep()
+                try:
+                    msg = self._results.get(timeout=self.poll_s)
+                except queue_mod.Empty:
+                    msg = None
+                if msg is not None:
+                    with phase("ctl.ingest"):
+                        self._handle(msg)
+                with phase("ctl.sweep"):
+                    self._sweep()
+                    self._probe()
+                with phase("ctl.admit"):
+                    self._assign()
+        finally:
+            for w in self._workers.values():
+                try:
+                    w.inbox.put_nowait(_STOP)
+                except queue_mod.Full:
+                    pass
+            for w in self._workers.values():
+                if w.slot.name not in self._lost:
+                    w.join(timeout=10.0)
         report = self.report()
         if strict:
             # quarantined jobs are the dead-letter REPORT, interrupted
@@ -998,7 +1029,6 @@ class FarmManager(ClientPolicy):
 
     def report(self) -> dict:
         return {
-            "mode": self.mode,
             "jobs": {j.name: {"status": j.status,
                               "windows": j.windows_drained,
                               "requeues": j.requeues,
@@ -1019,50 +1049,13 @@ class FarmManager(ClientPolicy):
         :meth:`FarmTelemetry.scope_report`)."""
         return self.telemetry.scope_report()
 
-    # ================================================== async control plane
+    # ------------------------------------------------------ control plane --
     @control_thread_only
-    def _run_async(self):
-        self._workers = {s.name: _SlotWorker(self, s, self.slot_queue_depth)
-                         for s in self.slots}
-        self._slot_load = {s.name: 0 for s in self.slots}
-        self._lost = set()
-        for w in self._workers.values():
-            w.start()
-        try:
-            with phase("ctl.admit"):
-                self._assign_async()
-            while self._running or self.queue:
-                if self._shutdown.is_set():
-                    with phase("ctl.sweep"):
-                        self._shutdown_async()
-                try:
-                    msg = self._results.get(timeout=self.poll_s)
-                except queue_mod.Empty:
-                    msg = None
-                if msg is not None:
-                    with phase("ctl.ingest"):
-                        self._handle_async(msg)
-                with phase("ctl.sweep"):
-                    self._sweep_async()
-                    self._probe_async()
-                with phase("ctl.admit"):
-                    self._assign_async()
-        finally:
-            for w in self._workers.values():
-                try:
-                    w.inbox.put_nowait(_STOP)
-                except queue_mod.Full:
-                    pass
-            for w in self._workers.values():
-                if w.slot.name not in self._lost:
-                    w.join(timeout=10.0)
-
-    @control_thread_only
-    def _assign_async(self):
+    def _assign(self):
         """Admission: feed queued jobs into slot work queues, honoring the
         requeue avoid-slot preference and each job's backoff gate, with
-        the same progress guarantee as lockstep admit (the preference
-        yields when nothing else can ever free a different slot). The walk
+        a progress guarantee (the preference yields when nothing else can
+        ever free a different slot). The walk
         stops once no seat is free, since every job behind that point
         could only be deferred: a tick costs O(free seats), not O(queue).
         Each tick's counts go to the telemetry's ``admission`` counter."""
@@ -1078,7 +1071,7 @@ class FarmManager(ClientPolicy):
                 deferred.append(job)
                 backing_off = True
                 continue
-            slot = self._pick_async_slot(self._avoid.get(job.name))
+            slot = self._pick_slot(self._avoid.get(job.name))
             if slot is None:            # only its old slot has capacity:
                 deferred.append(job)    # wait for a DIFFERENT one
                 continue
@@ -1093,7 +1086,7 @@ class FarmManager(ClientPolicy):
                 and not backing_off:
             # nothing running, nothing assigned: no other slot will ever
             # free, so the avoid preference must yield (progress guarantee)
-            slot = self._pick_async_slot(None)
+            slot = self._pick_slot(None)
             if slot is not None:
                 job = self.queue.popleft()
                 examined += 1
@@ -1118,7 +1111,7 @@ class FarmManager(ClientPolicy):
                    for s in self.slots if s.name not in out)
 
     @control_thread_only
-    def _pick_async_slot(self, avoid: Optional[str]) -> Optional[DeviceSlot]:
+    def _pick_slot(self, avoid: Optional[str]) -> Optional[DeviceSlot]:
         # least-loaded first: with slot_queue_depth >= 2 a fixed slot
         # order would double-book early slots while later ones sit idle
         out = self._unavailable()
@@ -1132,7 +1125,7 @@ class FarmManager(ClientPolicy):
                          sole_candidate=len(live) == 1)
 
     @control_thread_only
-    def _probe_async(self):
+    def _probe(self):
         """Dispatch a canary to every benched slot whose cooldown has
         elapsed (one probe in flight per slot)."""
         if self.policy is None or not self._benched:
@@ -1162,7 +1155,7 @@ class FarmManager(ClientPolicy):
                 self._journal("interrupted", job=job.name)
 
     @control_thread_only
-    def _shutdown_async(self):
+    def _shutdown_sweep(self):
         """Graceful-stop sweep: orphan the queue, cut every running job at
         its next drain boundary (its committed prefix stays delivered)."""
         self._orphan_queue()
@@ -1177,18 +1170,6 @@ class FarmManager(ClientPolicy):
         takes lanes) on ``slot`` as one run; returns the jobs seated."""
         members = self._gather_lanes(job, slot)
         run = self._new_run(members, slot, t_assigned=self.clock())
-        with self._mu:
-            forced = bool({m.name for m in members} & self._force)
-        if forced and not (
-                run.lanes is None
-                and run.job.requeues >= self._budget(run.job)):
-            # signal a pre-existing force mark at assignment, not at the
-            # next sweep: the control plane's first sweep runs after a
-            # blocking results poll, and a short job can finish entirely
-            # inside that window — the mark would never land (flaky
-            # force_evict on sub-poll_s jobs)
-            run.evict_why = "forced"
-            run.evict_flag.set()
         self._slot_load[slot.name] += 1
         self.wd.heartbeat(slot.name, gap=False)   # assigned: alive
         self.telemetry.depth(slot.name,
@@ -1299,7 +1280,7 @@ class FarmManager(ClientPolicy):
                      for j, b in enumerate(proto))
 
     @control_thread_only
-    def _handle_async(self, msg):
+    def _handle(self, msg):
         if msg[0] == "canary":
             _, slot_name, ok, err = msg
             self._canary_verdict(slot_name, ok, err)
@@ -1358,48 +1339,35 @@ class FarmManager(ClientPolicy):
         return "auto"
 
     @control_thread_only
-    def _sweep_async(self):
+    def _sweep(self):
         """Control-plane sweep: watchdog stragglers (measured window wall)
-        + forced marks are SIGNALLED to the slot thread (honored at its
-        next drain boundary); hung boards (liveness timeout) are abandoned
-        — the slot leaves the pool, the job requeues elsewhere."""
-        marks: Dict[int, str] = {}
+        are SIGNALLED to the slot thread (honored at its next drain
+        boundary); hung boards (liveness timeout) are abandoned — the
+        slot leaves the pool, the job requeues elsewhere."""
         if self.evict_stragglers and self._running:
-            # unlike the lockstep sweep, async jobs finish at their own
-            # pace: a straggler is often the LAST one running, judged
-            # against the departed fleet's retained samples — the
-            # watchdog's own min_fleet (>= 2 sampled workers) is the gate
+            # jobs finish at their own pace: a straggler is often the LAST
+            # one running, judged against the departed fleet's retained
+            # samples — the watchdog's own min_fleet (>= 2 sampled
+            # workers) is the gate
             slow = set(self.wd.stragglers(self.straggler_factor,
                                           min_s=self.straggler_min_s,
                                           channel=self._straggler_channel()))
-            for idx, run in self._running.items():
-                if run.slot.name in slow:
-                    marks.setdefault(idx, "straggler")
-        with self._mu:
-            force = set(self._force)
-        for idx, run in self._running.items():
-            names = {run.job.name}
-            if run.lanes is not None:   # force-marking a member cuts the
-                names.update(m.name for m in run.lanes)  # whole fused run
-            if names & force:
-                marks.setdefault(idx, "forced")
-        for idx, why in marks.items():
-            run = self._running[idx]
-            if run.evict_flag.is_set():
-                continue                # already signalled
-            if (run.lanes is None and run.fault is None
-                    and run.job.requeues >= self._budget(run.job)):
-                continue                # budget spent: let it limp home
-                # (lane runs skip the gate: members budget at requeue)
-            run.evict_why = why
-            run.evict_flag.set()
+            for run in self._running.values():
+                if run.slot.name not in slow or run.evict_flag.is_set():
+                    continue
+                if (run.lanes is None and run.fault is None
+                        and run.job.requeues >= self._budget(run.job)):
+                    continue            # budget spent: let it limp home
+                    # (lane runs skip the gate: members budget at requeue)
+                run.evict_why = "straggler"
+                run.evict_flag.set()
         dead = set(self.wd.dead_workers())
         for run in [r for r in self._running.values()
                     if r.slot.name in dead]:
-            self._abandon_async(run)
+            self._abandon(run)
 
     @control_thread_only
-    def _abandon_async(self, run: _Run):
+    def _abandon(self, run: _Run):
         """A slot whose thread stopped beating past the watchdog timeout is
         HUNG mid-dispatch (it cannot even reach an eviction check). The
         board is written off: its thread is left to the OS (daemon), the
@@ -1412,7 +1380,7 @@ class FarmManager(ClientPolicy):
                 msg = self._results.get_nowait()
             except queue_mod.Empty:
                 break
-            self._handle_async(msg)
+            self._handle(msg)
         if run.closed:          # the drained backlog finished the run
             return
         run.closed = True
@@ -1438,10 +1406,10 @@ class FarmManager(ClientPolicy):
     # ------------------------------------------------- checkpointed resume --
     def _publish_snapshot(self, run: _Run, plan, state, shell):
         """Publish the job's resume point at an accepted barrier commit
-        (runs on the thread that owns the job's JAX state — the slot
-        thread in async mode). The payload is host-copied by the store's
-        save, so it survives donation and slot loss; the cursor handle on
-        the run is what the control plane reads at requeue time."""
+        (runs on the job's slot thread, which owns its JAX state). The
+        payload is host-copied by the store's save, so it survives
+        donation and slot loss; the cursor handle on the run is what the
+        control plane reads at requeue time."""
         job = run.job
         # snapshots hold the DUT shell only: scope counters ride BESIDE
         # the DUT and restart on requeue (observability, not progress)
@@ -1614,8 +1582,8 @@ class FarmManager(ClientPolicy):
         """Bind a fresh per-attempt :class:`ScopePlane` for a scoped job
         (``None`` otherwise). One plane instruments the whole run — under
         lane batching the counters are per-lane via the existing lane
-        axis. Drained samples land on the observing thread (the slot
-        thread in async mode) and fan into telemetry + the watchdog's
+        axis. Drained samples land on the run's slot thread and fan into
+        telemetry + the watchdog's
         device-side work-rate channel."""
         job = run.job
         if job.scope is None:
@@ -1651,27 +1619,6 @@ class FarmManager(ClientPolicy):
             self.wd.observe(run.slot.name, 0.0, quiet=True)
             return
         self.wd.observe(run.slot.name, wall, work=work)
-
-    @control_thread_only
-    def _on_commit(self, k: int, plan, state, shell):
-        """Lockstep snapshot hook (the async path is the slot worker's
-        closure): publish unless the attempt is faulted — the veto
-        contract keeps the resume point BEFORE a rejected window."""
-        run = self._running.get(k)
-        if run is None or run.fault is not None:
-            return
-        self._publish_snapshot(run, plan, state, shell)
-        self._deliver_committed(run)    # ledger mode: hand over the newly
-        # committed windows now (lockstep's control thread owns delivery)
-
-    def _inject_lockstep(self, k: int, point: str, plan):
-        """Lockstep route for the ClientDriver injection points (the async
-        route is the slot worker's closure)."""
-        run = self._running.get(k)
-        if run is None:
-            return
-        self._inject("slot." + point, job=run.job.name,
-                     slot=run.slot.name, window=plan.index)
 
     def _gated_barriers(self, run: _Run):
         """Per-attempt barrier wrappers: a barrier action (e.g. a
@@ -1732,8 +1679,7 @@ class FarmManager(ClientPolicy):
         The ``windows_delivered`` cursor — seeded from the journal by
         ``recover()`` — suppresses windows a dead predecessor already
         delivered, which is what makes ``on_drain`` exactly-once ACROSS
-        process lifetimes. Control thread only (lockstep's control thread
-        or the async control plane)."""
+        process lifetimes. Control thread only."""
         upto = min(upto, base + len(outputs))
         if job.windows_delivered >= upto:
             return
@@ -1770,9 +1716,8 @@ class FarmManager(ClientPolicy):
     # ------------------------------------------------------ lane lifecycle --
     def _lane_ingest(self, run: _Run, plan, records, ys):
         """Fan one fused window out to its live lanes and run each
-        member's verify against ITS slice (called on the thread that owns
-        the drain: the slot thread in async mode, the control thread in
-        lockstep). A verify exception vetoes that lane alone: it is
+        member's verify against ITS slice (called on the slot thread that
+        owns the drain). A verify exception vetoes that lane alone: it is
         recorded in ``run.lane_faults`` (so later commits on this run skip
         the lane), stamped with the lane id, and the lane's window is not
         delivered. Returns ``(delivered, faulted)`` as
@@ -1934,211 +1879,10 @@ class FarmManager(ClientPolicy):
             self._journal("done", job=m.name,
                           windows=m._base + len(outputs))
 
-    # ----------------------------------------------- ClientPolicy protocol --
-    @control_thread_only
-    def admit(self, round_idx: int):
-        if self._shutdown.is_set():
-            self._interrupt_lockstep()
-            return ()
-        self._process_evictions()
-        if self._benched:
-            self._probe_lockstep()
-        admissions = []
-        while True:
-            deferred = []
-            backing_off = False
-            now = self.clock()
-            while self.queue and self._free:
-                job = self.queue.popleft()
-                if job.not_before > now:    # backoff: re-admission waits
-                    deferred.append(job)
-                    backing_off = True
-                    continue
-                slot = self._pick_slot(self._avoid.get(job.name))
-                if slot is None:    # only its old slot is free: wait for
-                    deferred.append(job)    # a DIFFERENT one
-                    continue
-                self._avoid.pop(job.name, None)
-                admissions.append(self._admit_one(job, slot))
-            self.queue.extendleft(reversed(deferred))
-            if admissions or self._running or not self.queue:
-                break
-            # STALLED: jobs queued, nothing running, nothing admitted.
-            # Lockstep has no background tick — resolve the stall here or
-            # run_many's round loop would exit with jobs stranded.
-            if backing_off:
-                # wait out the earliest backoff gate, then re-admit
-                delay = min(j.not_before for j in self.queue) - self.clock()
-                if delay > 0:
-                    time.sleep(delay)
-                continue
-            slot = self._pick_slot(None)
-            if slot is not None:
-                # only the avoid preference blocks: no other slot will
-                # ever free, so it must yield (progress guarantee)
-                job = self.queue.popleft()
-                self._avoid.pop(job.name, None)
-                admissions.append(self._admit_one(job, slot))
-                break
-            if self._benched:
-                # every placeable seat is benched: probe inline until one
-                # heals or the breaker writes them all off
-                self._probe_lockstep()
-                if self._benched and self.policy is not None:
-                    delay = (min(self._benched.values())
-                             + self.policy.breaker_cooldown_s
-                             - self.clock())
-                    if delay > 0:
-                        time.sleep(delay)
-                continue
-            raise FarmError(
-                "no live slots left to place queued jobs "
-                f"(lost: {sorted(self._lost)})")
-        if self._running:
-            self.telemetry.occupancy(len(self._running), len(self.slots))
-        return admissions
-
-    @control_thread_only
-    def evict(self, k: int) -> bool:
-        return k in self._evicted
-
-    @control_thread_only
-    def done(self, k: int, state, shell):
-        run = self._running.pop(k)
-        self._free.append(run.slot)
-        if run.fault is not None:
-            if run.lanes is None:       # lane vetoes don't score the seat
-                self._slot_result(run.slot.name, ok=False,
-                                  why=f"veto: {run.fault}")
-            self._requeue_or_fail(run, f"drain veto: {run.fault}")
-            return
-        self._slot_result(run.slot.name, ok=True)
-        self._finish_run(run, state, shell)
-
-    @control_thread_only
-    def crashed(self, k: int, exc: BaseException) -> bool:
-        """Lockstep crash absorption (the ClientPolicy hook run_many
-        offers a raising driver to): a client crashing mid-drive is a
-        board fault, not a farm failure — free the seat, score the slot,
-        requeue or dead-letter the job, keep the pass alive. Mirrors the
-        async mode's slot-thread ``crash`` message."""
-        run = self._running.pop(k, None)
-        if run is None:
-            return False
-        self._free.append(run.slot)
-        self._slot_result(run.slot.name, ok=False, why=f"crash: {exc!r}")
-        self._requeue_or_fail(run, f"client crash: {exc!r}")
-        return True
-
-    # -------------------------------------------------- scheduler callbacks --
-    @control_thread_only
-    def _place(self, k: int, stack):
-        self._pre[k] = self.clock()
-        return place_stack(stack, self._running[k].slot)
-
-    @control_thread_only
-    def _on_dispatch(self, k: int, plan, state):
-        run = self._running[k]
-        cost = self.clock() - self._pre.pop(k, self.clock())
-        if plan.index > 0:
-            # window 0 of an attempt pays jit compilation (the farm analog
-            # of bitstream build time) — a known one-off, not slowness; a
-            # lane-batched window is N boards of work, normalized per board
-            self.wd.observe(run.slot.name, cost, lanes=run.lane_count)
-            if run.scope_plane is not None:
-                # lockstep's wall proxy is the dispatch cost; consumed by
-                # _scope_observe at the next read-rate sample
-                run.scope_wall_acc += cost
-        self.telemetry.dispatch(run.slot.name, self._key(run, plan), cost)
-        if run.job.capture is not None:
-            run.job.capture.on_dispatch(plan, state)
-
-    @control_thread_only
-    def _on_drain(self, k: int, plan, records, ys):
-        run = self._running[k]
-        self.wd.heartbeat(run.slot.name, gap=False)
-        self.telemetry.drain(run.slot.name, self._key(run, plan))
-        if run.job.capture is not None:
-            run.job.capture.on_drain(plan, records, ys)
-        self.telemetry.moe_routing(run.job.name, records)
-        if run.lanes is not None:
-            delivered, faulted = self._lane_ingest(run, plan, records, ys)
-            for lane, rec, y in delivered:
-                run.lane_outputs[lane].append((plan, rec, y))
-            for lane, exc in faulted:
-                self._detach_lane(run, lane, f"lane veto: {exc}")
-            if faulted and len(run.lane_faults) == len(run.lanes):
-                run.fault = faulted[-1][1]          # every lane dead
-            return
-        if run.job.verify is not None and run.fault is None:
-            with phase("slot.verify"):
-                try:
-                    run.job.verify(plan, records, ys)
-                except Exception as e:      # noqa: BLE001 — veto, not crash
-                    self.telemetry.veto(run.slot.name)
-                    run.fault = e
-        run.outputs.append((plan, records, ys))
-
     # ----------------------------------------------------------- internals --
     @staticmethod
     def _key(run: _Run, plan):
         return (run.job.name, run.job.attempts, plan.index)
-
-    @control_thread_only
-    def _pick_slot(self, avoid: Optional[str]) -> Optional[DeviceSlot]:
-        out = self._unavailable()
-        candidates = [s for s in self._free if s.name not in out]
-        live = [s for s in self.slots if s.name not in out]
-        s = pick_slot(candidates, avoid=avoid,
-                      sole_candidate=len(live) == 1)
-        if s is not None:
-            self._free.remove(s)
-        return s
-
-    @control_thread_only
-    def _probe_lockstep(self):
-        """Inline breaker probe (lockstep has no slot threads): run the
-        canary on the control thread for each benched slot past its
-        cooldown, and apply the verdict immediately."""
-        if self.policy is None:
-            return
-        now = self.clock()
-        for name, t0 in list(self._benched.items()):
-            if name in self._lost \
-                    or now - t0 < self.policy.breaker_cooldown_s:
-                continue
-            slot = next(s for s in self.slots if s.name == name)
-            self.telemetry.breaker(name, "probe")
-            try:
-                self._inject("slot.canary", slot=name)
-                fn = self.policy.canary or _default_canary
-                fn(slot)
-            except BaseException as e:  # noqa: BLE001 — verdict, not crash
-                self._canary_verdict(name, False, e)
-            else:
-                self._canary_verdict(name, True, None)
-
-    @control_thread_only
-    def _interrupt_lockstep(self):
-        """Graceful-stop (lockstep): cut every running client at this
-        round boundary — run_many's evict check cancels it, its committed
-        prefix and snapshots stay — and orphan the queue."""
-        for k, run in list(self._running.items()):
-            self._evicted.add(k)
-            self._running.pop(k)
-            self._free.append(run.slot)
-            self._retire_interrupted(run)
-        self._orphan_queue()
-
-    @control_thread_only
-    def _drain_interrupted(self):
-        """Post-run sweep for a shutdown that landed after the last admit
-        tick: everything still queued or running is interrupted."""
-        for k, run in list(self._running.items()):
-            self._running.pop(k)
-            self._free.append(run.slot)
-            self._retire_interrupted(run)
-        self._orphan_queue()
 
     @control_thread_only
     def _retire_interrupted(self, run: _Run):
@@ -2155,49 +1899,6 @@ class FarmManager(ClientPolicy):
         self.wd.forget(run.slot.name)
         run.job.status = "interrupted"
         self._journal("interrupted", job=run.job.name)
-
-    @control_thread_only
-    def _admit_one(self, job: FarmJob, slot: DeviceSlot) -> Client:
-        members = self._gather_lanes(job, slot)
-        run = self._new_run(members, slot)
-        self.wd.heartbeat(slot.name, gap=False)
-        return self._client_for(run, slot)
-
-    @control_thread_only
-    def _process_evictions(self):
-        """Drain-boundary eviction sweep: watchdog stragglers + forced
-        marks + drain-veto faults all take the same evict/requeue path."""
-        marks: Dict[int, str] = {}
-        if self.evict_stragglers and len(self._running) > 1:
-            slow = set(self.wd.stragglers(self.straggler_factor,
-                                          min_s=self.straggler_min_s,
-                                          channel=self._straggler_channel()))
-            for k, run in self._running.items():
-                if run.slot.name in slow:
-                    marks.setdefault(k, "straggler")
-        with self._mu:
-            force = set(self._force)
-        for k, run in self._running.items():
-            names = {run.job.name}
-            if run.lanes is not None:   # force-marking a member cuts the
-                names.update(m.name for m in run.lanes)  # whole fused run
-            if names & force:
-                marks.setdefault(k, "forced")
-            if run.fault is not None:
-                marks.setdefault(k, f"drain veto: {run.fault}")
-        for k, why in marks.items():
-            run = self._running[k]
-            if (run.lanes is None and run.fault is None
-                    and run.job.requeues >= self._budget(run.job)):
-                continue                # budget spent: let it limp home
-                # (lane runs skip the gate: members budget at requeue)
-            self._evicted.add(k)
-            self._running.pop(k)
-            self._free.append(run.slot)
-            if run.fault is not None and run.lanes is None:
-                self._slot_result(run.slot.name, ok=False,
-                                  why=f"veto: {run.fault}")
-            self._requeue_or_fail(run, why)
 
     @control_thread_only
     def _adopt_progress(self, run: _Run) -> int:
@@ -2219,8 +1920,8 @@ class FarmManager(ClientPolicy):
 
     @control_thread_only
     def _requeue_or_fail(self, run: _Run, why: str):
-        """Shared evict/fault tail (boundary sweep AND the done()-path
-        fault on a job's final window): adopt the attempt's committed
+        """Shared evict/fault/crash tail of a run that did not finish
+        clean: adopt the attempt's committed
         progress, clear the slot's duration history so its next tenant is
         not judged against the evicted job's, drop any stale force mark,
         then requeue (with the policy's backoff gate), quarantine, or fail

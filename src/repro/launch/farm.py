@@ -9,25 +9,19 @@ virtual slots on a single-device host), dynamic admission, per-slot
 watchdogs, straggler eviction + requeue, and one aggregated telemetry
 report.
 
-Host-loop mode: ``--async`` (default) drives each slot from its own
-dispatcher thread — a slow board delays only itself; ``--lockstep`` is the
-single-thread round-robin oracle the async mode is bit-identity-tested
-against.
+Each slot is driven from its own dispatcher thread — a slow board delays
+only itself:
 
   PYTHONPATH=src python -m repro.launch.farm --steps 8
   PYTHONPATH=src python -m repro.launch.farm --steps 8 --synthetic-straggler
-  PYTHONPATH=src python -m repro.launch.farm --steps 8 --lockstep \\
-      --synthetic-straggler
 
-``--synthetic-straggler`` slows one verify board down. In lockstep mode it
-is force-marked for eviction (the deterministic path — dispatch-cost
-observations there come from too few windows to flag it); in async mode
-NOTHING is marked: the board must be caught by the watchdog from its
-measured per-window WALL time alone — the wall-time-divergence gate the CI
+``--synthetic-straggler`` adds a long board gone slow. NOTHING is marked:
+the board must be caught by the watchdog from its measured per-window
+WALL time alone — the wall-time-divergence gate the CI
 ``farm-async-smoke`` leg enforces. The run exits non-zero unless every job
 completes verified — and, when a straggler was injected, unless it was
-actually evicted (in async mode: evicted specifically as a ``straggler``),
-requeued, and still delivered correct outputs.
+evicted specifically as a ``straggler``, requeued, and still delivered
+correct outputs.
 
 ``--restart-smoke`` is the checkpointed-requeue gate (CI
 ``farm-restart-smoke``): a long board with per-window checkpoint barriers
@@ -37,7 +31,6 @@ committed (``windows_replayed < windows_committed``), resumed through the
 telemetry resume log, and still delivered bit-identical outputs:
 
   PYTHONPATH=src python -m repro.launch.farm --restart-smoke
-  PYTHONPATH=src python -m repro.launch.farm --restart-smoke --lockstep
 
 ``--chaos SEED`` is the fault-recovery gate (CI ``farm-chaos-smoke``): a
 toy multi-board workload is run twice — once fault-free (the bit-identity
@@ -50,7 +43,6 @@ telemetry), every non-quarantined board's outputs are bit-identical to
 the oracle, and the poisoned board was dead-lettered, not raised:
 
   PYTHONPATH=src python -m repro.launch.farm --chaos 7
-  PYTHONPATH=src python -m repro.launch.farm --chaos 7 --lockstep
 
 ``--lanes N`` is the lane-batched-boards gate (CI ``farm-lanes-smoke``):
 N identical-arch boards sharing one weight tree must coalesce into ONE
@@ -61,7 +53,6 @@ exactly that lane (requeued solo, resuming from its per-lane barrier
 snapshot) while the surviving lanes keep running:
 
   PYTHONPATH=src python -m repro.launch.farm --lanes 8 --chaos-lane
-  PYTHONPATH=src python -m repro.launch.farm --lanes 8 --lockstep
 
 ``--scope-smoke`` is the ZP-Scope non-interference gate (CI
 ``farm-scope-smoke``): the same boards run scope-off (the oracle) and
@@ -73,8 +64,7 @@ window drains, and ``--telemetry-out PATH`` dumps the merged telemetry +
 scope report as mergeable JSON:
 
   PYTHONPATH=src python -m repro.launch.farm --scope-smoke
-  PYTHONPATH=src python -m repro.launch.farm --scope-smoke --lanes 8 \\
-      --lockstep
+  PYTHONPATH=src python -m repro.launch.farm --scope-smoke --lanes 8
   PYTHONPATH=src python -m repro.launch.farm --steps 8 --scope 2 \\
       --telemetry-out telemetry.json
 
@@ -94,8 +84,6 @@ outputs, every window delivered exactly once across both process
 lifetimes, and ``windows_replayed < windows_committed``:
 
   PYTHONPATH=src python -m repro.launch.farm --killrestart-smoke
-  PYTHONPATH=src python -m repro.launch.farm --killrestart-smoke \\
-      --lockstep
 
 SIGINT (^C) and SIGTERM during a farm run are a GRACEFUL stop: every
 board is cut at its next drain boundary, committed prefixes and
@@ -362,22 +350,19 @@ def submit_soak_straggler(mgr, n_windows: int = 150,
     return board
 
 
-def submit_restart_board(mgr, n_windows: int = 40, evict_at: int = 8,
-                         delay: float = 0.02) -> SoakBoard:
+def submit_restart_board(mgr, n_windows: int = 40,
+                         evict_at: int = 8) -> SoakBoard:
     """A long board with a checkpoint barrier at EVERY window boundary,
     for the checkpointed-requeue gate: its verify force-marks the job
-    mid-stream (first attempt only), so the eviction lands with committed
-    snapshots behind it and the requeued attempt must resume from the
-    last accepted barrier instead of window 0. The per-window ``delay``
-    keeps attempt 1 slow enough that the async control plane's sweep can
-    signal the mark at a drain boundary; the replay runs full speed."""
+    mid-stream (first attempt only), so the eviction lands at the next
+    drain boundary with committed snapshots behind it and the requeued
+    attempt must resume from the last accepted barrier instead of
+    window 0."""
     @jax.jit
     def _body(state, stack):
         return state + jnp.sum(stack), stack * 2.0
 
     def engine(state, shell, stack):
-        if board.job.attempts == 1:
-            time.sleep(delay)
         s, ys = _body(state, stack)
         return s, shell, ys
 
@@ -404,13 +389,13 @@ def submit_restart_board(mgr, n_windows: int = 40, evict_at: int = 8,
     return board
 
 
-def run_restart_smoke(mode: str = "async", slots: int = 3) -> dict:
+def run_restart_smoke(slots: int = 3) -> dict:
     """The ``farm-restart-smoke`` gate: a mid-stream eviction must resume
     from the job's last accepted drain-barrier snapshot. Exits non-zero
     (via ``ok``) unless the evicted board requeued, replayed FEWER windows
     than it had committed, logged a snapshot resume, and still delivered
     outputs bit-identical to an uninterrupted run."""
-    mgr = FarmManager(slots=slots, mode=mode, evict_stragglers=False)
+    mgr = FarmManager(slots=slots, evict_stragglers=False)
     board = submit_restart_board(mgr)
     report = mgr.run(strict=False)
     j = report["jobs"]["restart"]
@@ -423,7 +408,6 @@ def run_restart_smoke(mode: str = "async", slots: int = 3) -> dict:
                   for r in resumes)
           and board.preserved())
     return {
-        "mode": mode,
         "jobs": report["jobs"],
         "resumes": resumes,
         "evictions": report["telemetry"]["evictions"],
@@ -460,7 +444,7 @@ def _chaos_board(mgr, name: str, scale: float, n_windows: int,
     return outs
 
 
-def run_chaos_smoke(seed: int, mode: str = "async", slots: int = 4,
+def run_chaos_smoke(seed: int, slots: int = 4,
                     n_jobs: int = 8, n_windows: int = 6) -> dict:
     """The ``farm-chaos-smoke`` gate: run the toy workload fault-free
     (the oracle), then again under the seed's injection schedule plus one
@@ -471,7 +455,7 @@ def run_chaos_smoke(seed: int, mode: str = "async", slots: int = 4,
         # straggler eviction OFF: wall-time heuristics are the one
         # nondeterministic eviction source, and chaos needs the injected
         # faults to be the ONLY faults
-        m = FarmManager(slots=slots, mode=mode, evict_stragglers=False,
+        m = FarmManager(slots=slots, evict_stragglers=False,
                         watchdog=Watchdog(timeout_s=timeout_s),
                         poll_s=0.01, policy=policy)
         o = {f"board{i}": _chaos_board(m, f"board{i}", float(i + 1),
@@ -507,7 +491,6 @@ def run_chaos_smoke(seed: int, mode: str = "async", slots: int = 4,
             problems.append(f"{name}: outputs diverged from the "
                             f"fault-free oracle")
     return {
-        "mode": mode,
         "seed": seed,
         "schedule": [dataclasses.asdict(i) for i in schedule],
         "faults_injected": len(harness.injector.fired),
@@ -578,8 +561,8 @@ def _submit_lane_boards(mgr, w, n_boards: int, n_steps: int, group: int,
 
 
 def run_lanes_smoke(lanes: int = 8, chaos_lane: bool = False,
-                    mode: str = "async", slots: int = 2,
-                    n_steps: int = 12, group: int = 2) -> dict:
+                    slots: int = 2, n_steps: int = 12,
+                    group: int = 2) -> dict:
     """The ``farm-lanes-smoke`` gate: ``lanes`` identical-arch boards must
     coalesce into one vmap-ed dispatch stream and stay bit-identical to
     the same boards run solo (the oracle). With ``--chaos-lane`` one
@@ -591,13 +574,12 @@ def run_lanes_smoke(lanes: int = 8, chaos_lane: bool = False,
     n_windows = (n_steps + group - 1) // group
 
     # solo oracle: same boards, no lane coalescing, no chaos
-    mgr0 = FarmManager(slots=slots, mode=mode, evict_stragglers=False)
+    mgr0 = FarmManager(slots=slots, evict_stragglers=False)
     oracle = _submit_lane_boards(mgr0, w, lanes, n_steps, group,
                                  chaos_lane=False, lane_key=None)
     mgr0.run()
 
-    mgr = FarmManager(slots=slots, mode=mode, evict_stragglers=False,
-                      lanes=lanes)
+    mgr = FarmManager(slots=slots, evict_stragglers=False, lanes=lanes)
     outs = _submit_lane_boards(mgr, w, lanes, n_steps, group,
                                chaos_lane=chaos_lane,
                                lane_key="lanes-smoke")
@@ -642,7 +624,6 @@ def run_lanes_smoke(lanes: int = 8, chaos_lane: bool = False,
         problems.append(f"unexpected lane vetoes: {tel['lane_vetoes']}")
 
     return {
-        "mode": mode,
         "lanes": lanes,
         "chaos_lane": chaos_lane,
         "jobs": report["jobs"],
@@ -653,8 +634,7 @@ def run_lanes_smoke(lanes: int = 8, chaos_lane: bool = False,
     }
 
 
-def run_scope_smoke(mode: str = "async", lanes: int = 1,
-                    every_n: int = 2, slots: int = 2,
+def run_scope_smoke(lanes: int = 1, every_n: int = 2, slots: int = 2,
                     n_steps: int = 12, group: int = 2) -> dict:
     """The ``farm-scope-smoke`` gate: the SAME boards run scope-off (the
     oracle) and scope-on must deliver bit-identical outputs and final
@@ -667,15 +647,13 @@ def run_scope_smoke(mode: str = "async", lanes: int = 1,
     n = max(1, lanes)
     lane_key = "scope-smoke" if n > 1 else None
 
-    mgr0 = FarmManager(slots=slots, mode=mode, evict_stragglers=False,
-                       lanes=n)
+    mgr0 = FarmManager(slots=slots, evict_stragglers=False, lanes=n)
     oracle = _submit_lane_boards(mgr0, w, n, n_steps, group,
                                  chaos_lane=False, lane_key=lane_key)
     mgr0.run()
 
     spec = ScopeSpec(every_n_windows=every_n)
-    mgr = FarmManager(slots=slots, mode=mode, evict_stragglers=False,
-                      lanes=n)
+    mgr = FarmManager(slots=slots, evict_stragglers=False, lanes=n)
     outs = _submit_lane_boards(mgr, w, n, n_steps, group,
                                chaos_lane=False, lane_key=lane_key,
                                scope=spec)
@@ -707,7 +685,6 @@ def run_scope_smoke(mode: str = "async", lanes: int = 1,
                             f"({row})")
 
     return {
-        "mode": mode,
         "lanes": n,
         "every_n_windows": every_n,
         "jobs": report["jobs"],
@@ -794,8 +771,7 @@ def ledger_board_spec(name: str, scale: float, n_windows: int,
         snapshot_keep=4, max_requeues=4)
 
 
-def run_ledger_farm(ledger_dir: str, mode: str = "async",
-                    recover: bool = False, kill_after=None,
+def run_ledger_farm(ledger_dir: str, recover: bool = False, kill_after=None,
                     n_boards: int = 3, n_windows: int = 24,
                     slots: int = 2) -> dict:
     """One durable-farm process lifetime: fresh (``recover=False``)
@@ -807,10 +783,10 @@ def run_ledger_farm(ledger_dir: str, mode: str = "async",
     OOM kill."""
     ledger = FarmLedger(ledger_dir)
     if recover:
-        mgr = FarmManager.recover(ledger, slots=slots, mode=mode,
+        mgr = FarmManager.recover(ledger, slots=slots,
                                   evict_stragglers=False, poll_s=0.01)
     else:
-        mgr = FarmManager(slots=slots, mode=mode, evict_stragglers=False,
+        mgr = FarmManager(slots=slots, evict_stragglers=False,
                           poll_s=0.01, ledger=ledger)
         for i in range(n_boards):
             mgr.submit_spec(ledger_board_spec(
@@ -826,7 +802,6 @@ def run_ledger_farm(ledger_dir: str, mode: str = "async",
     report = mgr.run(strict=False)
     jobs = report["jobs"]       # empty-journal recover: a minimal report
     out = {
-        "mode": mode,
         "recover": recover,
         "jobs": jobs,
         "recoveries": report["telemetry"].get("recoveries", []),
@@ -859,7 +834,7 @@ def _read_window_files(out_dir: str) -> dict:
     return files
 
 
-def run_killrestart_smoke(mode: str = "async", n_boards: int = 3,
+def run_killrestart_smoke(n_boards: int = 3,
                           n_windows: int = 24, kill_after: int = 8,
                           slots: int = 2) -> dict:
     """The ``farm-killrestart-smoke`` gate: whole-process crash recovery.
@@ -883,7 +858,7 @@ def run_killrestart_smoke(mode: str = "async", n_boards: int = 3,
         os.path.abspath(__file__))))
     base = tempfile.mkdtemp(prefix="zp-killrestart-")
     problems: list = []
-    out: dict = {"mode": mode, "kill_after": kill_after}
+    out: dict = {"kill_after": kill_after}
     try:
         env = dict(os.environ)
         env["PYTHONPATH"] = src_root + os.pathsep \
@@ -891,7 +866,7 @@ def run_killrestart_smoke(mode: str = "async", n_boards: int = 3,
 
         def ledger_cmd(ledger_dir):
             return [sys.executable, "-m", "repro.launch.farm",
-                    "--ledger", ledger_dir, f"--{mode}",
+                    "--ledger", ledger_dir,
                     "--slots", str(slots),
                     "--ledger-boards", str(n_boards),
                     "--ledger-windows", str(n_windows)]
@@ -1001,9 +976,8 @@ def _poison_board(n_windows: int = 4) -> FarmJob:
         state=jnp.float32(0), shell={}, stack_fn=_toy_stack)
 
 
-def run_certify_smoke(work_dir: str | None = None, mode: str = "async",
-                      slots: int = 2, n_boards: int = 2,
-                      n_windows: int = 8) -> dict:
+def run_certify_smoke(work_dir: str | None = None, slots: int = 2,
+                      n_boards: int = 2, n_windows: int = 8) -> dict:
     """The ``farm-certify-smoke`` gate: a ``certify=True`` farm given
     ``n_boards`` healthy boards plus one statically-broken board must
     dead-letter the broken one AT ADMISSION — an unrun quarantine with a
@@ -1015,11 +989,11 @@ def run_certify_smoke(work_dir: str | None = None, mode: str = "async",
     base = work_dir or tempfile.mkdtemp(prefix="zp_certify_")
     own = work_dir is None
     problems = []
-    out = {"mode": mode}
+    out = {}
     try:
         cert_dir = os.path.join(base, "certified")
         ledger = FarmLedger(cert_dir)
-        mgr = FarmManager(slots=slots, mode=mode, evict_stragglers=False,
+        mgr = FarmManager(slots=slots, evict_stragglers=False,
                           poll_s=0.01, ledger=ledger, certify=True)
         for i in range(n_boards):
             mgr.submit_spec(ledger_board_spec(
@@ -1043,8 +1017,8 @@ def run_certify_smoke(work_dir: str | None = None, mode: str = "async",
                             f"{ {k: j['status'] for k, j in healthy.items()} }")
 
         oracle_dir = os.path.join(base, "oracle")
-        oracle = FarmManager(slots=slots, mode=mode,
-                             evict_stragglers=False, poll_s=0.01)
+        oracle = FarmManager(slots=slots, evict_stragglers=False,
+                             poll_s=0.01)
         for i in range(n_boards):
             oracle.submit_spec(ledger_board_spec(
                 f"board{i}", float(i + 1), n_windows, oracle_dir))
@@ -1121,7 +1095,7 @@ def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
              prompt_len: int, verify_seq: int, interval: int = 2,
              synthetic_straggler: bool = False, straggler_factor: float = 6.0,
              roofline: bool = False, seed: int = 0,
-             mode: str = "async", handle_sigint: bool = False,
+             handle_sigint: bool = False,
              scope: ScopeSpec = None, certify: bool = False) -> dict:
     """The mixed farm over ``cfg``: one fused train board (``steps``
     steps of ``batch`` x ``seq``), one decode board (``gen`` tokens per
@@ -1138,7 +1112,7 @@ def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
     # a one-layer verify window), so sub-200ms medians are never flagged
     # however large the ratio — only genuinely slow boards are evictable
     mgr = FarmManager(slots=slots, straggler_factor=straggler_factor,
-                      straggler_min_s=0.2, mode=mode, certify=certify)
+                      straggler_min_s=0.2, certify=certify)
 
     capture = WindowCapture() if roofline else None
     losses = submit_train_job(mgr, cfg, steps, interval, batch=batch,
@@ -1166,27 +1140,11 @@ def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
         for j in mgr.jobs:
             j.scope = scope
 
-    straggler = None
     soak = None
     if synthetic_straggler:
-        if mode == "async":
-            # wall-time path: a long-workload board gone slow, caught by
-            # the watchdog from measured window wall alone
-            soak = submit_soak_straggler(mgr)
-            straggler = soak.job
-        else:
-            # lockstep path: dispatch-cost observations on the short
-            # verify streams are too few to flag (window 0 is compile), so
-            # the board is force-marked — the deterministic oracle path
-            straggler = mgr.jobs[-1]        # last verify board
-            inner = straggler.engine
-
-            def slow_engine(state, shell, stack):
-                time.sleep(0.15)            # a board gone slow
-                return inner(state, shell, stack)
-
-            straggler.engine = slow_engine
-            mgr.force_evict(straggler.name)
+        # a long-workload board gone slow, caught by the watchdog from
+        # measured window wall alone
+        soak = submit_soak_straggler(mgr)
 
     setup_s = time.perf_counter() - t_setup
     prewarm_s = prewarm(mgr, list(dict.fromkeys(s.device for s in slots)))
@@ -1202,7 +1160,6 @@ def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
         # graceful stop: partial report + telemetry, no pass/fail gating —
         # committed prefixes and published snapshots were kept
         return {
-            "mode": mode,
             "interrupted": True,
             "exit_code": drainer.exit_code if drainer else 130,
             "prewarm_s": round(prewarm_s, 3),
@@ -1214,7 +1171,6 @@ def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
     reps = finalize()
 
     out = {
-        "mode": mode,
         "setup_s": setup_s,
         "prewarm_s": round(prewarm_s, 3),
         "run_s": run_s,
@@ -1243,20 +1199,17 @@ def run_farm(cfg, slots, *, steps: int, gen: int, batch: int, seq: int,
 
     ok = all(j["status"] == "done" for j in report["jobs"].values())
     ok = ok and not any(r.diverged for r in reps.values())
-    if synthetic_straggler:
-        evs = report["telemetry"]["evictions"]
-        evicted = {e["job"] for e in evs}
-        ok = ok and straggler.name in evicted \
-            and report["jobs"][straggler.name]["requeues"] >= 1
-        if soak is not None:
-            # the CI wall-time-divergence gate: the board must have been
-            # caught by the watchdog (not a forced mark), and its delivered
-            # outputs must be bit-identical to an uninterrupted run
-            ok = ok and any(e["job"] == straggler.name
-                            and e["why"] == "straggler" for e in evs)
-            ok = ok and soak.preserved()
-            out["soak"] = {"windows": len(soak.outputs),
-                           "preserved": soak.preserved()}
+    if soak is not None:
+        # the CI wall-time-divergence gate: the board must have been
+        # caught by the watchdog (not a forced mark), requeued, and its
+        # delivered outputs must be bit-identical to an uninterrupted run
+        name = soak.job.name
+        ok = ok and report["jobs"][name]["requeues"] >= 1
+        ok = ok and any(e["job"] == name and e["why"] == "straggler"
+                        for e in report["telemetry"]["evictions"])
+        ok = ok and soak.preserved()
+        out["soak"] = {"windows": len(soak.outputs),
+                       "preserved": soak.preserved()}
     out["ok"] = ok
     return out
 
@@ -1335,34 +1288,25 @@ def main():
                          "schedule; exit non-zero unless every fault was "
                          "recovered with oracle-identical outputs and "
                          "the poisoned board quarantined")
-    g = ap.add_mutually_exclusive_group()
-    g.add_argument("--async", dest="mode", action="store_const",
-                   const="async", default="async",
-                   help="per-slot dispatcher threads (default)")
-    g.add_argument("--lockstep", dest="mode", action="store_const",
-                   const="lockstep",
-                   help="single-thread round-robin host loop (the "
-                        "bit-identity oracle)")
     args = ap.parse_args()
     enable_compile_cache()
 
     if args.certify_smoke:
-        out = run_certify_smoke(mode=args.mode, slots=args.slots)
+        out = run_certify_smoke(slots=args.slots)
         print(json.dumps(out, indent=1, default=float))
         if not out["ok"]:
             sys.exit(1)
         return
 
     if args.killrestart_smoke:
-        out = run_killrestart_smoke(mode=args.mode)
+        out = run_killrestart_smoke()
         print(json.dumps(out, indent=1, default=float))
         if not out["ok"]:
             sys.exit(1)
         return
 
     if args.ledger:
-        out = run_ledger_farm(args.ledger, mode=args.mode,
-                              recover=args.recover,
+        out = run_ledger_farm(args.ledger, recover=args.recover,
                               kill_after=args.kill_after_commits,
                               n_boards=args.ledger_boards,
                               n_windows=args.ledger_windows,
@@ -1373,19 +1317,19 @@ def main():
         return
 
     if args.scope_smoke:
-        out = run_scope_smoke(mode=args.mode, lanes=args.lanes or 1,
+        out = run_scope_smoke(lanes=args.lanes or 1,
                               every_n=args.scope or 2, slots=args.slots)
         if args.telemetry_out:
             write_telemetry(args.telemetry_out,
                             {"telemetry": {"scope": out["scope"]}},
-                            f"scope-smoke-{args.mode}-l{args.lanes or 1}")
+                            f"scope-smoke-l{args.lanes or 1}")
         print(json.dumps(out, indent=1, default=float))
         if not out["ok"]:
             sys.exit(1)
         return
 
     if args.restart_smoke:
-        out = run_restart_smoke(mode=args.mode, slots=args.slots)
+        out = run_restart_smoke(slots=args.slots)
         print(json.dumps(out, indent=1, default=float))
         if not out["ok"]:
             sys.exit(1)
@@ -1393,16 +1337,14 @@ def main():
 
     if args.lanes is not None:
         out = run_lanes_smoke(lanes=args.lanes,
-                              chaos_lane=args.chaos_lane,
-                              mode=args.mode)
+                              chaos_lane=args.chaos_lane)
         print(json.dumps(out, indent=1, default=float))
         if not out["ok"]:
             sys.exit(1)
         return
 
     if args.chaos is not None:
-        out = run_chaos_smoke(args.chaos, mode=args.mode,
-                              slots=args.slots)
+        out = run_chaos_smoke(args.chaos, slots=args.slots)
         print(json.dumps(out, indent=1, default=float))
         if not out["ok"]:
             sys.exit(1)
@@ -1417,7 +1359,7 @@ def main():
                        interval=args.sample_interval,
                        synthetic_straggler=args.synthetic_straggler,
                        straggler_factor=args.straggler_factor,
-                       roofline=args.roofline, mode=args.mode,
+                       roofline=args.roofline,
                        handle_sigint=True, scope=scope,
                        certify=args.certify)
     except KeyboardInterrupt:
@@ -1428,7 +1370,7 @@ def main():
         sys.exit(130)
     if args.telemetry_out:
         write_telemetry(args.telemetry_out, out,
-                        f"farm-{args.mode}-{args.arch}-s{args.steps}")
+                        f"farm-{args.arch}-s{args.steps}")
     if out.get("interrupted"):
         print(json.dumps(out, indent=1, default=float))
         print(out["summary"], file=sys.stderr)

@@ -47,9 +47,10 @@ def _healthy_job(name="healthy", n=6):
     return job, outs
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_certify_gate_dead_letters_poison_board(mode):
-    mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False,
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_certify_gate_dead_letters_poison_board(slots):
+    mgr = FarmManager(slots=slots, evict_stragglers=False,
                       poll_s=0.01, certify=True)
     job, outs = _healthy_job()
     mgr.submit(job)
@@ -69,7 +70,7 @@ def test_certify_gate_dead_letters_poison_board(mode):
                for q in report["telemetry"]["quarantined"])
 
     # the healthy board's stream is bit-identical to an uncertified run
-    oracle_mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False,
+    oracle_mgr = FarmManager(slots=slots, evict_stragglers=False,
                              poll_s=0.01)
     ojob, oouts = _healthy_job()
     oracle_mgr.submit(ojob)
@@ -82,7 +83,7 @@ def test_certify_gate_dead_letters_poison_board(mode):
 def test_certify_gate_journals_certify_fail(tmp_path):
     from repro.farm import FarmLedger
     ledger = FarmLedger(str(tmp_path))
-    mgr = FarmManager(slots=2, mode="lockstep", evict_stragglers=False,
+    mgr = FarmManager(slots=2, evict_stragglers=False,
                       ledger=ledger, certify=True)
     mgr.submit(_poison_job())
     recs = [r for r in ledger.records() if r["kind"] == "certify_fail"]
@@ -97,15 +98,15 @@ def test_certify_gate_journals_certify_fail(tmp_path):
 
 
 def test_certify_off_by_default():
-    mgr = FarmManager(slots=2, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=2, evict_stragglers=False)
     poison = mgr.submit(_poison_job())
     assert poison.status == "queued"    # uncertified farms behave as before
 
 
 def test_certify_smoke_gate(tmp_path):
     from repro.launch.farm import run_certify_smoke
-    out = run_certify_smoke(work_dir=str(tmp_path), mode="lockstep",
-                            n_boards=2, n_windows=4)
+    out = run_certify_smoke(work_dir=str(tmp_path), n_boards=2,
+                            n_windows=4)
     assert out["ok"], out["problems"]
 
 

@@ -302,7 +302,7 @@ def test_train_boards_verify_on_their_own_chip():
         dev = lambda tree: sorted({str(d) for leaf in jax.tree.leaves(tree)
                                    for d in leaf.devices()})
         seen = {}
-        mgr = FarmManager(slots=4, mode="async", evict_stragglers=False)
+        mgr = FarmManager(slots=4, evict_stragglers=False)
         for b in range(4):
             box = {}
 

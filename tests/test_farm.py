@@ -2,7 +2,6 @@
 dynamic admission, watchdog straggler eviction, forced eviction + requeue
 output preservation, drain-veto fault handling, and the scheduler-driven
 roofline capture."""
-import time
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +61,17 @@ def _baseline():
     return out, states
 
 
+# ------------------------------------------------------------------ mode --
+def test_farm_accepts_only_the_slot_thread_mode():
+    """``mode`` names the one host loop: "async" changes nothing, the
+    removed lockstep loop and any other value are refused."""
+    assert FarmManager(mode="async").report()["jobs"] == {}
+    with pytest.raises(ValueError, match="lockstep host loop was removed"):
+        FarmManager(mode="lockstep")
+    with pytest.raises(ValueError, match="unknown farm mode"):
+        FarmManager(mode="threads")
+
+
 # ------------------------------------------------------------- placement --
 def test_enumerate_slots_single_device_fallback():
     """On a single-device host, min_slots virtual seats round-robin over
@@ -108,9 +118,10 @@ def test_farm_runs_three_concurrent_jobs_and_queues_extras():
 def test_farm_forced_eviction_requeues_and_preserves_outputs():
     """Eviction + requeue contract: partial outputs are discarded, the
     window stream replays on a DIFFERENT slot, and every job's delivered
-    outputs are bit-identical to the no-eviction baseline."""
+    outputs are bit-identical to the no-eviction baseline. Straggler
+    eviction is off: the forced mark must be the only eviction."""
     base, _ = _baseline()
-    mgr = FarmManager(slots=3)
+    mgr = FarmManager(slots=3, evict_stragglers=False)
     col = _submit(mgr)
     mgr.force_evict("job1")
     rep = mgr.run()
@@ -125,16 +136,31 @@ def test_farm_forced_eviction_requeues_and_preserves_outputs():
             np.testing.assert_array_equal(a, b)
 
 
-def test_farm_watchdog_detects_and_evicts_straggler():
-    """A genuinely slow board trips Watchdog.stragglers via the per-slot
-    dispatch-cost observations and is evicted + requeued, outputs intact."""
-    def slow(state, shell, stack):
-        time.sleep(0.05)
+def test_farm_watchdog_detects_and_evicts_straggler(virtual_walls):
+    """A slow board trips Watchdog.stragglers via the per-slot window-wall
+    observations and is evicted + requeued, outputs intact. The walls are
+    virtual (each engine call spends 1 ms, the slow board's first attempt
+    50 ms), and the slow board holds its window-1 verify until the
+    verdict, so it cannot finish before the control plane judges it."""
+    vw = virtual_walls
+    mgr = FarmManager(slots=3, straggler_factor=2.0, clock=vw.clock,
+                      watchdog=vw.watchdog)
+
+    def engine(state, shell, stack):
+        vw.spend(0.001)
         return _engine(state, shell, stack)
 
+    def slow(state, shell, stack):
+        vw.spend(0.05 if mgr.jobs[1].attempts == 1 else 0.001)
+        return _engine(state, shell, stack)
+
+    def hold(plan, records, ys):
+        if plan.index == 1 and mgr.jobs[1].attempts == 1:
+            vw.wait_for_verdict()
+
     base, _ = _baseline()
-    mgr = FarmManager(slots=3, straggler_factor=2.0)
-    col = _submit(mgr, engines={1: slow})
+    col = _submit(mgr, engines={0: engine, 1: slow, 2: engine})
+    mgr.jobs[1].verify = hold
     rep = mgr.run()
     ev = rep["telemetry"]["evictions"]
     assert [e["job"] for e in ev] == ["job1"] and ev[0]["why"] == "straggler"

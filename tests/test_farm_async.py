@@ -1,19 +1,19 @@
-"""Async ZP-Farm tests: per-slot dispatcher threads vs the lockstep
-oracle — bit-identical outputs (plain runs, forced eviction + requeue,
-checkpoint DrainBarrier veto mid-stream), wall-time straggler eviction,
+"""ZP-Farm slot-thread tests: per-slot dispatcher threads vs a plain
+``WindowScheduler.run_many`` pass over the same clients — bit-identical
+outputs (plain runs, forced eviction + requeue, checkpoint DrainBarrier
+veto mid-stream), wall-time straggler eviction,
 thread confinement of each job's dispatches, hung-board abandonment, the
 per-slot host-overhead telemetry, and admission that stops walking the
 queue once no seat is free."""
 import random
 import threading
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import DrainBarrier, iter_windows
+from repro.core import Client, DrainBarrier, WindowScheduler, iter_windows
 from repro.core.watchdog import Watchdog
 from repro.farm import FarmError, FarmJob, FarmManager
 from repro.farm.manager import _SlotWorker
@@ -56,37 +56,45 @@ def _submit(mgr, n_jobs=3, engines=None, n_items=6, seed_base=0, **extra):
     return col
 
 
-def _run_mode(mode, n_jobs=3, n_items=6, seed_base=0, **mgr_kw):
-    mgr = FarmManager(slots=3, mode=mode, **mgr_kw)
-    col = _submit(mgr, n_jobs=n_jobs, n_items=n_items, seed_base=seed_base)
-    rep = mgr.run()
-    states = {n: np.asarray(mgr.results[n][0]) for n in col}
-    return col, states, rep
+def _reference(n_jobs=3, n_items=6, seed_base=0, barriers=()):
+    """The same jobs straight through a plain ``WindowScheduler.run_many``
+    pass on this thread, no farm: per-job outputs and final states."""
+    sched = WindowScheduler(interval=1, overlap=True, drain_fn=None,
+                            stack_fn=None)
+    col = {f"job{s}": [] for s in range(n_jobs)}
+    finals = sched.run_many(
+        [Client(_engine, _windows(seed_base + s, n_items=n_items),
+                jnp.float32(0), {}, stack_fn=_stack, drain_fn=None,
+                barriers=barriers) for s in range(n_jobs)],
+        on_drain=lambda k, p, r, y: col[f"job{k}"].append(np.asarray(y)))
+    states = {f"job{k}": np.asarray(st) for k, (st, _) in enumerate(finals)}
+    return col, states
 
 
 # ----------------------------------------------------------- determinism --
 @pytest.mark.parametrize("seed_base", [0, 7])
-def test_async_bit_identical_to_lockstep(seed_base):
+def test_async_bit_identical_to_run_many(seed_base):
     """The headline contract: the threaded farm delivers byte-for-byte the
-    outputs and final states of the lockstep oracle, for every job."""
-    lock_col, lock_states, _ = _run_mode("lockstep", seed_base=seed_base)
-    async_col, async_states, rep = _run_mode("async", seed_base=seed_base)
-    assert rep["mode"] == "async"
+    outputs and final states of a plain run_many pass, for every job."""
+    ref_col, ref_states = _reference(seed_base=seed_base)
+    mgr = FarmManager(slots=3)
+    col = _submit(mgr, seed_base=seed_base)
+    rep = mgr.run()
     assert all(j["status"] == "done" for j in rep["jobs"].values())
-    for name in lock_col:
-        assert len(async_col[name]) == len(lock_col[name]) == 3
-        for a, b in zip(lock_col[name], async_col[name]):
+    for name in ref_col:
+        assert len(col[name]) == len(ref_col[name]) == 3
+        for a, b in zip(ref_col[name], col[name]):
             np.testing.assert_array_equal(a, b)
-        np.testing.assert_array_equal(lock_states[name],
-                                      async_states[name])
+        np.testing.assert_array_equal(ref_states[name],
+                                      np.asarray(mgr.results[name][0]))
 
 
 def test_async_forced_eviction_requeues_and_preserves_outputs():
-    """Eviction under threads keeps the lockstep contract: partial outputs
-    discarded, replay on a DIFFERENT slot, delivered outputs bit-identical
-    to the no-eviction lockstep baseline, exactly once."""
-    base, _, _ = _run_mode("lockstep")
-    mgr = FarmManager(slots=3, mode="async")
+    """Eviction under threads: partial outputs discarded, replay on a
+    DIFFERENT slot, delivered outputs bit-identical to the run_many
+    reference, exactly once."""
+    base, _ = _reference()
+    mgr = FarmManager(slots=3)
     col = _submit(mgr)
     mgr.force_evict("job1")
     rep = mgr.run()
@@ -105,60 +113,72 @@ def test_async_forced_eviction_requeues_and_preserves_outputs():
 def test_async_barrier_veto_midstream_then_requeue_commits_once():
     """A per-job checkpoint DrainBarrier is VETOED when the drain verifier
     rejects the window behind it; the evicted job replays on another slot
-    and the replay's commits (and outputs) match the lockstep oracle."""
-    def run_mode(mode):
-        commits = []
-        failed = {"n": 0}
+    and the replay's commits (and outputs) match the run_many
+    reference's."""
+    def barrier(commits):
+        return (DrainBarrier(every=4, action=lambda state, step:
+                             commits.append((step, float(state)))),)
 
-        def verify(plan, records, ys):
-            # reject the window starting at step 2 — first attempt only
-            if plan.start == 2 and failed["n"] == 0:
-                failed["n"] += 1
-                raise AssertionError("synthetic commit divergence")
+    ref_commits = []
+    ref, _ = _reference(n_jobs=1, barriers=barrier(ref_commits))
+    commits = []
+    failed = {"n": 0}
 
-        got = []
-        mgr = FarmManager(slots=3, mode=mode)
-        mgr.submit(FarmJob(
-            name="ckpt", engine=_engine, windows=_windows(0),
-            state=jnp.float32(0), shell={}, stack_fn=_stack,
-            verify=verify,
-            on_drain=lambda p, r, y: got.append(np.asarray(y)),
-            barriers=(DrainBarrier(
-                every=4,
-                action=lambda state, step: commits.append(
-                    (step, float(state)))),)))
-        rep = mgr.run()
-        return commits, got, rep
+    def verify(plan, records, ys):
+        # reject the window starting at step 2 — first attempt only
+        if plan.start == 2 and failed["n"] == 0:
+            failed["n"] += 1
+            raise AssertionError("synthetic commit divergence")
 
-    lock_commits, lock_got, lock_rep = run_mode("lockstep")
-    async_commits, async_got, async_rep = run_mode("async")
-    for rep in (lock_rep, async_rep):
-        assert rep["jobs"]["ckpt"]["status"] == "done"
-        assert rep["jobs"]["ckpt"]["requeues"] == 1
-        assert rep["telemetry"]["drain_vetoes"] == 1
-        assert "veto" in rep["telemetry"]["evictions"][0]["why"]
+    got = []
+    mgr = FarmManager(slots=3)
+    mgr.submit(FarmJob(
+        name="ckpt", engine=_engine, windows=_windows(0),
+        state=jnp.float32(0), shell={}, stack_fn=_stack,
+        verify=verify,
+        on_drain=lambda p, r, y: got.append(np.asarray(y)),
+        barriers=barrier(commits)))
+    rep = mgr.run()
+    assert rep["jobs"]["ckpt"]["status"] == "done"
+    assert rep["jobs"]["ckpt"]["requeues"] == 1
+    assert rep["telemetry"]["drain_vetoes"] == 1
+    assert "veto" in rep["telemetry"]["evictions"][0]["why"]
     # attempt 1 faulted at the window behind boundary 4: its commit was
-    # vetoed, so the ONLY commit is the clean replay's — in both modes,
-    # with the same committed state
-    assert async_commits == lock_commits
-    assert len(async_commits) == 1 and async_commits[0][0] == 4
-    assert len(async_got) == len(lock_got) == 3
-    for a, b in zip(lock_got, async_got):
+    # vetoed, so the ONLY commit is the clean replay's, with the same
+    # committed state as the reference's
+    assert commits == ref_commits
+    assert len(commits) == 1 and commits[0][0] == 4
+    assert len(got) == len(ref["job0"]) == 3
+    for a, b in zip(ref["job0"], got):
         np.testing.assert_array_equal(a, b)
 
 
 # ------------------------------------------------------- wall-time signals --
-def test_async_watchdog_evicts_wall_time_straggler():
-    """A genuinely slow board is flagged from its MEASURED window wall
-    (observed on its own slot thread) and evicted mid-stream; outputs are
-    preserved via requeue + replay."""
-    def slow(state, shell, stack):
-        time.sleep(0.05)
+def test_async_watchdog_evicts_wall_time_straggler(virtual_walls):
+    """A slow board is flagged from its MEASURED window wall (observed on
+    its own slot thread) and evicted mid-stream; outputs are preserved via
+    requeue + replay. The walls are virtual (1 ms an engine call, 50 ms on
+    the slow board's first attempt), and the slow board holds its window-1
+    verify until the verdict, so it cannot finish before it is judged."""
+    vw = virtual_walls
+    base, _ = _reference(n_items=10)
+    mgr = FarmManager(slots=3, straggler_factor=2.0, clock=vw.clock,
+                      watchdog=vw.watchdog)
+
+    def engine(state, shell, stack):
+        vw.spend(0.001)
         return _engine(state, shell, stack)
 
-    base, _, _ = _run_mode("lockstep", n_items=10)
-    mgr = FarmManager(slots=3, mode="async", straggler_factor=2.0)
-    col = _submit(mgr, engines={1: slow}, n_items=10)
+    def slow(state, shell, stack):
+        vw.spend(0.05 if mgr.jobs[1].attempts == 1 else 0.001)
+        return _engine(state, shell, stack)
+
+    def hold(plan, records, ys):
+        if plan.index == 1 and mgr.jobs[1].attempts == 1:
+            vw.wait_for_verdict()
+
+    col = _submit(mgr, engines={0: engine, 1: slow, 2: engine}, n_items=10)
+    mgr.jobs[1].verify = hold
     rep = mgr.run()
     ev = rep["telemetry"]["evictions"]
     assert [e["job"] for e in ev] == ["job1"]
@@ -186,7 +206,7 @@ def test_async_thread_confinement_and_per_thread_tagging():
             return _engine(state, shell, stack)
         return engine
 
-    mgr = FarmManager(slots=3, mode="async")
+    mgr = FarmManager(slots=3)
     _submit(mgr, engines={s: make_engine(f"job{s}") for s in range(3)})
     rep = mgr.run()
     main = threading.current_thread().name
@@ -211,8 +231,8 @@ def test_async_hung_board_abandoned_and_job_requeued():
             release.wait(timeout=30.0)
         return _engine(state, shell, stack)
 
-    base, _, _ = _run_mode("lockstep", n_jobs=2)
-    mgr = FarmManager(slots=2, mode="async",
+    base, _ = _reference(n_jobs=2)
+    mgr = FarmManager(slots=2,
                       watchdog=Watchdog(timeout_s=0.3),
                       evict_stragglers=False)
     col = _submit(mgr, n_jobs=2, engines={1: hang_once})
@@ -237,7 +257,7 @@ def test_async_queue_depth_two_spreads_before_stacking():
     """With slot_queue_depth=2, admission is least-loaded-first: three
     equal jobs land on three DIFFERENT slots (full parallelism), not two
     pre-staged behind one board."""
-    mgr = FarmManager(slots=3, mode="async", slot_queue_depth=2)
+    mgr = FarmManager(slots=3, slot_queue_depth=2)
     _submit(mgr)
     rep = mgr.run()
     assert all(j["status"] == "done" for j in rep["jobs"].values())
@@ -250,7 +270,7 @@ def test_async_telemetry_reports_host_overhead_channels():
     """The async report attributes per-slot host overhead: queue wait,
     dispatch wall, drain wall, and idle gaps all carry samples, and the
     printable summary includes the host line."""
-    mgr = FarmManager(slots=2, mode="async")
+    mgr = FarmManager(slots=2)
     _submit(mgr, n_jobs=4)              # 4 jobs on 2 slots: queuing + idle
     rep = mgr.run()
     t = rep["telemetry"]
@@ -272,7 +292,7 @@ def _control_state(n_jobs, n_slots=4, full=(0, 1, 2, 3), running=True):
     ``n_jobs`` queued, the slots in ``full`` at capacity (depth 1) and, if
     ``running``, runs in flight. One admission tick can then be driven by
     hand and its decisions read off the slot inboxes."""
-    mgr = FarmManager(slots=n_slots, mode="async")
+    mgr = FarmManager(slots=n_slots)
     for j in range(n_jobs):
         mgr.submit(FarmJob(name=f"q{j}", engine=_engine,
                            windows=_windows(j, n_items=2),
@@ -313,7 +333,7 @@ def test_admission_tick_with_every_seat_full_examines_nothing(fourth):
         else:
             getattr(mgr, "_" + fourth).add(name)
     before = [j.name for j in mgr.queue]
-    mgr._assign_async()
+    mgr._assign()
     assert [j.name for j in mgr.queue] == before
     assert _seated(mgr) == {}
     assert _admission(mgr) == {"ticks": 1, "examined": 0, "assigned": 0}
@@ -327,7 +347,7 @@ def test_admission_walk_stops_at_the_last_free_seat():
     free = mgr.slots[3].name
     mgr.queue[0].not_before = float("inf")      # backing off
     mgr._avoid["q1"] = free                     # only seat is its avoided one
-    mgr._assign_async()
+    mgr._assign()
     assert _seated(mgr) == {free: ["q2"]}
     assert [j.name for j in mgr.queue] == (
         ["q0", "q1"] + [f"q{j}" for j in range(3, 50)])
@@ -341,7 +361,7 @@ def test_admission_passes_over_a_job_whose_only_seat_is_its_avoided_one():
     mgr = _control_state(2, full=(0, 1, 2))
     free = mgr.slots[3].name
     mgr._avoid["q0"] = free
-    mgr._assign_async()
+    mgr._assign()
     assert _seated(mgr) == {free: ["q1"]}
     assert [j.name for j in mgr.queue] == ["q0"]
     assert mgr._avoid == {"q0": free}
@@ -356,11 +376,11 @@ def test_admission_with_no_seat_and_nothing_running(backing_off):
     mgr._lost = {s.name for s in mgr.slots}
     if backing_off:
         mgr.queue[7].not_before = float("inf")
-        mgr._assign_async()
+        mgr._assign()
         assert len(mgr.queue) == 20 and _seated(mgr) == {}
     else:
         with pytest.raises(FarmError, match="no live slots"):
-            mgr._assign_async()
+            mgr._assign()
 
 
 def _full_walk(mgr):
@@ -374,7 +394,7 @@ def _full_walk(mgr):
             deferred.append(job)
             backing_off = True
             continue
-        slot = mgr._pick_async_slot(mgr._avoid.get(job.name))
+        slot = mgr._pick_slot(mgr._avoid.get(job.name))
         if slot is None:
             deferred.append(job)
             continue
@@ -383,7 +403,7 @@ def _full_walk(mgr):
         assigned += 1
     mgr.queue.extendleft(reversed(deferred))
     if not assigned and not mgr._running and mgr.queue and not backing_off:
-        slot = mgr._pick_async_slot(None)
+        slot = mgr._pick_slot(None)
         if slot is not None:
             job = mgr.queue.popleft()
             mgr._avoid.pop(job.name, None)
@@ -399,7 +419,7 @@ def _random_control_state(seed):
     rng = random.Random(seed)
     depth = rng.choice([1, 1, 2, 3])
     n_slots = rng.randint(1, 5)
-    mgr = FarmManager(slots=n_slots, mode="async", slot_queue_depth=depth,
+    mgr = FarmManager(slots=n_slots, slot_queue_depth=depth,
                       clock=lambda: 100.0)
     mgr.slots = enumerate_slots(min_slots=n_slots,
                                 lane_capacity=rng.choice([1, 1, 2, 3]))
@@ -455,7 +475,7 @@ def test_admission_tick_decides_as_the_full_walk_does():
     for seed in range(300):
         got = _random_control_state(seed)
         want = _random_control_state(seed)
-        d_got = _decisions(got, got._assign_async)
+        d_got = _decisions(got, got._assign)
         d_want = _decisions(want, lambda: _full_walk(want))
         assert d_got == d_want, seed
         seated += bool(d_got[0])
@@ -463,15 +483,13 @@ def test_admission_tick_decides_as_the_full_walk_does():
     assert seated > 100 and raised > 0     # both branches were exercised
 
 
-def test_fault_free_farm_counts_admission_and_matches_lockstep():
+def test_fault_free_farm_counts_admission_and_matches_run_many():
     """A fault-free 4-slot farm of 48 short boards seats every board once,
     in queue order, examining no job it does not seat, and delivers the
-    lockstep oracle's outputs. Straggler eviction is off: on a loaded host
-    it would requeue a board, and the farm would not be fault-free."""
-    lock_mgr = FarmManager(slots=4, mode="lockstep")
-    base = _submit(lock_mgr, n_jobs=48, n_items=4)
-    lock_mgr.run()
-    mgr = FarmManager(slots=4, mode="async", evict_stragglers=False)
+    run_many reference's outputs. Straggler eviction is off: on a loaded
+    host it would requeue a board, and the farm would not be fault-free."""
+    base, _ = _reference(n_jobs=48, n_items=4)
+    mgr = FarmManager(slots=4, evict_stragglers=False)
     col = _submit(mgr, n_jobs=48, n_items=4)
     order = []
     dispatch = mgr._dispatch_to_slot

@@ -12,8 +12,8 @@ from repro.checkpoint import CheckpointManager, MemorySnapshotStore
 from repro.core import DrainBarrier
 from repro.farm import (FailurePolicy, FarmError, FarmJob, FarmManager,
                         FarmTelemetry, enumerate_slots)
-from repro.farm.chaos import (ChaosInjector, ChaosSnapshotStore, Injection,
-                              build_schedule)
+from repro.farm.chaos import (KINDS, ChaosInjector, ChaosSnapshotStore,
+                              Injection, build_schedule)
 from repro.launch.farm import run_chaos_smoke
 
 
@@ -55,15 +55,22 @@ def _assert_stream(outs, scale, n):
 # ------------------------------------------------------- the headline gate --
 @pytest.mark.filterwarnings(
     "ignore::pytest.PytestUnhandledThreadExceptionWarning")
-@pytest.mark.parametrize("mode,seed", [("async", 7), ("lockstep", 7),
-                                       ("async", 42)])
-def test_chaos_smoke_recovers_every_fault(mode, seed):
+@pytest.mark.parametrize("slots,n_jobs,seed", [
+    pytest.param(4, 8, 7, id="async-7"),
+    pytest.param(1, 5, 7, id="one-slot-7"),
+    pytest.param(4, 8, 42, id="async-42")])
+def test_chaos_smoke_recovers_every_fault(slots, n_jobs, seed):
     """The acceptance gate: a seeded schedule with >= 5 distinct fault
     kinds fires in full, every fault is recovered, non-quarantined boards
     deliver bit-identical-to-oracle outputs, and the genuinely poisoned
-    board is dead-lettered instead of raising."""
-    out = run_chaos_smoke(seed, mode=mode, slots=4)
+    board is dead-lettered instead of raising. A one-slot farm cannot
+    outlive its only seat, so its five boards take the menu's first five
+    kinds — none that writes the slot off — and every requeue returns to
+    that seat."""
+    out = run_chaos_smoke(seed, slots=slots, n_jobs=n_jobs)
     assert out["ok"], out["problems"]
+    if slots == 1:
+        assert {i["kind"] for i in out["schedule"]} == set(KINDS[:5])
     assert len({i["kind"] for i in out["schedule"]}) >= 5
     assert out["faults_injected"] == len(out["schedule"])
     assert out["quarantined"] == ["poison"]
@@ -72,23 +79,20 @@ def test_chaos_smoke_recovers_every_fault(mode, seed):
 
 
 def test_schedule_is_seed_deterministic_and_mode_scoped():
-    mgr = FarmManager(slots=2, mode="lockstep")
+    mgr = FarmManager(slots=2)
     for i in range(8):
         _submit(mgr, f"b{i}")
     assert build_schedule(3, mgr.jobs) == build_schedule(3, mgr.jobs)
     assert build_schedule(3, mgr.jobs) != build_schedule(4, mgr.jobs)
-    lock_kinds = {i.kind for i in
-                  build_schedule(3, mgr.jobs, mode="lockstep")}
-    # the control thread cannot detect its own hang: async-only kinds
-    # never appear in a lockstep schedule
-    assert not lock_kinds & {"hung_drain", "thread_death", "results_stall"}
-    assert len(lock_kinds) >= 5
+    # one menu: eight boards with barriers give every kind a board
+    assert {i.kind for i in build_schedule(3, mgr.jobs)} == set(KINDS)
 
 
 # ------------------------------------------------- quarantine / dead-letter --
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_exhausted_budget_quarantines_instead_of_raising(mode):
-    mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False,
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_exhausted_budget_quarantines_instead_of_raising(slots):
+    mgr = FarmManager(slots=slots, evict_stragglers=False,
                       poll_s=0.01, policy=FailurePolicy(quarantine=True))
 
     def poison(state, shell, stack):
@@ -116,7 +120,7 @@ def test_exhausted_budget_quarantines_instead_of_raising(mode):
 
 
 def test_legacy_no_policy_marks_failed_and_strict_raises():
-    mgr = FarmManager(slots=2, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=2, evict_stragglers=False)
 
     def poison(state, shell, stack):
         raise RuntimeError("dead board")
@@ -136,7 +140,7 @@ def test_retry_backoff_gates_readmission():
     assert policy.backoff_for(1) == 0.05
     assert policy.backoff_for(2) == 0.10
     assert policy.backoff_for(10) == 0.2        # capped
-    mgr = FarmManager(slots=2, mode="async", evict_stragglers=False,
+    mgr = FarmManager(slots=2, evict_stragglers=False,
                       poll_s=0.005, policy=policy)
     flaky = {"left": 2}
 
@@ -173,7 +177,7 @@ def test_flapping_slot_trips_breaker_and_readmits_after_canary():
     policy = FailurePolicy(breaker_window=4, breaker_threshold=2,
                            breaker_cooldown_s=0.0)
     slots = enumerate_slots(min_slots=2)
-    mgr = FarmManager(slots=slots, mode="async", evict_stragglers=False,
+    mgr = FarmManager(slots=slots, evict_stragglers=False,
                       poll_s=0.01, policy=policy)
     flappy = slots[0].name
     inj = ChaosInjector(telemetry=mgr.telemetry)
@@ -210,7 +214,7 @@ def test_torn_disk_snapshot_falls_back_to_previous_step(tmp_path, kind):
     """A truncated/corrupted ON-DISK snapshot: the requeue restores the
     newest older verifiable step, rewinds its cursor, logs the fallback,
     and still delivers a bit-identical stream."""
-    mgr = FarmManager(slots=2, mode="lockstep", evict_stragglers=False,
+    mgr = FarmManager(slots=2, evict_stragglers=False,
                       policy=FailurePolicy(quarantine=True))
     inj = ChaosInjector(telemetry=mgr.telemetry)
     job, outs = _submit(mgr, "ckpt", scale=2.0, n=6)
@@ -239,7 +243,7 @@ def test_no_verifiable_snapshot_replays_from_window_zero():
     """Corrupting the job's ONLY published snapshot leaves nothing
     verifiable: the requeue rewinds to a window-0 replay (verifier
     included) and the fallback is logged with got_step=None."""
-    mgr = FarmManager(slots=2, mode="lockstep", evict_stragglers=False,
+    mgr = FarmManager(slots=2, evict_stragglers=False,
                       policy=FailurePolicy(quarantine=True))
     inj = ChaosInjector(telemetry=mgr.telemetry)
     job, outs = _submit(mgr, "solo", scale=2.0, n=4)
@@ -293,9 +297,10 @@ def test_async_save_failure_surfaces_on_wait_and_next_save(
 
 
 # ----------------------------------------------------------- graceful stop --
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_request_shutdown_drains_at_barrier_and_keeps_snapshots(mode):
-    mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False,
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_request_shutdown_drains_at_barrier_and_keeps_snapshots(slots):
+    mgr = FarmManager(slots=slots, evict_stragglers=False,
                       poll_s=0.01)
     job, outs = _submit(mgr, "long", scale=2.0, n=40)
     _submit(mgr, "short", scale=3.0, n=2, barriers=False)

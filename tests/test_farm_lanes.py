@@ -2,7 +2,7 @@
 dispatch stream. The contract under test: lane packing broadcasts
 identity-shared weight trees as one device copy; a fused LaneBatch run is
 bit-identical to the N solo runs it replaces (through the raw scheduler
-AND through the farm, in both host-loop modes, tail windows included);
+AND through the farm, on several slots and on one, tail windows included);
 the farm coalesces compatible queued jobs up to the slot's lane capacity
 and refuses incompatible ones for a nameable reason; a verify failure
 vetoes ONE lane — detached and requeued solo from its per-lane barrier
@@ -141,19 +141,20 @@ def _submit_lane_jobs(mgr, n, *, n_steps=7, group=2, lane_key="arch-a",
     return outs
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
 @pytest.mark.parametrize("n_steps,group", [(7, 2), (8, 2), (9, 4)])
-def test_farm_lanes_bit_identical_to_solo(mode, n_steps, group):
+def test_farm_lanes_bit_identical_to_solo(slots, n_steps, group):
     """The acceptance oracle: a lane-coalesced farm pass (tail windows
     included) delivers every board's outputs and final state bit-identical
     to the solo farm pass, and actually coalesced (one dispatch stream)."""
     n = 4
-    solo_mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False)
+    solo_mgr = FarmManager(slots=slots, evict_stragglers=False)
     solo = _submit_lane_jobs(solo_mgr, n, n_steps=n_steps, group=group,
                              lane_key=None)
     solo_mgr.run()
 
-    mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False, lanes=n)
+    mgr = FarmManager(slots=slots, evict_stragglers=False, lanes=n)
     outs = _submit_lane_jobs(mgr, n, n_steps=n_steps, group=group)
     rep = mgr.run()
     assert rep["telemetry"]["lanes_per_dispatch_max"] == n
@@ -170,7 +171,7 @@ def test_farm_lanes_bit_identical_to_solo(mode, n_steps, group):
 def test_farm_lane_capacity_splits_queue():
     """5 compatible jobs on a capacity-4 slot: one 4-lane dispatch plus
     one solo run — never a partial merge beyond capacity."""
-    mgr = FarmManager(slots=1, mode="lockstep", evict_stragglers=False,
+    mgr = FarmManager(slots=1, evict_stragglers=False,
                       lanes=4)
     outs = _submit_lane_jobs(mgr, 5)
     rep = mgr.run()
@@ -212,14 +213,15 @@ def test_lane_compatible_names_the_mismatch():
 
 
 # ------------------------------------------------------ lane-granular veto --
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_lane_veto_evicts_only_the_faulted_lane(mode, n=4, bad=2):
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_lane_veto_evicts_only_the_faulted_lane(slots, n=4, bad=2):
     """A verify failure mid-stream names ONE lane: that member is
     detached and requeued solo (resuming from its per-lane snapshot, not
     window 0), the survivors keep running, and every board — including
     the vetoed one — still delivers exactly-once outputs bit-identical
     to its solo run."""
-    solo_mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False)
+    solo_mgr = FarmManager(slots=slots, evict_stragglers=False)
     solo = _submit_lane_jobs(solo_mgr, n, lane_key=None)
     solo_mgr.run()
 
@@ -230,7 +232,7 @@ def test_lane_veto_evicts_only_the_faulted_lane(mode, n=4, bad=2):
             marked["done"] = True
             raise RuntimeError("injected lane fault")
 
-    mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False, lanes=n)
+    mgr = FarmManager(slots=slots, evict_stragglers=False, lanes=n)
     outs = _submit_lane_jobs(mgr, n, verify_for=bad, verify=chaos_verify)
     rep = mgr.run(strict=False)
 
@@ -270,13 +272,15 @@ def _shell_reset(shell):
     return {"acc": jnp.zeros_like(shell["acc"])}
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_fused_custom_drain_fans_records_out_per_lane(mode, n=3):
+@pytest.mark.parametrize("slots", [pytest.param(1, id="async"),
+                                   pytest.param(2, id="two-slot")])
+def test_fused_custom_drain_fans_records_out_per_lane(slots, n=3):
     """Boards with a custom drain_fn/reset shell: the fused drain runs the
     base drain per lane against shell SLICES and each member's on_drain
-    sees exactly the records its solo run produces."""
+    sees exactly the records its solo run produces (the solo runs spread
+    over the slots; the fused run takes one)."""
     def run(lanes):
-        mgr = FarmManager(slots=1, mode=mode, evict_stragglers=False,
+        mgr = FarmManager(slots=slots, evict_stragglers=False,
                           lanes=lanes)
         recs = {}
         for i in range(n):

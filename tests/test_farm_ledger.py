@@ -297,13 +297,14 @@ def _cut_mid_stream(mgr, at_window=3):
         job.verify = cut
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
 def test_recover_finishes_campaign_exactly_once_across_lifetimes(
-        tmp_path, mode):
-    tag = f"xonce-{mode}"
+        tmp_path, slots):
+    tag = f"xonce-{slots}"
     DELIVERED[tag] = []
     n = 8
-    mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False,
+    mgr = FarmManager(slots=slots, evict_stragglers=False,
                       poll_s=0.01, ledger=FarmLedger(str(tmp_path)))
     for i in range(2):
         mgr.submit_spec(_spec(f"b{i}", tag, tmp_path, n_windows=n,
@@ -316,9 +317,8 @@ def test_recover_finishes_campaign_exactly_once_across_lifetimes(
               for b in ("b0", "b1")}
     assert any(phase1.values())          # delivery was already in flight
 
-    mgr2 = FarmManager.recover(FarmLedger(str(tmp_path)), slots=2,
-                               mode=mode, evict_stragglers=False,
-                               poll_s=0.01)
+    mgr2 = FarmManager.recover(FarmLedger(str(tmp_path)), slots=slots,
+                               evict_stragglers=False, poll_s=0.01)
     rep2 = mgr2.run(strict=False)
     mgr2.ledger.close()
     assert all(j["status"] == "done" for j in rep2["jobs"].values())
@@ -353,7 +353,7 @@ def test_torn_deliver_record_redelivers_only_its_own_windows(tmp_path):
     tag = "torn-deliver"
     DELIVERED[tag] = []
     n = 8
-    mgr = FarmManager(slots=1, mode="lockstep", evict_stragglers=False,
+    mgr = FarmManager(slots=1, evict_stragglers=False,
                       ledger=FarmLedger(str(tmp_path)))
     mgr.submit_spec(_spec("b0", tag, tmp_path, n_windows=n))
     _cut_mid_stream(mgr, at_window=4)
@@ -376,7 +376,7 @@ def test_torn_deliver_record_redelivers_only_its_own_windows(tmp_path):
         f.writelines(ln for i, ln in enumerate(lines) if i != torn_i)
 
     mgr2 = FarmManager.recover(FarmLedger(str(tmp_path)), slots=1,
-                               mode="lockstep", evict_stragglers=False)
+                               evict_stragglers=False)
     rep2 = mgr2.run(strict=False)
     mgr2.ledger.close()
     assert rep2["jobs"]["b0"]["status"] == "done"
@@ -393,16 +393,17 @@ def test_torn_deliver_record_redelivers_only_its_own_windows(tmp_path):
     assert all(len(set(vs)) == 1 for vs in by_window.values())
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_ledger_on_delivery_bit_identical_to_ledger_off(tmp_path, mode):
+@pytest.mark.parametrize("slots", [pytest.param(2, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_ledger_on_delivery_bit_identical_to_ledger_off(tmp_path, slots):
     """Attaching a ledger switches delivery to incremental-at-commit; the
     delivered stream (order AND values) must not change."""
     n = 6
-    tag_off, tag_on = f"id-off-{mode}", f"id-on-{mode}"
+    tag_off, tag_on = f"id-off-{slots}", f"id-on-{slots}"
     for tag, ledger in ((tag_off, None),
                         (tag_on, FarmLedger(str(tmp_path)))):
         DELIVERED[tag] = []
-        mgr = FarmManager(slots=2, mode=mode, evict_stragglers=False,
+        mgr = FarmManager(slots=slots, evict_stragglers=False,
                           poll_s=0.01, ledger=ledger)
         for i in range(2):
             mgr.submit_spec(_spec(f"b{i}", tag, tmp_path / tag,

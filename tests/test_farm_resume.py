@@ -6,7 +6,6 @@ the resume point BEFORE the rejected window, donating engines survive
 both the no-snapshot replay and the snapshot-resume path, and the
 snapshot travels the checkpoint store's atomic publish path (in-memory
 and on-disk)."""
-import time
 from collections import Counter
 
 import jax
@@ -63,7 +62,7 @@ def _submit_board(mgr, *, windows=None, engine=_engine, verify=None,
 
 
 def _baseline(windows=None):
-    mgr = FarmManager(slots=3, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=3, evict_stragglers=False)
     got = _submit_board(mgr, windows=windows)
     mgr.run()
     return got, np.asarray(mgr.results["j"][0])
@@ -71,7 +70,8 @@ def _baseline(windows=None):
 
 def _evict_trigger(mgr, at_index, name="j"):
     """verify hook that force-marks the job once it has delivered window
-    ``at_index`` (first attempt only)."""
+    ``at_index`` (first attempt only): the job's slot thread honors the
+    mark at its next drain boundary."""
     fired = {"done": False}
 
     def verify(plan, records, ys):
@@ -83,17 +83,19 @@ def _evict_trigger(mgr, at_index, name="j"):
 
 
 # ----------------------------------------------------- resume bit-identity --
-def test_lockstep_resume_zero_replay_and_bit_identical():
-    """The acceptance contract, deterministically (lockstep): a job
-    evicted right after N committed barriers replays ZERO windows before
-    its resume cursor and its delivered outputs + final state are
-    bit-identical to the uninterrupted run."""
+def test_one_slot_resume_zero_replay_and_bit_identical():
+    """The acceptance contract on a one-slot farm: a job evicted right
+    after N committed barriers requeues onto its only seat, replays ZERO
+    windows before its resume cursor, and its delivered outputs + final
+    state are bit-identical to the uninterrupted run."""
     base, base_state = _baseline()
-    mgr = FarmManager(slots=3, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=1, evict_stragglers=False)
     got = _submit_board(mgr, verify=_evict_trigger(mgr, 4))
     rep = mgr.run()
     j = rep["jobs"]["j"]
     assert j["status"] == "done" and j["requeues"] == 1
+    ev = rep["telemetry"]["evictions"]
+    assert len(ev) == 1 and j["slot"] == ev[0]["slot"]  # its only seat
     assert j["windows_committed"] > 0
     assert j["windows_replayed"] == 0           # resumed AT the commit
     resumes = rep["telemetry"]["resumes"]
@@ -108,20 +110,13 @@ def test_lockstep_resume_zero_replay_and_bit_identical():
 
 
 def test_async_resume_bit_identical_and_replays_less_than_committed():
-    """Same contract under per-slot dispatcher threads: the evict lands at
-    a nondeterministic drain boundary, but the resumed job never re-runs
-    more than the uncommitted tail (replayed < committed) and delivery is
+    """Same contract on a three-slot farm: the evict lands at the drain
+    boundary after window 3, the resumed job never re-runs more than the
+    uncommitted tail (replayed < committed) and delivery is
     bit-identical."""
     base, base_state = _baseline()
-    mgr = FarmManager(slots=3, mode="async", evict_stragglers=False)
-
-    def slow_first(state, shell, stack):
-        if mgr.jobs[0].attempts == 1:
-            time.sleep(0.03)        # give the control sweep a boundary
-        return _engine(state, shell, stack)
-
-    got = _submit_board(mgr, engine=slow_first,
-                        verify=_evict_trigger(mgr, 3))
+    mgr = FarmManager(slots=3, evict_stragglers=False)
+    got = _submit_board(mgr, verify=_evict_trigger(mgr, 3))
     rep = mgr.run()
     j = rep["jobs"]["j"]
     assert j["status"] == "done" and j["requeues"] == 1
@@ -137,19 +132,14 @@ def test_async_resume_bit_identical_and_replays_less_than_committed():
                                   base_state)
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_resumed_on_drain_never_redelivers_a_committed_window(mode):
+@pytest.mark.parametrize("slots", [pytest.param(3, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_resumed_on_drain_never_redelivers_a_committed_window(slots):
     """Exactly-once across the eviction: every window index reaches
     on_drain once, in window order — the committed prefix is retained,
     never re-delivered by the resumed attempt."""
-    mgr = FarmManager(slots=3, mode=mode, evict_stragglers=False)
-
-    def engine(state, shell, stack):
-        if mode == "async" and mgr.jobs[0].attempts == 1:
-            time.sleep(0.02)
-        return _engine(state, shell, stack)
-
-    got = _submit_board(mgr, engine=engine, verify=_evict_trigger(mgr, 4))
+    mgr = FarmManager(slots=slots, evict_stragglers=False)
+    got = _submit_board(mgr, verify=_evict_trigger(mgr, 4))
     rep = mgr.run()
     assert rep["jobs"]["j"]["requeues"] == 1
     counts = Counter(i for i, _, _ in got)
@@ -157,8 +147,9 @@ def test_resumed_on_drain_never_redelivers_a_committed_window(mode):
     assert [i for i, _, _ in got] == list(range(8))     # in order
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_veto_then_evict_resumes_from_barrier_before_the_veto(mode):
+@pytest.mark.parametrize("slots", [pytest.param(3, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_veto_then_evict_resumes_from_barrier_before_the_veto(slots):
     """A drain veto blocks BOTH the barrier action and the snapshot: the
     faulted attempt requeues with its resume point at the last barrier
     before the rejected window, the rejected window re-runs (and passes),
@@ -172,7 +163,7 @@ def test_veto_then_evict_resumes_from_barrier_before_the_veto(mode):
             failed["n"] += 1
             raise AssertionError("synthetic commit divergence")
 
-    mgr = FarmManager(slots=3, mode=mode, evict_stragglers=False)
+    mgr = FarmManager(slots=slots, evict_stragglers=False)
     got = _submit_board(mgr, verify=verify, commits=commits)
     rep = mgr.run()
     j = rep["jobs"]["j"]
@@ -203,7 +194,7 @@ def test_donating_engine_full_replay_after_eviction():
     from fresh copies, so the job's state stays a valid replay source
     with no snapshot involved (evicted before any barrier)."""
     base, base_state = _baseline()
-    mgr = FarmManager(slots=3, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=3, evict_stragglers=False)
     got = _submit_board(mgr, engine=_donating_engine(), barrier_every=0)
     mgr.force_evict("j")            # at the first drain boundary
     rep = mgr.run()
@@ -216,22 +207,17 @@ def test_donating_engine_full_replay_after_eviction():
                                   base_state)
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_donating_engine_snapshot_resume_bit_identical(mode):
+@pytest.mark.parametrize("slots", [pytest.param(3, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_donating_engine_snapshot_resume_bit_identical(slots):
     """The acceptance criterion's donating case: snapshots are host
     copies, so a donated-and-deleted device buffer is never a restore
     source — the resumed attempt restores fresh buffers and finishes
     bit-identical."""
     base, base_state = _baseline()
-    mgr = FarmManager(slots=3, mode=mode, evict_stragglers=False)
-    donating = _donating_engine()
-
-    def engine(state, shell, stack):
-        if mode == "async" and mgr.jobs[0].attempts == 1:
-            time.sleep(0.02)
-        return donating(state, shell, stack)
-
-    got = _submit_board(mgr, engine=engine, verify=_evict_trigger(mgr, 4))
+    mgr = FarmManager(slots=slots, evict_stragglers=False)
+    got = _submit_board(mgr, engine=_donating_engine(),
+                        verify=_evict_trigger(mgr, 4))
     rep = mgr.run()
     j = rep["jobs"]["j"]
     assert j["status"] == "done" and j["requeues"] == 1
@@ -250,7 +236,7 @@ def test_resume_keeps_tail_window_math_for_non_divisible_streams():
     windows = _windows(n_items=7, group=2)
     base, base_state = _baseline(windows=windows)
     assert [s for _, s, _ in base] == [0, 2, 4, 6]
-    mgr = FarmManager(slots=3, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=3, evict_stragglers=False)
     got = _submit_board(mgr, windows=windows, verify=_evict_trigger(mgr, 2))
     rep = mgr.run()
     assert rep["jobs"]["j"]["requeues"] == 1
@@ -268,7 +254,7 @@ def test_on_disk_snapshot_store_resumes_through_atomic_publish(tmp_path):
     requeued attempt restores from disk."""
     base, base_state = _baseline()
     store = CheckpointManager(str(tmp_path / "snaps"), keep=2)
-    mgr = FarmManager(slots=3, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=3, evict_stragglers=False)
     got = _submit_board(mgr, verify=_evict_trigger(mgr, 4),
                         snapshot_store=store)
     rep = mgr.run()
@@ -350,8 +336,9 @@ def test_commit_stream_verifier_resumes_mid_stream():
     assert e.value.step == 7
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
-def test_stateful_verifier_rewinds_on_no_snapshot_requeue(mode):
+@pytest.mark.parametrize("slots", [pytest.param(3, id="async"),
+                                   pytest.param(1, id="one-slot")])
+def test_stateful_verifier_rewinds_on_no_snapshot_requeue(slots):
     """A snapshot/restore verifier must rewind to its STARTING position
     when the job requeues without any accepted barrier (full window-0
     replay) — otherwise the replay is compared against an oracle already
@@ -370,16 +357,10 @@ def test_stateful_verifier_rewinds_on_no_snapshot_requeue(mode):
         def restore(self, snap):
             self.pos = snap["pos"]
 
-    mgr = FarmManager(slots=3, mode=mode, evict_stragglers=False)
+    mgr = FarmManager(slots=slots, evict_stragglers=False)
     v = PositionVerifier()
-
-    def engine(state, shell, stack):
-        if mode == "async" and mgr.jobs[0].attempts == 1:
-            time.sleep(0.02)
-        return _engine(state, shell, stack)
-
     # barrier never fires (every=1000): eviction happens with NO snapshot
-    got = _submit_board(mgr, engine=engine, verify=v, barrier_every=1000)
+    got = _submit_board(mgr, verify=v, barrier_every=1000)
     mgr.force_evict("j")
     rep = mgr.run()
     assert rep["jobs"]["j"]["status"] == "done"
@@ -435,7 +416,7 @@ def test_capture_keeps_committed_rows_across_resume():
     from repro.roofline import WindowCapture
 
     cap = WindowCapture()
-    mgr = FarmManager(slots=3, mode="lockstep", evict_stragglers=False)
+    mgr = FarmManager(slots=3, evict_stragglers=False)
     _submit_board(mgr, verify=_evict_trigger(mgr, 4), capture=cap)
     rep = mgr.run()
     assert rep["jobs"]["j"]["requeues"] == 1

@@ -1,5 +1,6 @@
 """ZP-Scope on the farm: non-interference (scope on/off bit-identity
-through both host loops, solo and lane-coalesced), the fleet scope report,
+on several slots and on one, solo and lane-coalesced), the fleet scope
+report,
 and THE acceptance scenario — a genuinely slow board evicted from its
 device-side throughput counters while host wall-clock noise makes the
 legacy wall channel misleading."""
@@ -10,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import iter_windows
+from repro.core import Client, WindowScheduler, iter_windows
 from repro.core.scope import ScopeSpec
 from repro.farm import FarmJob, FarmManager
 from repro.farm.manager import lane_compatible
@@ -20,13 +21,13 @@ jax.config.update("jax_platform_name", "cpu")
 
 
 # --------------------------------------------------------- the smoke gate --
-@pytest.mark.parametrize("mode,lanes", [("async", 1), ("lockstep", 1),
-                                        ("async", 2)])
-def test_scope_smoke_bit_identity(mode, lanes):
+@pytest.mark.parametrize("slots,lanes", [
+    pytest.param(2, 1, id="async-1"), pytest.param(1, 1, id="one-slot-1"),
+    pytest.param(2, 2, id="async-2")])
+def test_scope_smoke_bit_identity(slots, lanes):
     """The CI gate's own checker: scope-on outputs/states bit-identical
     to scope-off, no scope keys leaking, non-empty fleet report."""
-    out = run_scope_smoke(mode=mode, lanes=lanes, every_n=2, slots=2,
-                          n_steps=8)
+    out = run_scope_smoke(lanes=lanes, every_n=2, slots=slots, n_steps=8)
     assert out["ok"], out["problems"]
     assert out["scope"]["samples"] > 0
 
@@ -78,7 +79,7 @@ def test_farm_scope_report_and_work_channel_feed():
     per job) AND the watchdog's device-side work-rate channel, while the
     published results stay scope-free."""
     base = {}
-    mgr0 = FarmManager(slots=2, mode="async", evict_stragglers=False)
+    mgr0 = FarmManager(slots=2, evict_stragglers=False)
     for i in range(2):
         mgr0.submit(FarmJob(name=f"job{i}", engine=_engine,
                             windows=_windows(i), state=jnp.float32(0),
@@ -86,7 +87,7 @@ def test_farm_scope_report_and_work_channel_feed():
     mgr0.run()
     base = {n: np.asarray(mgr0.results[n][0]) for n in ("job0", "job1")}
 
-    mgr = FarmManager(slots=2, mode="async", evict_stragglers=False)
+    mgr = FarmManager(slots=2, evict_stragglers=False)
     for i in range(2):
         mgr.submit(FarmJob(name=f"job{i}", engine=_engine,
                            windows=_windows(i), state=jnp.float32(0),
@@ -119,7 +120,7 @@ def test_device_counters_evict_true_straggler_not_heavy_board():
     their wall — the true per-token straggler. With every board scoped,
     the watchdog judges seconds-per-token from the on-device counters:
     only "slow" is evicted, requeued, and still delivers outputs
-    bit-identical to an undisturbed oracle run."""
+    bit-identical to an undisturbed run_many pass."""
     def make_slow(sleep_s, engine=_engine):
         def eng(state, shell, stack):
             time.sleep(sleep_s)
@@ -145,20 +146,29 @@ def test_device_counters_evict_true_straggler_not_heavy_board():
                           col[n].append(np.asarray(y)))))
         return col
 
-    oracle = FarmManager(slots=4, mode="lockstep", evict_stragglers=False)
-    base = submit_all(oracle, scope=None)
-    oracle.run()
-
-    # Warm the scoped-async path end to end with a throwaway farm over
-    # both ys structures. The farm writes off window-0 compile as
-    # bitstream-build time, but under overlap pipelining the first-use
-    # compile WAIT leaks into window-1 walls — and this test is about
-    # steady-state rates, not compile accounting.
     def heavy_nosleep(state, shell, stack):
         s, ys = _heavy_body(state, stack)
         return s, shell, ys
 
-    warm = FarmManager(slots=2, mode="async", evict_stragglers=False)
+    # the undisturbed reference: the same boards through a plain run_many
+    # pass (a sleep changes no output, so the engines here do not sleep)
+    names = ["norm0", "norm1", "heavy", "slow"]
+    base = {n: [] for n in names}
+    finals = WindowScheduler(
+        interval=1, overlap=True, drain_fn=None, stack_fn=None).run_many(
+        [Client(heavy_nosleep if n == "heavy" else _engine,
+                _windows(i, n_items=24), jnp.float32(0), {},
+                stack_fn=_stack, drain_fn=None)
+         for i, n in enumerate(names)],
+        on_drain=lambda k, p, r, y: base[names[k]].append(np.asarray(y)))
+
+    # Warm the scoped path end to end with a throwaway farm over both ys
+    # structures. The farm writes off window-0 compile as bitstream-build
+    # time, but under overlap pipelining the first-use compile WAIT leaks
+    # into window-1 walls — and this test is about steady-state rates,
+    # not compile accounting.
+
+    warm = FarmManager(slots=2, evict_stragglers=False)
     for i, eng in enumerate((_engine, heavy_nosleep)):
         warm.submit(FarmJob(name=f"warm{i}", engine=eng,
                             windows=_windows(9 + i, n_items=6),
@@ -167,7 +177,7 @@ def test_device_counters_evict_true_straggler_not_heavy_board():
                             scope=ScopeSpec(every_n_windows=1)))
     warm.run()
 
-    mgr = FarmManager(slots=4, mode="async", straggler_factor=2.0,
+    mgr = FarmManager(slots=4, straggler_factor=2.0,
                       straggler_min_s=0.01)
     col = submit_all(mgr, scope=ScopeSpec(every_n_windows=1))
     rep = mgr.run()
@@ -179,11 +189,11 @@ def test_device_counters_evict_true_straggler_not_heavy_board():
     assert all(j["status"] == "done" for j in rep["jobs"].values())
     # the eviction was judged on the device-side work-rate channel
     assert any(len(v) for v in mgr.wd.work_rates.values())
-    # exactly-once delivery, bit-identical to the undisturbed oracle
+    # exactly-once delivery, bit-identical to the undisturbed reference
     for name in base:
         assert len(col[name]) == len(base[name]) == 12
         for a, b in zip(base[name], col[name]):
             np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(
-            np.asarray(oracle.results[name][0]),
+            np.asarray(finals[names.index(name)][0]),
             np.asarray(mgr.results[name][0]))

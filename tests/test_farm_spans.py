@@ -48,7 +48,7 @@ def _job(name, seed, n_items=8, group=2, verify=None, **kw):
 
 
 def _farm(n_jobs=4, slots=2, verify=None, **kw):
-    mgr = FarmManager(slots=slots, mode="async")
+    mgr = FarmManager(slots=slots)
     for j in range(n_jobs):
         mgr.submit(_job(f"job{j}", j, verify=verify, **kw))
     return mgr
@@ -175,7 +175,7 @@ def test_window_shape_change_logs_one_recompile():
 
     windows = ([[np.ones((3, 5), np.float32)]] * 3
                + [[np.ones((3, 7), np.float32)]] * 3)
-    mgr = FarmManager(slots=1, mode="async")
+    mgr = FarmManager(slots=1)
     mgr.submit(FarmJob(name="reshaped", engine=engine, windows=windows,
                        state=jnp.float32(0), shell={}))
     rep = mgr.run()
@@ -200,7 +200,7 @@ def test_last_report_is_the_newest_run():
     def reject(plan, records, ys):
         raise AssertionError("never accepted")
 
-    failing = FarmManager(slots=1, mode="async")
+    failing = FarmManager(slots=1)
     failing.submit(_job("bad", 9, verify=reject, max_requeues=0))
     with pytest.raises(FarmError):
         failing.run()
@@ -214,7 +214,7 @@ def test_control_report_counts_admission():
     ticks, jobs examined and jobs assigned, where every assignment is one
     attempt of one job, and a fault-free farm examines only what it
     seats (straggler eviction off, so that no board is requeued)."""
-    mgr = FarmManager(slots=2, mode="async", evict_stragglers=False)
+    mgr = FarmManager(slots=2, evict_stragglers=False)
     for j in range(6):
         mgr.submit(_job(f"job{j}", j))
     rep = mgr.run()
