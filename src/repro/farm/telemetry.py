@@ -50,7 +50,12 @@ window, s}``: a compile inside a window after a job's window 0).
 ``moe`` holds the routing counter ``routing`` (:meth:`moe_routing`):
 decode steps, token-expert pairs, distinct experts touched summed over
 layer-steps, and the largest group, summed over jobs (the largest group
-the largest) from the ``moe_routing`` CSR of the decode shell.
+the largest) from the first four fields of the ``moe_routing`` CSR of
+the decode shell, as before the streaming kernel; and beside it
+``streamed``, the layer-steps whose expert products the streaming Pallas
+kernel served, summed over jobs from the CSR's fifth field (a decode
+step of an ``L``-layer model counts ``L`` when every layer streamed, 0
+on ``ragged_dot``).
 ``clock_origin`` is the ``perf_counter`` start of the ``zp.farm.run``
 span, so a host-clock stamp ``t`` lies at ``t - clock_origin`` into that
 span on the profiler's timeline.
@@ -226,16 +231,17 @@ class FarmTelemetry:
 
     def moe_routing(self, job: str, records):
         """From a drained window's shell ``records``, keep the job's
-        cumulative ``moe_routing`` CSR ([steps, pairs, touched, largest];
-        a lane run carries one row per lane), read from the drain already
-        in hand. Records without it are left alone."""
+        cumulative ``moe_routing`` CSR ([steps, pairs, touched, largest,
+        streamed]; a lane run carries one row per lane), read from the
+        drain already in hand. Records without it are left alone."""
         csrs = records.get("csrs") if isinstance(records, dict) else None
         if not csrs or "moe_routing" not in csrs:
             return
         rows = [[int(v) for v in row] for row in
-                np.asarray(csrs["moe_routing"]).reshape(-1, 4)]
+                np.asarray(csrs["moe_routing"]).reshape(-1, 5)]
         row = (sum(r[0] for r in rows), sum(r[1] for r in rows),
-               sum(r[2] for r in rows), max(r[3] for r in rows))
+               sum(r[2] for r in rows), max(r[3] for r in rows),
+               sum(r[4] for r in rows))
         with self._lock:
             self.routing_jobs[job] = row
 
@@ -482,6 +488,7 @@ class FarmTelemetry:
                        "pairs": sum(r[1] for r in rows),
                        "touched": sum(r[2] for r in rows),
                        "largest": max((r[3] for r in rows), default=0)}
+            streamed = sum(r[4] for r in rows)
             per_thread = ([d["compiles"] for d in devices.values()]
                           + [control["compiles"]])
             compiles = {
@@ -518,7 +525,7 @@ class FarmTelemetry:
             "control": {"phases": control["phases"],
                         "compiles": control["compiles"],
                         "admission": admission},
-            "moe": {"routing": routing},
+            "moe": {"routing": routing, "streamed": streamed},
             "compiles": compiles,
             "clock_origin": self.clock_origin,
         }

@@ -3,8 +3,10 @@ ahead-of-time compile test (``tests/test_tpu_compile.py``) and the on-chip
 agreement check (``chip_smoke.py``), so both always see the same shapes.
 
 Widths: granite-8b attention (32 query heads, 8 kv heads, head_dim 128,
-2048 positions, a 4096-slot decode cache), the mixtral-8x7b expert FFN
-(8 x 512 x 4096 @ 8 x 4096 x 14336), the falcon-mamba-7b selective scan
+2048 positions, a 4096-slot decode cache), the qwen3-30b-a3b expert FFN
+of a decode step (8 tokens x top-8 = 64 rows over 52 of layer 1's 128
+experts of 2048 x 768, read in place from a 2-layer stack of 256), the
+falcon-mamba-7b selective scan
 (d_inner 8192, state 16) and the recurrentgemma-2b RG-LRU (width 2560).
 ``small=True`` shrinks every dim for a CPU rehearsal in interpret mode.
 """
@@ -20,14 +22,23 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
-from repro.kernels.grouped_gemm.ops import grouped_gemm
-from repro.kernels.grouped_gemm.ref import grouped_gemm_ref
+from repro.kernels.grouped_gemm.ops import ragged_grouped_ffn
+from repro.kernels.grouped_gemm.ref import ragged_swiglu_ref
 from repro.kernels.rglru_scan.ops import rglru_scan
 from repro.kernels.rglru_scan.ref import rglru_scan_ref
 from repro.kernels.ssm_scan.ops import ssm_scan
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def _route(draw, E: int, rows: int, touched: int):
+    """Group sizes over a 2-layer stack of ``E`` experts a layer: ``rows``
+    rows dealt round-robin over ``touched`` experts of layer 1, picked by
+    the order of ``draw``."""
+    experts = E + jnp.argsort(draw[E:2 * E])[:touched]
+    return jnp.zeros(draw.shape, I32).at[
+        experts[jnp.arange(rows) % touched]].add(1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,11 +63,12 @@ class KernelCase:
 
 def kernel_cases(small: bool = False) -> Dict[str, KernelCase]:
     if small:
-        S, H, K, hd, W, E, M, D, F, Din, L = 128, 4, 2, 32, 256, 2, 64, \
+        S, H, K, hd, W, E, M, D, F, Din, L = 128, 4, 2, 32, 256, 16, 16, \
             128, 256, 256, 128
     else:
-        S, H, K, hd, W, E, M, D, F, Din, L = 2048, 32, 8, 128, 4096, 8, \
-            512, 4096, 14336, 8192, 2560
+        S, H, K, hd, W, E, M, D, F, Din, L = 2048, 32, 8, 128, 4096, 128, \
+            64, 2048, 768, 8192, 2560
+    G, touched = 2 * E, M * 13 // 16                # 52 of 128 experts
 
     def ops(*shapes):
         return tuple(jax.ShapeDtypeStruct(s, d) for s, d in shapes)
@@ -79,11 +91,20 @@ def kernel_cases(small: bool = False) -> Dict[str, KernelCase]:
             ops(((4, H, hd), BF16), ((4, W, K, hd), BF16),
                 ((4, W, K, hd), BF16), ((), I32)), bf16_tol,
             prep=lambda q, k, v, pos: (q, k, v, jnp.int32(W - 1))),
+        # the reference: three f32 ragged_dots over layer 1's slice; the
+        # kernel rounds only h and its output to bf16. The rows are
+        # scaled so the output is of order 1
         "grouped_gemm": KernelCase(
-            lambda x, w, interpret=False: grouped_gemm(x, w,
-                                                       interpret=interpret),
-            grouped_gemm_ref,
-            ops(((E, M, D), BF16), ((E, D, F), BF16)), bf16_tol),
+            lambda *a, interpret=False: ragged_grouped_ffn(
+                *a, interpret=interpret),
+            lambda xs, wg, wu, wd, sizes: ragged_swiglu_ref(
+                xs.astype(F32), *(w[E:].astype(F32) for w in (wg, wu, wd)),
+                sizes[E:]),
+            ops(((M, D), BF16), ((G, D, F), BF16), ((G, D, F), BF16),
+                ((G, F, D), BF16), ((G,), I32)), bf16_tol,
+            prep=lambda xs, wg, wu, wd, s: (
+                xs * (D ** -0.5 * F ** -0.25), wg, wu, wd,
+                _route(s, E, M, touched))),
         # f32 recurrences, all on the vector unit: only the order of the
         # h.C sum differs from the reference
         "ssm_scan": KernelCase(

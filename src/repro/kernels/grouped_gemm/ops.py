@@ -1,4 +1,9 @@
-"""jit'd public wrappers: padded grouped GEMM + the composed MoE FFN."""
+"""Public wrapper of the ragged grouped expert FFN, with the contract of
+``jax.lax.ragged_dot``: ``xs`` (P, D) rows sorted by group, ``sizes``
+(G,) rows per group of the weight stacks, groups read in place. The
+output is ``xs.dtype``, accumulated in f32. Its VJP is the VJP of the
+same FFN as three ``ragged_dot`` products, so a differentiated caller
+keeps its gradients."""
 from __future__ import annotations
 
 import functools
@@ -6,39 +11,71 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.grouped_gemm.grouped_gemm import grouped_gemm_kernel
+from repro.kernels.grouped_gemm.grouped_gemm import ragged_ffn_kernel
+from repro.kernels.grouped_gemm.ref import ragged_swiglu_ref
+
+#: VMEM bytes the streamed weight blocks may take, both pipeline buffers
+#: together. A v5e core has 128 MiB of VMEM; this leaves room for the
+#: rows, the output and the compiler's own scratch.
+WEIGHT_VMEM_BYTES = 48 << 20
 
 
-def _pad(x, axis, mult):
-    p = (-x.shape[axis]) % mult
-    if p == 0:
-        return x
-    w = [(0, 0)] * x.ndim
-    w[axis] = (0, p)
-    return jnp.pad(x, w)
+def schedule(sizes, steps: int):
+    """The non-empty groups, in order, padded to ``steps`` entries:
+    (group id, first row, rows, [number non-empty]). A padded entry
+    repeats the last non-empty group's id with 0 rows."""
+    live = sizes > 0
+    n = jnp.sum(live, dtype=jnp.int32)
+    gid = jnp.nonzero(live, size=steps, fill_value=0)[0].astype(jnp.int32)
+    real = jnp.arange(steps) < n
+    gid = jnp.where(real, gid, gid[jnp.maximum(n - 1, 0)])
+    starts = (jnp.cumsum(sizes) - sizes).astype(jnp.int32)
+    return (gid, starts[gid], jnp.where(real, sizes[gid], 0).astype(jnp.int32),
+            n[None])
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "block_m", "block_n", "block_k", "interpret"))
-def grouped_gemm(x, w, *, block_m: int = 128, block_n: int = 128,
-                 block_k: int = 128, interpret: bool = False):
-    """x: (E,M,K) @ w: (E,K,N) -> (E,M,N)."""
-    E, M, K = x.shape
-    N = w.shape[2]
-    bm = min(block_m, max(8, M))
-    bn = min(block_n, max(8, N))
-    bk = min(block_k, max(8, K))
-    xp = _pad(_pad(x, 1, bm), 2, bk)
-    wp = _pad(_pad(w, 1, bk), 2, bn)
-    out = grouped_gemm_kernel(xp, wp, block_m=bm, block_n=bn, block_k=bk,
-                              interpret=interpret)
-    return out[:, :M, :N]
+def _tile_width(F: int, per_col: int) -> int:
+    """The widest tile of the ``F`` columns, a multiple of 128 dividing
+    ``F`` (or ``F`` itself), whose two pipeline copies of ``per_col``
+    bytes a column fit in :data:`WEIGHT_VMEM_BYTES`."""
+    if 2 * F * per_col <= WEIGHT_VMEM_BYTES or F % 128:
+        return F
+    fits = [t for t in range(128, F, 128)
+            if F % t == 0 and 2 * t * per_col <= WEIGHT_VMEM_BYTES]
+    return max(fits, default=128)
 
 
-def moe_ffn(disp, wg, wu, wd, *, interpret: bool = False):
-    """Expert FFN on dispatched tokens: silu(x@wg)*(x@wu) @ wd."""
-    g = jax.nn.silu(grouped_gemm(disp, wg, interpret=interpret)
-                    .astype(jnp.float32))
-    u = grouped_gemm(disp, wu, interpret=interpret).astype(jnp.float32)
-    h = (g * u).astype(disp.dtype)
-    return grouped_gemm(h, wd, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ffn(xs, wg, wu, wd, sizes, interpret):
+    P, D = xs.shape
+    G, _, F = wg.shape
+    pad = (-P) % (32 // xs.dtype.itemsize)          # the sublane tile
+    # each non-empty group holds a row: at most min(P, G) of them
+    sched = schedule(sizes, max(1, min(P, G)))
+    out = ragged_ffn_kernel(
+        sched, jnp.pad(xs, ((0, pad), (0, 0))), wg, wu, wd,
+        tf=_tile_width(F, 3 * D * wg.dtype.itemsize), interpret=interpret)
+    return out[:P]
+
+
+def _ffn_fwd(xs, wg, wu, wd, sizes, interpret):
+    return _ffn(xs, wg, wu, wd, sizes, interpret), (xs, wg, wu, wd, sizes)
+
+
+def _ffn_bwd(interpret, res, g):
+    *arrays, sizes = res
+    _, vjp = jax.vjp(lambda *a: ragged_swiglu_ref(*a, sizes), *arrays)
+    return (*vjp(g), None)
+
+
+_ffn.defvjp(_ffn_fwd, _ffn_bwd)
+
+
+def ragged_grouped_ffn(xs, wg, wu, wd, sizes, *, interpret: bool = False):
+    """The SwiGLU expert FFN over ragged groups in one kernel:
+    ``(silu(xs @ wg[g]) * (xs @ wu[g])) @ wd[g]`` for the rows of each
+    group g; wg, wu (G, D, F), wd (G, F, D) -> (P, D); rows past
+    ``sum(sizes)`` are zero. Only the non-empty groups' weights are read,
+    each group's three blocks once; the gate and up products stay in VMEM
+    in f32 until the down product."""
+    return _ffn(xs, wg, wu, wd, sizes, interpret)

@@ -1,17 +1,11 @@
-"""Pure-jnp oracle for the grouped GEMM."""
-import jax.numpy as jnp
+"""Pure-jnp oracle of the ragged grouped expert FFN: XLA's ``ragged_dot``."""
+import jax
 
 
-def grouped_gemm_ref(x, w):
-    """x: (E,M,K) @ w: (E,K,N) -> (E,M,N) with f32 accumulation."""
-    return jnp.einsum("emk,ekn->emn", x, w,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def moe_ffn_ref(disp, wg, wu, wd):
-    """The full expert FFN the kernel composes into: silu(x@wg)*(x@wu)@wd."""
-    import jax
-    g = jax.nn.silu(grouped_gemm_ref(disp, wg).astype(jnp.float32))
-    u = grouped_gemm_ref(disp, wu).astype(jnp.float32)
-    h = (g * u).astype(disp.dtype)
-    return grouped_gemm_ref(h, wd)
+def ragged_swiglu_ref(xs, wg, wu, wd, sizes):
+    """The SwiGLU expert FFN as three ragged products, each rounded to
+    ``xs.dtype``: ``(silu(xs @ wg[g]) * (xs @ wu[g])) @ wd[g]`` for the
+    rows of each group g."""
+    g = jax.nn.silu(jax.lax.ragged_dot(xs, wg, sizes))
+    u = jax.lax.ragged_dot(xs, wu, sizes)
+    return jax.lax.ragged_dot(g * u, wd, sizes)

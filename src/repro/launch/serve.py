@@ -7,10 +7,11 @@ steps: ONE jit dispatch per window (donated cache), with a decode FIFO in
 the P-Shell carrying per-token telemetry ([step, mean token id, max
 logit]), a ``tokens`` CSR counting emissions and a ``moe_routing`` CSR
 counting the experts' routing (decode steps, token-expert pairs, distinct
-experts touched summed over layer-steps, the largest group). The scheduler
-double-buffers the shell so the host drain of window *i* — where the
-blocking token fetch and the per-window decode-latency sample land —
-overlaps window *i+1*'s in-flight decode.
+experts touched summed over layer-steps, the largest group, and the
+layer-steps whose expert products the streaming Pallas kernel served).
+The scheduler double-buffers the shell so the host drain of window *i* —
+where the blocking token fetch and the per-window decode-latency sample
+land — overlaps window *i+1*'s in-flight decode.
 
   PYTHONPATH=src python -m repro.launch.serve --arch glm4-9b --smoke \\
       --batch 4 --prompt-len 32 --gen 16 --sample-interval 4
@@ -43,7 +44,7 @@ def decode_shell_config(sample_interval: int) -> ShellConfig:
     the routing counter (:func:`count_routing`)."""
     return ShellConfig(
         csrs={"tokens": jax.ShapeDtypeStruct((), jnp.int32),
-              "moe_routing": jax.ShapeDtypeStruct((4,), jnp.int32)},
+              "moe_routing": jax.ShapeDtypeStruct((5,), jnp.int32)},
         fifos={"decode": FifoSpec(depth=max(1, sample_interval), shape=(3,),
                                   dtype=jnp.float32)},
         sample_interval=sample_interval)
@@ -51,12 +52,18 @@ def decode_shell_config(sample_interval: int) -> ShellConfig:
 
 def count_routing(shell, routing):
     """Add one decode step's routing (``Model.decode_step_routed``) to the
-    ``moe_routing`` CSR: [steps, pairs, experts touched, largest group],
-    the first three summed, the last the largest seen."""
+    ``moe_routing`` CSR: [steps, pairs, experts touched, largest group,
+    streamed layer-steps], the largest group the largest seen, the others
+    summed. The streamed count rides in this CSR, not a CSR of its own:
+    one more scalar carried through the window's loops moved XLA's
+    memory-space assignment to copy the KV cache stack into fast memory
+    every token step in the Pallas-attention decode window
+    (``tests/test_tpu_compile.py``)."""
     cur = csr_read(shell, "moe_routing")
     step = jnp.concatenate([jnp.ones((1,), jnp.int32), routing[:2]])
     return csr_write(shell, "moe_routing", jnp.concatenate(
-        [cur[:3] + step, jnp.maximum(cur[3:], routing[2:])]))
+        [cur[:3] + step, jnp.maximum(cur[3:4], routing[2:3]),
+         cur[4:] + routing[3:]]))
 
 
 #: Compiler options of the decode window on a TPU. XLA's memory-space

@@ -127,15 +127,16 @@ class Model:
         return cache, logits
 
     def decode_step_routed(self, params, cache, tokens1):
-        """:meth:`decode_step` plus the step's expert routing, a (3,)
+        """:meth:`decode_step` plus the step's expert routing, a (4,)
         int32: token-expert pairs and distinct experts touched, each
-        summed over the MoE layers, and the largest group of any layer
-        (zeros for a model without experts)."""
+        summed over the MoE layers, the largest group of any layer, and
+        the MoE layers whose products the streaming kernel served (zeros
+        for a model without experts)."""
         cfg, rt = self.cfg, self.rt
         if cfg.family == "encdec":
             cache, logits = ed.encdec_decode_step(params, cfg, cache,
                                                   tokens1, rt)
-            return cache, logits, jnp.zeros((3,), jnp.int32)
+            return cache, logits, jnp.zeros((4,), jnp.int32)
         pos = cache["pos"]
         B = tokens1.shape[0]
         x = embed_apply(params["embed"], tokens1,

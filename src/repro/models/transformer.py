@@ -107,7 +107,7 @@ def block_cache_spec(cfg, spec, batch: int, max_len: int):
 
 def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime, layer=None):
     """One-token decode of one block: (x1, cache, routing), ``routing``
-    the layer-step's (3,) :func:`~repro.models.moe.routing_counts`
+    the layer-step's (4,) :func:`~repro.models.moe.routing_counts`
     (zeros for a block without experts). ``layer``: None for a block
     outside the scanned stack; else this block's stacked inputs are read
     at index ``layer``: its cache (a stack of layers' caches, returned
@@ -133,7 +133,7 @@ def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime, layer=None):
             lambda c, s: jax.lax.dynamic_update_index_in_dim(c, s, layer, 0),
             cache, state)
     x1 = x1 + y
-    routing = jnp.zeros((3,), jnp.int32)
+    routing = jnp.zeros((4,), jnp.int32)
     if ffn is not None:
         h2 = norm_apply(cfg, p["norm2"], x1)
         if ffn == "mlp":
@@ -153,11 +153,12 @@ def block_decode(p, cfg, spec, x1, cache, pos, rt: Runtime, layer=None):
 
 
 def fold_routing(rows):
-    """Routing counts of several layer-steps (..., 3) -> (3,): pairs and
-    experts touched summed, the largest group the largest."""
-    rows = rows.reshape(-1, 3)
-    return jnp.concatenate([jnp.sum(rows[:, :2], axis=0),
-                            jnp.max(rows[:, 2:], axis=0, initial=0)])
+    """Routing counts of several layer-steps (..., 4) -> (4,): pairs,
+    experts touched and streamed layer-steps summed, the largest group
+    the largest."""
+    rows = rows.reshape(-1, 4)
+    total = jnp.sum(rows, axis=0)
+    return total.at[2].set(jnp.max(rows[:, 2], initial=0))
 
 
 def block_prefill(p, cfg, spec, x, positions, max_len: int, rt: Runtime,
@@ -329,7 +330,7 @@ def stack_decode(stack, cfg, x1, cache, rt: Runtime):
         (x1, new_cache["scanned"]), routes = jax.lax.scan(
             period_body, (x1, cache["scanned"]),
             (tuple(b for b, _ in split), jnp.arange(n_periods)))
-        rows.append(routes.reshape(-1, 3))
+        rows.append(routes.reshape(-1, 4))
     tail_new = []
     for i, p in enumerate(stack["tail"]):
         x1, c, r = block_decode(p, cfg, pattern[i % P_len], x1,
@@ -339,7 +340,7 @@ def stack_decode(stack, cfg, x1, cache, rt: Runtime):
     new_cache["tail"] = tuple(tail_new)
     new_cache["pos"] = pos + 1
     routing = (fold_routing(jnp.concatenate(rows)) if rows
-               else jnp.zeros((3,), jnp.int32))
+               else jnp.zeros((4,), jnp.int32))
     return x1, new_cache, routing
 
 

@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracle,
 shape/dtype sweeps + hypothesis property tests."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.decode_attention import ops as da_ops
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.grouped_gemm import ops as gg_ops
-from repro.kernels.grouped_gemm.ref import grouped_gemm_ref, moe_ffn_ref
+from repro.kernels.grouped_gemm.ref import ragged_swiglu_ref
 from repro.kernels.ssm_scan import ops as ssm_ops
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
 from repro.kernels.rglru_scan import ops as lru_ops
@@ -132,30 +134,82 @@ def test_decode_attention_reads_a_layer_of_a_stack(W):
         assert_allclose(np.asarray(out), np.asarray(one), rtol=0, atol=0)
 
 
-# ----------------------------------------------------------- grouped gemm ---
-@pytest.mark.parametrize("E,M,K,N", [
-    (4, 128, 64, 128), (3, 50, 33, 17), (1, 8, 8, 8), (8, 256, 128, 64)])
+# ----------------------------------------------------- ragged grouped ffn ---
+# rows per group of 8 groups: some empty, every pair on one group, and a
+# P = 50 (not a multiple of 8) whose groups hold 41 rows (the rest zero)
+SIZES = {"empty": (0, 9, 0, 0, 3, 17, 0, 3),
+         "one_group": (0, 0, 0, 32, 0, 0, 0, 0),
+         "p50": (7, 0, 1, 13, 0, 0, 20, 0)}
+
+
+def _ragged(key, name, D, F, dtype):
+    """(xs, sizes, wg, wu, wd) of 8 experts of D x F."""
+    sizes = jnp.array(SIZES[name], jnp.int32)
+    P = 50 if name == "p50" else int(jnp.sum(sizes))
+    ks = jax.random.split(key, 4)
+    return (rand(ks[0], (P, D), dtype), sizes,
+            *(rand(k, s, dtype, scale=D ** -0.5) for k, s in (
+                (ks[1], (8, D, F)), (ks[2], (8, D, F)), (ks[3], (8, F, D)))))
+
+
+@pytest.mark.parametrize("name", ["empty", "one_group", "p50", "tiled"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_grouped_gemm(E, M, K, N, dtype):
-    ks = jax.random.split(jax.random.key(4), 2)
-    x = rand(ks[0], (E, M, K), dtype)
-    w = rand(ks[1], (E, K, N), dtype)
-    out = gg_ops.grouped_gemm(x, w, block_m=32, block_n=32, block_k=32,
-                              interpret=True)
-    ref = grouped_gemm_ref(x, w)
+def test_ragged_grouped_ffn(name, dtype, monkeypatch):
+    """The fused SwiGLU against three ``ragged_dot``s in f32: the kernel
+    keeps the gate and up products in f32 and rounds only the product
+    into the down projection and the output to ``dtype``."""
+    F = 512 if name == "tiled" else 96
+    if name == "tiled":
+        # weights too wide for the VMEM budget: 128-column tiles of 512
+        monkeypatch.setattr(gg_ops, "WEIGHT_VMEM_BYTES", 2 * 3 * 64 * 128 * 4)
+        assert gg_ops._tile_width(F, 3 * 64 * 4) == 128
+    xs, sizes, wg, wu, wd = _ragged(jax.random.key(5), "empty" if name ==
+                                    "tiled" else name, 64, F, dtype)
+    out = gg_ops.ragged_grouped_ffn(xs, wg, wu, wd, sizes, interpret=True)
+    ref = ragged_swiglu_ref(*(a.astype(jnp.float32) for a in (xs, wg, wu,
+                                                             wd)), sizes)
+    assert out.dtype == dtype
     assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
                     **tol(dtype))
 
 
-def test_moe_ffn_composed():
-    ks = jax.random.split(jax.random.key(5), 4)
-    E, C, D, F = 4, 64, 32, 48
-    disp = rand(ks[0], (E, C, D))
-    wg, wu = rand(ks[1], (E, D, F)), rand(ks[2], (E, D, F))
-    wd = rand(ks[3], (E, F, D))
-    out = gg_ops.moe_ffn(disp, wg, wu, wd, interpret=True)
-    ref = moe_ffn_ref(disp, wg, wu, wd)
-    assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("layer", [0, 3])
+def test_ragged_grouped_ffn_reads_a_layer_in_place(layer):
+    """Layer ``layer`` of a 4-layer stack of 8 experts, read in place as 32
+    groups (all empty but the layer's), equals the layer's slice."""
+    xs, sizes, wg, wu, wd = _ragged(jax.random.key(6), "empty", 64, 96,
+                                    jnp.float32)
+    stacks = [jnp.concatenate([w * (i + 2) if i != layer else w
+                               for i in range(4)]) for w in (wg, wu, wd)]
+    stacked = jax.lax.dynamic_update_slice(jnp.zeros((32,), jnp.int32),
+                                           sizes, (layer * 8,))
+    out = gg_ops.ragged_grouped_ffn(xs, *stacks, stacked, interpret=True)
+    ref = ragged_swiglu_ref(xs, wg, wu, wd, sizes)
+    assert_allclose(np.asarray(out), np.asarray(ref), **tol(jnp.float32))
+
+
+def test_ragged_schedule_lists_the_non_empty_groups():
+    gid, start, count, n = gg_ops.schedule(
+        jnp.array(SIZES["empty"], jnp.int32), 6)
+    assert int(n[0]) == 4
+    # the padded entries repeat the last group with no rows: no new DMA
+    np.testing.assert_array_equal(gid, [1, 4, 5, 7, 7, 7])
+    np.testing.assert_array_equal(start, [0, 9, 12, 29, 29, 29])
+    np.testing.assert_array_equal(count, [9, 3, 17, 3, 0, 0])
+
+
+def test_ragged_grouped_ffn_vjp_is_ragged_dots():
+    xs, sizes, wg, wu, wd = _ragged(jax.random.key(7), "empty", 64, 96,
+                                    jnp.float32)
+    fn = functools.partial(gg_ops.ragged_grouped_ffn, interpret=True)
+
+    def loss(f):
+        return lambda *a: jnp.sum(jnp.sin(f(*a, sizes)))
+    got = jax.grad(loss(fn), argnums=(0, 1, 2, 3))(xs, wg, wu, wd)
+    want = jax.grad(loss(ragged_swiglu_ref), argnums=(0, 1, 2, 3))(
+        xs, wg, wu, wd)
+    for a, b in zip(got, want):
+        assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
 
 # -------------------------------------------------------------- ssm scan ----

@@ -29,6 +29,7 @@ from repro.kernels.cases import kernel_cases
 from repro.launch.serve import (TPU_DECODE_OPTIONS, decode_shell_config,
                                 make_decode_engine)
 from repro.models import build_model
+from repro.models import moe as moe_mod
 from repro.models.runtime import Runtime
 
 
@@ -169,3 +170,35 @@ def test_decode_window_carries_the_cache_stack_in_place(arch, one_chip):
     assert not moved, moved
     k_stack_bytes = math.prod(stack) * 2          # bf16
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * k_stack_bytes
+
+
+def test_moe_decode_window_streams_the_experts_in_place(one_chip,
+                                                        monkeypatch):
+    """The qwen3-moe decode window at the cell's widths (8 x top-8 = 64
+    pairs a layer-step) runs its expert FFN as the streaming kernel,
+    which reads each layer's experts out of the whole stack: no op writes
+    a buffer one layer's expert weights in size (a slice would copy
+    them), and the window's temporaries exceed those of the same window
+    on ``ragged_dot`` by at most the kernel's output blocks."""
+    B, W = 8, 2313
+    cfg, streamed = _decode_window("qwen3-moe-30b-a3b", "xla", one_chip, B,
+                                   W, options=TPU_DECODE_OPTIONS)
+    monkeypatch.setattr(moe_mod, "_ffn_streamed", moe_mod._ffn_ragged)
+    _, plain = _decode_window("qwen3-moe-30b-a3b", "xla", one_chip, B, W,
+                              options=TPU_DECODE_OPTIONS)
+    text = streamed.as_text()
+    assert "ragged_grouped_ffn" in text
+    assert "ragged-dot" not in text and "ragged-dot" in plain.as_text()
+    layer_experts = cfg.num_experts * cfg.d_model * cfg.moe_d_ff
+    big = []
+    for dims, op in _HLO_OP.findall(text):
+        shape = [int(d) for d in filter(None, dims.split(","))]
+        if (op in _WRITES | {"dynamic-slice", "slice"}
+                and cfg.moe_d_ff in shape
+                and math.prod(shape) >= layer_experts):
+            big.append((op, dims))
+    assert not big, big
+    pairs = B * cfg.num_experts_per_tok
+    out_blocks = cfg.num_layers * pairs * cfg.d_model * 2
+    assert (streamed.memory_analysis().temp_size_in_bytes
+            <= plain.memory_analysis().temp_size_in_bytes + out_blocks)
